@@ -14,15 +14,9 @@ Multi-chip:  pass --mesh-data/--mesh-entity to shard over a device mesh.
 from __future__ import annotations
 
 import argparse
-import os
 import time
 
 import numpy as np
-
-if os.environ.get("JAX_PLATFORMS", "").strip().lower() == "cpu":
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
 
 
 def main():

@@ -96,8 +96,8 @@ def module_text(obj: Any) -> str:
 
 def try_module_text(obj: Any) -> tuple[str | None, str | None]:
     """``(text, None)`` or ``(None, reason)`` — some backends' executables
-    raise from ``as_text()`` (serialization not implemented, relay
-    transport errors). One unprintable program must degrade to a
+    raise from ``as_text()`` (serialization not implemented, transport
+    errors). One unprintable program must degrade to a
     skipped-with-warning audit entry, not kill the whole ``--programs``
     run."""
     try:

@@ -138,15 +138,6 @@ def evaluators_from_args(args):
     return parse_evaluators(args.evaluators) if args.evaluators else []
 
 
-def ensure_single_process_jax() -> None:
-    """Pin the platform before the first JAX import side effects when the
-    caller asked for CPU (tests / airgapped runs)."""
-    if os.environ.get("JAX_PLATFORMS", "").strip().lower() == "cpu":
-        import jax
-
-        jax.config.update("jax_platforms", "cpu")
-
-
 @contextlib.contextmanager
 def run_profile(out_root=None):
     """Telemetry session for one driver run: enable the spine

@@ -33,6 +33,7 @@ from photon_tpu.io.model_io import (
     save_scoring_results,
 )
 from photon_tpu.util import EventEmitter, PhotonLogger, Timed, prepare_output_dir
+from photon_tpu.util.compile_cache import enable_persistent_cache
 
 SCORES_DIR = "scores"
 
@@ -278,7 +279,7 @@ def _score_streaming(
 
 def run(argv=None) -> dict:
     args = build_parser().parse_args(argv)
-    game_base.ensure_single_process_jax()
+    enable_persistent_cache()
     # chaos: (re)install the PHOTON_FAULTS plan per driver run
     from photon_tpu.util import faults
 

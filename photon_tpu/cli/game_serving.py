@@ -35,6 +35,7 @@ import time
 
 from photon_tpu.cli import game_base
 from photon_tpu.util import PhotonLogger, prepare_output_dir
+from photon_tpu.util.compile_cache import enable_persistent_cache
 
 SUMMARY_NAME = "serve-summary.json"
 
@@ -238,7 +239,7 @@ def _handle_swap(cmd: dict, registry, shard_configs, log) -> None:
 
 def run(argv=None) -> dict:
     args = build_parser().parse_args(argv)
-    game_base.ensure_single_process_jax()
+    enable_persistent_cache()
     from photon_tpu.util import faults
 
     faults.install_from_env()

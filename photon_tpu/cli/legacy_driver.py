@@ -431,9 +431,9 @@ def build_parser() -> argparse.ArgumentParser:
 
 def run(argv=None) -> LegacyDriver:
     args = build_parser().parse_args(argv)
-    from photon_tpu.cli.game_base import ensure_single_process_jax
+    from photon_tpu.util.compile_cache import enable_persistent_cache
 
-    ensure_single_process_jax()
+    enable_persistent_cache()
     prepare_output_dir(
         args.output_directory, override=args.override_output_directory
     )

@@ -99,8 +99,8 @@ class RandomEffectCoordinateConfig:
     #: compile-bill governor: cap on the TOTAL distinct (rows, d) bucket
     #: shapes (split across d-groups when a coordinate/pool mixes widths)
     #: — each distinct shape is one traced-and-compiled solve
-    #: program, and remote compiles are the dominant fixed cost of a cold
-    #: fit (PERF.md r4: 40-140 s/program through the relay). The row-level
+    #: program, and compiles are the dominant fixed cost of a cold
+    #: fit (PERF.md, Findings PR 26). The row-level
     #: DP returns its waste-optimal ≤-budget partition, and coordinates
     #: built under one estimator SHARE one pooled level set (game/data.py
     #: ShapePool) so near-duplicate shapes across coordinates collapse.

@@ -157,7 +157,7 @@ class GameEstimator:
     #: first sweep. λ rides as a traced scalar, so one precompiled
     #: program set serves the whole regularization grid. Off by default:
     #: it front-loads the compile bill, which only pays when the fit is
-    #: compile-bound (cold caches, relay-tunnelled backends, many
+    #: compile-bound (cold caches, many
     #: coordinates).
     precompile: bool = False
     #: lifecycle event bus (util/events.EventEmitter). When set, ``fit``

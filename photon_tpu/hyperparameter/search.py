@@ -11,6 +11,7 @@ import math
 from typing import Sequence
 
 import numpy as np
+from scipy.stats import qmc
 
 from photon_tpu.hyperparameter.criteria import expected_improvement
 from photon_tpu.hyperparameter.evaluation import EvaluationFunction
@@ -19,7 +20,6 @@ from photon_tpu.hyperparameter.gp import (
     GaussianProcessModel,
 )
 from photon_tpu.hyperparameter.kernels import Matern52, StationaryKernel
-from photon_tpu.hyperparameter.qmc_compat import sobol_engine
 
 Observation = tuple[np.ndarray, float]
 
@@ -44,7 +44,7 @@ class RandomSearch:
         self.kernel = kernel if kernel is not None else Matern52()
         self.seed = seed
         self.maximize = maximize
-        self._sobol = sobol_engine(num_params, scramble=True, seed=seed)
+        self._sobol = qmc.Sobol(d=num_params, scramble=True, rng=seed)
 
     # --- public API -------------------------------------------------------
 

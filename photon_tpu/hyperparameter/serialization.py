@@ -85,14 +85,14 @@ def shrink_search_range(
     ``prior_points01``: [n, d] rescaled hyperparameter settings;
     returns (lower [d], upper [d]) clipped to [0, 1].
     """
-    from photon_tpu.hyperparameter.qmc_compat import sobol_engine
+    from scipy.stats import qmc
 
     pts = np.atleast_2d(np.asarray(prior_points01, dtype=float))
     vals = np.asarray(prior_values, dtype=float)
     y = vals if maximize else -vals
     model = GaussianProcessEstimator(kernel=Matern52()).fit(pts, y)
     d = pts.shape[1]
-    pool = sobol_engine(d, scramble=True, seed=seed).random(
+    pool = qmc.Sobol(d=d, scramble=True, rng=seed).random(
         candidate_pool_size
     )
     mean, _ = model.predict(pool)

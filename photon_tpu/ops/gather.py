@@ -5,8 +5,9 @@ lookups the reference's aggregators stream row-by-row on CPU executors
 (photon-lib function/glm/ValueAndGradientAggregator.scala:119-247); on
 TPU the lookup itself is the bottleneck, not the FLOPs.
 
-On-chip measurements at config-3 scale (scripts/gather_lab.py, 67M
-gathered elements, v5e):
+Measurements at config-3 scale (67M gathered elements) on a v5e under
+jaxlib 0.4.37, round 4 — the lab script is gone and the numbers have
+not been taken again on the local chip (PERF.md, Open questions):
 
     plain 1-element gather     ~112 Melem/s   (iota == sorted == random:
                                                serialized, not locality-bound)

@@ -455,11 +455,16 @@ def rmatvec_windows_pallas(
         else:
             # No aligned divisor (custom build chunk not a multiple of 8).
             # chunk=length would put a (length, w) one-hot in VMEM — fine
-            # for modest lengths, a Mosaic VMEM blowup for big ones — so
-            # large undivisible instances route to the pure-XLA scan
-            # variant instead (correct everywhere, just not MXU-shaped).
+            # for modest lengths, a Mosaic VMEM blowup for big ones. Say
+            # so: a caller who selected this kernel must not silently
+            # measure another implementation.
             if length > 4096:
-                return rmatvec_windows_onehot(windows, per_row, dim)
+                raise ValueError(
+                    f"pallas rmatvec: instance length {length} has no "
+                    "divisor that is a multiple of 8; build the layout "
+                    "with a chunk that is, or select "
+                    "PHOTON_SPARSE_RMATVEC=prefix"
+                )
     # f32 accumulation: the MXU path is TPU-only, where x64 is unsupported
     contrib = _contrib(windows, per_row).astype(jnp.float32)
     lcols = windows.lcols

@@ -22,10 +22,9 @@ Spark's shuffle service.
 """
 from __future__ import annotations
 
-import inspect
-
 import jax
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from photon_tpu.types import LabeledBatch, PyTree, SparseBatch
@@ -33,23 +32,8 @@ from photon_tpu.types import LabeledBatch, PyTree, SparseBatch
 BATCH_AXIS = "data"
 ENTITY_AXIS = "entity"
 
-try:  # jax ≥ 0.5 exports shard_map at top level
-    from jax import shard_map
-except ImportError:  # 0.4.x series (the pinned toolchain)
-    from jax.experimental.shard_map import shard_map
-
-#: the replication/varying-axis checker kwarg was renamed across jax
-#: versions (0.4.x: check_rep; later: check_vma)
-_SHARD_MAP_CHECK_KW = (
-    "check_vma"
-    if "check_vma" in inspect.signature(shard_map).parameters
-    else "check_rep"
-)
-
-
 def shard_map_unchecked(f, *, mesh, in_specs, out_specs):
-    """``shard_map`` with the replication/varying-axis checker DISABLED,
-    portable across jax versions. Scope it to the SMALLEST sub-function
+    """``shard_map`` with the varying-axis checker DISABLED. Scope it to the SMALLEST sub-function
     the checker provably mis-handles — today that is exactly the vmapped
     optimizer while-loop solve (this jax has no replication rule for
     ``while``, and the carries mix shard-varying state with constant-
@@ -62,7 +46,7 @@ def shard_map_unchecked(f, *, mesh, in_specs, out_specs):
         mesh=mesh,
         in_specs=in_specs,
         out_specs=out_specs,
-        **{_SHARD_MAP_CHECK_KW: False},
+        check_vma=False,
     )
 
 

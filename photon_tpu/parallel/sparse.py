@@ -30,10 +30,8 @@ from __future__ import annotations
 
 import jax
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-# version-compat shard_map resolved once, in parallel/mesh.py
-from photon_tpu.parallel.mesh import shard_map
 
 from photon_tpu.ops.sparse_windows import ColumnWindows, windowed_rmatvec
 from photon_tpu.types import Array
@@ -89,7 +87,7 @@ def shard_windows(
     )
     inst_sharded = NamedSharding(mesh, P(axes))
     inst_mat = NamedSharding(mesh, P(axes, None))
-    # placement wrapped against transient relay UNAVAILABLE, like every
+    # placement wrapped against a transient UNAVAILABLE, like every
     # other multi-hundred-MB coordinate-build put (game/coordinate.py);
     # the chaos fault point rides inside the retried thunk
     from photon_tpu.util import faults

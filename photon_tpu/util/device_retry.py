@@ -1,7 +1,7 @@
 """Transient-device-error retry for host→device placement.
 
-Remote/relayed TPU transports occasionally fail a ``device_put`` with
-``UNAVAILABLE`` even though the chip recovers seconds later. For a GAME
+A device runtime can fail a ``device_put`` with ``UNAVAILABLE`` even
+though the chip recovers seconds later. For a GAME
 coordinate build that places dozens of bucket blocks over many minutes,
 one transient placement failure otherwise kills the whole training
 worker (observed: bench config 5 lost two 40-minute TPU attempts to a

@@ -52,7 +52,7 @@ matches every occurrence). Kinds:
 
 ``unavailable``   raise :class:`InjectedFault` whose message carries the
                   transient ``UNAVAILABLE`` marker — exercises every
-                  retry/restart classifier exactly like a relay flake.
+                  retry/restart classifier exactly like a transport flake.
 ``io_error``      raise :class:`InjectedIOError` (an ``OSError``) — a
                   torn read / failed decode.
 ``error``         raise :class:`InjectedFault` with NO transient marker
@@ -114,7 +114,7 @@ class InjectedFault(RuntimeError):
     """A planned fault (kinds ``unavailable`` / ``error``). The
     ``unavailable`` kind embeds the transient marker in its message so
     the shared classifiers (util/retry.is_transient) treat it exactly
-    like a real relay flake."""
+    like a real transport flake."""
 
 
 class InjectedIOError(OSError):

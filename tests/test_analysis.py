@@ -158,17 +158,17 @@ def test_hot_path_scoping():
 
 def test_mesh_scoping_for_phl007():
     """PHL007 fires in mesh-scoped modules (hot paths + parallel/) and
-    stays silent in probe scripts — a default-device put in gather_lab is
+    stays silent in scripts — a default-device put in a load harness is
     fine; in the sharding layer it is the replicated-table hazard. PHL008
     is whole-tree (a shard_map call site is mesh code wherever it is)."""
     from photon_tpu.analysis.core import is_mesh_scoped
 
     assert is_mesh_scoped("photon_tpu/parallel/mesh.py")
     assert is_mesh_scoped("photon_tpu/game/scoring.py")
-    assert not is_mesh_scoped("scripts/gather_lab.py")
+    assert not is_mesh_scoped("scripts/load_harness.py")
     src = "import jax\ndef f(x):\n    return jax.device_put(x)\n"
     mesh_scoped = analyze_source(src, "photon_tpu/parallel/mesh.py")
-    script = analyze_source(src, "scripts/gather_lab.py")
+    script = analyze_source(src, "scripts/load_harness.py")
     assert any(f.rule == "PHL007" for f in mesh_scoped)
     assert not any(f.rule == "PHL007" for f in script)
     sm = (

@@ -318,7 +318,10 @@ def test_precompile_reports_persistent_cache_hits(tmp_path):
         feature_shard="g", optimization=_opt(), regularization_weights=(1.0,)
     )
     try:
-        assert enable_persistent_cache(str(tmp_path))
+        # a directory that is already configured (JAX_COMPILATION_CACHE_DIR
+        # lands in this same config value) is the one the function keeps
+        jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+        assert enable_persistent_cache() == str(tmp_path)
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
         cold = precompile_coordinates(
             {"fixed": FixedEffectCoordinate.build(data, fe_cfg)}
@@ -330,6 +333,25 @@ def test_precompile_reports_persistent_cache_hits(tmp_path):
         )
         assert warm["cache_hits"] > 0
         assert warm["cache_misses"] == 0
+    finally:
+        jax.config.update("jax_compilation_cache_dir", None)
+
+
+def test_persistent_cache_defaults_to_the_checkout():
+    """With no directory configured the cache sits at a fixed path in the
+    checkout (the path is part of an entry's key: a moving one never
+    hits)."""
+    import os
+
+    from photon_tpu.util.compile_cache import enable_persistent_cache
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    try:
+        jax.config.update("jax_compilation_cache_dir", None)
+        assert enable_persistent_cache() == os.path.join(root, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == os.path.join(
+            root, ".jax_cache"
+        )
     finally:
         jax.config.update("jax_compilation_cache_dir", None)
 

@@ -564,32 +564,44 @@ def test_bench_trend_verdicts(tmp_path, capsys):
     assert run({"glmix_game_estimator": bad}) == 3
 
 
-def test_bench_trend_over_committed_history(capsys):
-    """Acceptance: the gate runs over the real BENCH_r01..r05 files +
-    a fresh synthetic smoke row and exits 0 with a trajectory table."""
-    import tempfile
-
+def test_bench_trend_over_a_recorded_history(tmp_path, capsys):
+    """Acceptance: the gate runs over a history shaped like the driver's
+    records — two failed rounds, then rounds whose rows ran at another
+    scale than the fresh one — plus a fresh smoke row, and exits 0 with a
+    trajectory table."""
     trend = _load_script("bench_trend")
-    with tempfile.TemporaryDirectory() as td:
-        fresh = os.path.join(td, "BENCH_partial.json")
-        with open(fresh, "w") as f:
-            json.dump(
-                {
-                    "metric_version": 4,
-                    "configs": {"glmix_game_estimator": _cfg(123.0)},
-                },
-                f,
-            )
-        rc = trend.main(
-            [
-                "--history", os.path.join(REPO_ROOT, "BENCH_r*.json"),
-                "--fresh", fresh,
-                "--out", os.path.join(td, "trend.json"),
-            ]
+    for name, tail in (
+        ("BENCH_r01", "RuntimeError: backend unavailable"),
+        ("BENCH_r02", "EOFError"),
+    ):
+        (tmp_path / f"{name}.json").write_text(
+            json.dumps({"n": 1, "rc": 1, "parsed": None, "tail": tail})
         )
+    for name, eps in (("BENCH_r03", 80.0), ("BENCH_r04", 95.0)):
+        _bench_round(
+            tmp_path, name,
+            {"glmix_game_estimator": _cfg(eps, scale="cpu")},
+            wrap=lambda p: {"n": 3, "rc": 0, "parsed": None,
+                            "tail": json.dumps(p)},
+        )
+    _bench_round(
+        tmp_path, "BENCH_r05", {"glmix_game_estimator": _cfg(110.0)},
+        wrap=lambda p: {"n": 5, "rc": 0, "parsed": p, "tail": ""},
+    )
+    fresh = _bench_round(
+        tmp_path, "fresh_run", {"glmix_game_estimator": _cfg(123.0)}
+    )
+    rc = trend.main(
+        [
+            "--history", str(tmp_path / "BENCH_r*.json"),
+            "--fresh", fresh,
+            "--out", str(tmp_path / "trend.json"),
+        ]
+    )
     out = capsys.readouterr().out
     assert rc == 0
     assert "glmix_game_estimator" in out and "fresh:" in out
+    assert "skipped BENCH_r01" in out and "skipped BENCH_r02" in out
 
 
 # ---------------------------------------------------------------------------

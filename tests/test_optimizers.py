@@ -218,7 +218,7 @@ def test_vmapped_lbfgs_batch_of_problems():
 
 def test_segmented_owlqn_matches_single_program():
     """SegmentedOWLQN (host-re-dispatched bounded segments — the
-    relay/preemption-safe driver for long solves) must match the
+    preemption-safe driver for long solves) must match the
     single-while-loop solve up to f32 reassociation, reuse its compiled
     segment across calls, and converge by the same criteria."""
     from photon_tpu.optimize.common import ConvergenceReason

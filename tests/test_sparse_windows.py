@@ -155,6 +155,21 @@ def test_pallas_chunk_divides_nondefault_length():
     assert got[0] == pytest.approx(n * k)
 
 
+def test_pallas_refuses_undivisible_long_instance():
+    """An instance length over 4096 with no 8-aligned divisor used to
+    return the one-hot scan's result under the kernel's name."""
+    n, k, d = 5001, 1, 8
+    windows = build_column_windows(
+        np.zeros((n, k), dtype=np.int32), np.ones((n, k), dtype=np.float32),
+        d, window=8, instance_cap=5001, chunk=4099,
+    )
+    assert windows.rows.shape[1] == 2 * 4099
+    with pytest.raises(ValueError, match="no divisor"):
+        rmatvec_windows_pallas(
+            windows, jnp.ones((n,), jnp.float32), d, interpret=True
+        )
+
+
 def test_flat_sorted_invariant_with_misaligned_cap():
     """Regression: a spill cap that is not a multiple of the length rounding
     must not leave mid-stream padding that breaks the non-decreasing global

@@ -1,0 +1,345 @@
+"""The main path's device programs, compiled by the TPU's own compiler at
+the shapes ``chip_smoke.py`` runs — with no chip attached.
+
+The TPU compiler is installed in the sandbox and compiles for a chip that
+is DESCRIBED (``v5e:2x2``), so what it refuses here it would refuse on
+the machine with the chip: misaligned Pallas blocks, programs over the
+16 GB of device memory, unsupported dtypes. Nothing runs, so these tests
+say nothing about results or times.
+
+``jax.default_backend()`` still reads ``cpu`` in such a compile, so each
+test steers the TPU-only branches with the knobs the program already has
+(``PHOTON_SPARSE_WINDOWS``, ``PHOTON_SPARSE_RMATVEC``,
+``PHOTON_SPARSE_GATHER``, ``PHOTON_SWEEP_DONATION``,
+``PHOTON_SCORE_DONATION``).
+
+The topology is described inside a module-scoped fixture and nowhere
+else: only one process may load the TPU library, pytest-xdist workers
+all import every test file, and only the worker that is handed this file
+may touch it. Keep every such compile in THIS file.
+"""
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+import chip_smoke
+from photon_tpu.game.config import (
+    FixedEffectCoordinateConfig,
+    RandomEffectCoordinateConfig,
+)
+from photon_tpu.game.coordinate import (
+    FixedEffectCoordinate,
+    RandomEffectCoordinate,
+)
+from photon_tpu.game.data import (
+    CSRMatrix,
+    GameData,
+    build_random_effect_dataset,
+)
+from photon_tpu.ops import sparse_windows
+from photon_tpu.optimize.common import OptimizerConfig
+from photon_tpu.optimize.problem import (
+    GLMProblemConfig,
+    RegularizationContext,
+    RegularizationType,
+)
+from photon_tpu.types import TaskType
+
+#: the smoke's widths and scale (chip_smoke.py; bench ``game_ctr_scale``)
+ROWS = chip_smoke.ROWS
+FE_DIM = chip_smoke.FE_DIM
+RE_DIM = chip_smoke.RE_DIM
+#: one device holds 16 GB; a program whose arguments + temporaries pass
+#: this cannot run next to anything else
+HBM_BYTES = 16 * (1 << 30)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 — any failure means "cannot describe"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _as_on_the_chip():
+    """f32 like a run on the chip (conftest turns x64 on for the CPU
+    suite), and no persistent cache: a compile for a described chip is
+    written to it but cannot be read back without a chip."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    x64 = jax.config.jax_enable_x64
+    cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_x64", False)
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_x64", x64)
+    jax.config.update("jax_enable_compilation_cache", cache)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def deployment():
+    """The smoke's training rows as a GameData (no avro round trip): the
+    FE shard carries the intercept as its last column, like the reader."""
+    d = chip_smoke.generate(0, ROWS, chip_smoke.USERS, chip_smoke.ITEMS)
+    n = ROWS
+    k = chip_smoke.FE_NNZ
+    cols = np.concatenate(
+        [d["cols"][:n], np.full((n, 1), FE_DIM - 1, np.int64)], axis=1
+    )
+    vals = np.concatenate([d["vals"][:n], np.ones((n, 1))], axis=1)
+    dense_indptr = np.arange(n + 1, dtype=np.int64) * RE_DIM
+    dense_cols = np.tile(np.arange(RE_DIM, dtype=np.int32), n)
+    return GameData.build(
+        labels=d["labels"][:n],
+        feature_shards={
+            "global": CSRMatrix(
+                indptr=np.arange(n + 1, dtype=np.int64) * k,
+                indices=cols.reshape(-1).astype(np.int32),
+                values=vals.reshape(-1),
+                num_cols=FE_DIM,
+            ),
+            "per_user": CSRMatrix(
+                indptr=dense_indptr,
+                indices=dense_cols,
+                values=d["xu"][:n].reshape(-1),
+                num_cols=RE_DIM,
+            ),
+        },
+        id_tags={"userId": [f"u{i}" for i in d["user"][:n]]},
+    )
+
+
+def _opt(max_iterations):
+    return GLMProblemConfig(
+        task=TaskType.LOGISTIC_REGRESSION,
+        regularization=RegularizationContext(RegularizationType.L2),
+        optimizer_config=OptimizerConfig(max_iterations=max_iterations),
+    )
+
+
+def _on(tree, sharding):
+    """The tree's arrays as shapes placed on the described chip."""
+    return jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding),
+        tree,
+    )
+
+
+def _fits(compiled):
+    m = compiled.memory_analysis()
+    need = (
+        m.argument_size_in_bytes
+        + m.output_size_in_bytes
+        + m.temp_size_in_bytes
+        - m.alias_size_in_bytes
+    )
+    assert need < HBM_BYTES, f"program needs {need} bytes of device memory"
+    return m
+
+
+@pytest.fixture(scope="module")
+def fe_coordinate(deployment):
+    """Built with the window layout forced on: the TPU branch of
+    ``maybe_build_windows``."""
+    mp = pytest.MonkeyPatch()
+    mp.setenv("PHOTON_SPARSE_WINDOWS", "1")
+    try:
+        coord = FixedEffectCoordinate.build(
+            deployment,
+            FixedEffectCoordinateConfig(
+                feature_shard="global",
+                optimization=_opt(10),
+                regularization_weights=(1.0,),
+            ),
+        )
+    finally:
+        mp.undo()
+    assert coord.batch.windows is not None
+    return coord
+
+
+@pytest.fixture()
+def tpu_branches(monkeypatch):
+    """What ``jax.default_backend() == 'tpu'`` selects on the chip."""
+    monkeypatch.setenv("PHOTON_SPARSE_RMATVEC", "prefix")
+    monkeypatch.setenv("PHOTON_SPARSE_GATHER", "chunked")
+    monkeypatch.setenv("PHOTON_SWEEP_DONATION", "1")
+    monkeypatch.setenv("PHOTON_SCORE_DONATION", "1")
+
+
+def test_fe_sweep_compiles_for_v5e(fe_coordinate, one_chip, tpu_branches):
+    coord = fe_coordinate
+    n = coord.batch.labels.shape[0]
+    row = jax.ShapeDtypeStruct((n,), coord.dtype, sharding=one_chip)
+    compiled = (
+        type(coord)
+        ._active_sweep_jit(True)
+        .lower(
+            coord,
+            _on(coord.batch, one_chip),
+            _on(coord._norm_args(), one_chip),
+            row,
+            row,
+            _on(coord._state_sds(), one_chip),
+            _on(coord._scalar_sds(), one_chip),
+        )
+        .compile()
+    )
+    m = _fits(compiled)
+    # total/score/state are donated on the chip: their buffers alias
+    assert m.alias_size_in_bytes >= 2 * n * 4
+    assert coord.batch.windows.instance_len == 4096
+    assert coord.batch.windows.window == 128
+
+
+def test_re_bucket_sweep_compiles_for_v5e(deployment, one_chip, tpu_branches):
+    """The fused RE sweep over ONE bucket of the per-user coordinate: the
+    cap-sized one ([E, 128, 16], ~1.5 s). The whole coordinate is the same
+    program over seven buckets and compiles in ~90 s here, nearly all of
+    it in the [50407, 1, 16] singleton bucket (PERF.md, Open questions) —
+    too long for a test every session runs."""
+    cfg = RandomEffectCoordinateConfig(
+        random_effect_type="userId",
+        feature_shard="per_user",
+        optimization=_opt(5),
+        regularization_weights=(1.0,),
+        active_data_upper_bound=chip_smoke.USER_CAP,
+    )
+    ds = build_random_effect_dataset(deployment, cfg, seed=0)
+    assert sum(b.num_entities for b in ds.buckets) == chip_smoke.USERS
+    coord = RandomEffectCoordinate.build(deployment, ds, cfg)
+    coord.device_buckets = [
+        max(coord.device_buckets, key=lambda b: b.features.shape[1])
+    ]
+    assert coord.device_buckets[0].features.shape[1:] == (
+        chip_smoke.USER_CAP, RE_DIM,
+    )
+    row = jax.ShapeDtypeStruct(
+        (coord.num_samples,), coord.dtype, sharding=one_chip
+    )
+    compiled = (
+        type(coord)
+        ._active_sweep_jit(True)
+        .lower(
+            coord,
+            _on(coord._train_args(), one_chip),
+            _on(coord._score_args(), one_chip),
+            row,
+            row,
+            _on(coord._state_sds_list(), one_chip),
+            coord._pad_slots(),
+            _on(coord._scalar_sds(), one_chip),
+        )
+        .compile()
+    )
+    _fits(compiled)
+
+
+def test_scorer_batch_program_compiles_for_v5e(one_chip, tpu_branches):
+    """The fused score program of a GLMix model at the smoke's widths:
+    FE d = 2^17 through a 32-wide ELL block (24 nnz snapped to its
+    power-of-two level), per-user and per-item tables of d = 16."""
+    from photon_tpu.game.scoring import GameScorer, _FixedSpec, _RandomSpec
+
+    # the program alone: a scorer's static specs without a model behind it
+    scorer = object.__new__(GameScorer)
+    scorer._fixed = [_FixedSpec(cid="global", shard="global")]
+    scorer._random, scorer._mf = [], []
+    params = {"fe": {"global": np.zeros(FE_DIM, np.float32)}, "re": {}, "mf": {}}
+    for cid, shard, tag, e_n in (
+        ("per-user", "per_user", "userId", chip_smoke.USERS),
+        ("per-item", "per_item", "itemId", chip_smoke.ITEMS),
+    ):
+        scorer._random.append(
+            _RandomSpec(
+                cid=cid, shard=shard, tag=tag, projected=False,
+                num_entities=e_n,
+            )
+        )
+        params["re"][cid] = {
+            "coef": np.zeros((e_n + 1, RE_DIM), np.float32),
+            "col": np.zeros((e_n + 1, RE_DIM), np.int32),
+        }
+    b = chip_smoke.SCORE_BATCH_ROWS
+    batch = {
+        "offsets": np.zeros((b,), np.float32),
+        "ell": {
+            "global": (
+                np.zeros((b, 32), np.int32),
+                np.zeros((b, 32), np.float32),
+            )
+        },
+        "dense": {
+            "per_user": np.zeros((b, RE_DIM + 1), np.float32),
+            "per_item": np.zeros((b, RE_DIM + 1), np.float32),
+        },
+        "eidx": {
+            "per-user": np.zeros((b,), np.int32),
+            "per-item": np.zeros((b,), np.int32),
+        },
+        "mf": {},
+    }
+    compiled = (
+        jax.jit(scorer._score_fn, donate_argnums=(1,))
+        .lower(_on(params, one_chip), _on(batch, one_chip))
+        .compile()
+    )
+    _fits(compiled)
+
+
+def test_chunked_gather_compiles_for_v5e(fe_coordinate, one_chip):
+    """The lane gather at the FE window stream's size (every slot of the
+    layout reads one element of an [N] per-row vector)."""
+    from photon_tpu.ops.gather import chunked_take
+
+    w = fe_coordinate.batch.windows
+    table = jax.ShapeDtypeStruct((ROWS,), jnp.float32, sharding=one_chip)
+    idx = jax.ShapeDtypeStruct(w.rows.shape, jnp.int32, sharding=one_chip)
+    _fits(jax.jit(chunked_take).lower(table, idx).compile())
+
+
+def _rmatvec_compiled(fn, fe_coordinate, one_chip):
+    w = _on(fe_coordinate.batch.windows, one_chip)
+    per_row = jax.ShapeDtypeStruct((ROWS,), jnp.float32, sharding=one_chip)
+    return (
+        jax.jit(fn, static_argnums=2).lower(w, per_row, FE_DIM).compile()
+    )
+
+
+def test_prefix_rmatvec_compiles_for_v5e(
+    fe_coordinate, one_chip, tpu_branches
+):
+    _fits(
+        _rmatvec_compiled(
+            sparse_windows.rmatvec_windows_prefix, fe_coordinate, one_chip
+        )
+    )
+
+
+def test_pallas_rmatvec_compiles_for_v5e(
+    fe_coordinate, one_chip, tpu_branches
+):
+    """Instance length 4096 x window 128, through Mosaic: the kernel is
+    in the program (``tpu_custom_call``), not a give-way."""
+    compiled = _rmatvec_compiled(
+        sparse_windows.rmatvec_windows_pallas, fe_coordinate, one_chip
+    )
+    _fits(compiled)
+    assert "tpu_custom_call" in compiled.as_text()
