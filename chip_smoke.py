@@ -327,13 +327,27 @@ def assert_live_arrays_on(devices) -> int:
     return len(live)
 
 
+def compiled_since(t: float | None) -> str:
+    """The programs ``compile_watch`` saw compile after ``perf_counter``
+    instant ``t`` (the whole process where ``t`` is None), latest first."""
+    from photon_tpu.util import compile_watch
+
+    rows = compile_watch.programs() if t is None else compile_watch.programs_since(t)
+    return compile_watch.describe(rows, since=t)
+
+
 def sweep_rows(result) -> list[dict]:
     return [r for r in result.tracker if "sweep_seconds" in r]
 
 
-def assert_training_result(result, base_rate_auc=0.5, margin=0.1) -> dict:
+def assert_training_result(
+    result, base_rate_auc=0.5, margin=0.1, since: float | None = None
+) -> dict:
     """Finite per-coordinate health in every sweep, zero compiles in the
-    second sweep, held-out AUC clearly above the base rate."""
+    second sweep, held-out AUC clearly above the base rate. ``since`` is
+    the ``perf_counter`` at which the fit began: a compile in the second
+    sweep is then reported with the names of what compiled after it, the
+    latest (the second sweep's) first."""
     sweeps = sweep_rows(result)
     assert len(sweeps) == 2, f"expected 2 sweep rows, got {len(sweeps)}"
     for row in sweeps:
@@ -342,7 +356,8 @@ def assert_training_result(result, base_rate_auc=0.5, margin=0.1) -> dict:
                 f"sweep {row['iteration']} coordinate {cid}: {h}"
             )
     assert sweeps[1]["compiles"] == 0, (
-        f"second sweep compiled {sweeps[1]['compiles']} program(s)"
+        f"second sweep compiled {sweeps[1]['compiles']} program(s); "
+        f"compiled since the fit began, latest first: {compiled_since(since)}"
     )
     auc = result.evaluation
     assert auc is not None and auc > base_rate_auc + margin, (
@@ -461,7 +476,7 @@ def training_phase(name, argv, *, devices, spread: int, fe_dim: int) -> dict:
         wall = time.perf_counter() - t0
         facts = coordinate_facts(built)
     result = out["results"][out["best"]]
-    checks = assert_training_result(result)
+    checks = assert_training_result(result, since=t0)
     assert len(facts["fe"]) == 1 and len(facts["re"]) == 2, (
         f"expected one FE and two RE coordinates under the driver: {facts}"
     )
@@ -713,7 +728,8 @@ def serving_phase(workdir, model_dir, by_uid, *, devices) -> None:
     assert summary["shed"] == 0 and summary["dispatch_failures"] == 0, summary
     traffic_compiles = summary["compiles"]["backend_compiles"]
     assert traffic_compiles == 0, (
-        f"{traffic_compiles} program(s) compiled while serving"
+        f"{traffic_compiles} program(s) compiled while serving; compiled "
+        f"since the server was started, latest first: {compiled_since(t0)}"
     )
     worst = 0.0
     for seq, chunk in enumerate(chunks, start=1):

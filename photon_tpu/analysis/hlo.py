@@ -106,6 +106,253 @@ def try_module_text(obj: Any) -> tuple[str | None, str | None]:
         return None, f"{type(e).__name__}: {e}"
 
 
+# --- device scopes ---------------------------------------------------------
+#
+# The join between what a profiler trace names (an executable's instruction
+# names: ``fusion.60``) and what the program names (the ``photon.*`` scopes
+# of ``photon_tpu/obs/scopes.py``, carried in ``metadata={op_name=...}``).
+#
+# THE TRAP: jax leaves metadata out of the persistent compilation cache's
+# key (``jax_compilation_cache_include_metadata_in_key`` is false, and stays
+# false: turning it on would make every moved source line a cold start). So
+# an executable SERVED from a cache that an older tree wrote carries that
+# tree's op_names, scopes or none. New scopes therefore cost no cold start,
+# and a join has to be made on an executable that THIS tree compiled: a
+# fresh cache directory, or ``jax_enable_compilation_cache`` off AND
+# ``jax.clear_caches()`` first (``fn.lower(*args).compile()`` is otherwise
+# handed the executable the process already holds, the served one).
+# Instruction names do not depend on metadata, so the text of a fresh
+# compile joins to a trace of the served executable (PERF.md, PR 29: the
+# chip's machine came with PR 28's cache, and read 100 % under no scope).
+
+_INSTR_RE = re.compile(r"^\s*(?P<root>ROOT\s+)?%?(?P<name>[\w.\-]+)\s*=\s*(?P<rest>.*)$")
+_COMPUTATION_RE = re.compile(r"^\s*(?:ENTRY\s+)?%?(?P<name>[\w.\-]+)\s*\(.*\)\s*->.*\{\s*$")
+_OP_NAME_RE = re.compile(r'op_name="((?:[^"\\]|\\.)*)"')
+_CALLS_RE = re.compile(r"\bcalls=%?([\w.\-]+)")
+_OPERAND_RE = re.compile(r"%([\w.\-]+)")
+_SCOPE_PREFIX = "photon."
+
+
+@dataclasses.dataclass(frozen=True)
+class HloInstruction:
+    """One instruction line of post-optimization HLO text."""
+
+    name: str
+    shape: str  # the result's type as written: ``f32[8,128]{1,0}``, or a tuple
+    opcode: str
+    operands: str  # the text between the opcode's parentheses
+    attributes: str  # what follows them: calls=, body=, metadata=, ...
+    op_name: str | None  # metadata op_name: jax's name stack, scopes in it
+    computation: str
+    is_root: bool
+    calls: str | None  # the fused computation of a fusion
+
+    @property
+    def operand_names(self) -> list[str]:
+        return _OPERAND_RE.findall(self.operands)
+
+
+def _matching_paren(text: str, start: int) -> int:
+    """Index of the parenthesis closing the one at ``start``."""
+    depth = 0
+    for i in range(start, len(text)):
+        if text[i] == "(":
+            depth += 1
+        elif text[i] == ")":
+            depth -= 1
+            if depth == 0:
+                return i
+    return len(text) - 1
+
+
+def parse_instructions(obj: Any) -> dict[str, HloInstruction]:
+    """Every instruction of a compiled module's text by name, fused
+    computations' included (names are unique within a module)."""
+    out: dict[str, HloInstruction] = {}
+    computation = ""
+    for line in module_text(obj).splitlines():
+        m = _INSTR_RE.match(line)
+        if m is None:
+            c = _COMPUTATION_RE.match(line)
+            if c is not None:
+                computation = c.group("name")
+            continue
+        rest = m.group("rest")
+        # the result shape: a tuple in parentheses, or one token
+        i = _matching_paren(rest, 0) + 1 if rest.startswith("(") else rest.find(" ")
+        tail = rest[i:].lstrip() if i > 0 else ""
+        open_at = tail.find("(")
+        if open_at < 0:
+            continue
+        close_at = _matching_paren(tail, open_at)
+        attrs = tail[close_at + 1 :]
+        op = _OP_NAME_RE.search(attrs)
+        calls = _CALLS_RE.search(attrs)
+        out[m.group("name")] = HloInstruction(
+            name=m.group("name"),
+            shape=rest[:i].strip(),
+            opcode=tail[:open_at].strip(),
+            operands=tail[open_at + 1 : close_at],
+            attributes=attrs,
+            op_name=op.group(1) if op else None,
+            computation=computation,
+            is_root=m.group("root") is not None,
+            calls=calls.group(1) if calls else None,
+        )
+    return out
+
+
+def scope_path(op_name: str | None) -> tuple[str, ...]:
+    """The ``photon.*`` components of a name stack, outermost first:
+    ``jit(f)/while/body/photon.matvec/photon.gather/gather`` ->
+    ``("photon.matvec", "photon.gather")``."""
+    if not op_name:
+        return ()
+    return tuple(p for p in op_name.split("/") if p.startswith(_SCOPE_PREFIX))
+
+
+#: these move no data: no scope unless they carry one
+_NO_SCOPE = frozenset({"parameter", "tuple", "get-tuple-element", "constant"})
+#: and control flow takes none from its operands (whole loop states)
+_NO_OPERAND_SCOPE = _NO_SCOPE | {"while", "conditional", "call"}
+_CALLEE_RE = re.compile(r"\b(?:body|condition|to_apply|calls)=%?([\w.\-]+)")
+
+
+def instruction_scope_paths(obj: Any) -> dict[str, tuple[str, ...]]:
+    """Instruction name -> its scopes, outermost first, for every
+    instruction of a compiled executable that sits under one.
+
+    A fusion has its root's: the compiler gives a fusion the metadata of
+    the instruction it grew from, and where it gave none the root of the
+    fused computation is read instead. The compiler also makes
+    instructions that carry no name stack at all; each takes the scope of
+    what it works for, found in this order:
+
+    1. its first scoped operand's. jax lowers ``cumsum`` on a TPU through a
+       cached sub-function (``reduce-window`` and its glue carry
+       ``op_name="reduce_window_sum"`` and no caller's stack), and the
+       compiler puts slices and copies on a scoped value's way;
+    2. its nearest scoped user's, through tuples and other unscoped
+       instructions. A reshape that changes a tiled layout becomes a
+       generated ``while`` of ``dynamic-update-slice`` with the reshape's
+       metadata dropped: the loop feeds only what the reshape fed (the
+       index stream's relayout ahead of each gather of
+       ``sparse_poisson.solve``);
+    3. that of the instruction that calls its computation: the body of
+       such a generated loop.
+
+    ``parameter``, ``tuple`` and ``get-tuple-element`` move no data and get
+    no scope; control flow (``while``, ``conditional``, ``call``) gets none
+    from its operands, which are whole loop states."""
+    instrs = parse_instructions(obj)
+    roots = {i.computation: i for i in instrs.values() if i.is_root}
+    out: dict[str, tuple[str, ...]] = {}
+    for ins in instrs.values():  # text order: operands before their users
+        path = scope_path(ins.op_name)
+        if not path and ins.calls in roots:
+            path = scope_path(roots[ins.calls].op_name)
+        if not path and ins.opcode not in _NO_OPERAND_SCOPE:
+            for operand in ins.operand_names:
+                producer = instrs.get(operand)
+                if (
+                    producer is not None
+                    and producer.computation == ins.computation
+                    and operand in out
+                ):
+                    path = out[operand]
+                    break
+        if path:
+            out[ins.name] = path
+
+    users: dict[str, list[HloInstruction]] = {}
+    for ins in instrs.values():
+        for operand in ins.operand_names:
+            users.setdefault(operand, []).append(ins)
+    unscoped = [
+        i for i in instrs.values() if i.name not in out and i.opcode not in _NO_SCOPE
+    ]
+    from_users = {i.name: _nearest_scoped_user(i, users, out) for i in unscoped}
+    out.update({name: path for name, path in from_users.items() if path})
+
+    callers: dict[str, HloInstruction] = {}
+    for ins in instrs.values():
+        for callee in _CALLEE_RE.findall(ins.attributes):
+            callers.setdefault(callee, ins)
+    for ins in unscoped:
+        if ins.name in out:
+            continue
+        caller = callers.get(ins.computation)
+        while caller is not None and caller.name not in out:
+            caller = callers.get(caller.computation)
+        if caller is not None:
+            out[ins.name] = out[caller.name]
+    return out
+
+
+def _nearest_scoped_user(ins, users, scoped, limit: int = 64):
+    """The scope path of the first scoped instruction reached from ``ins``
+    along its users (breadth first, within its computation, at most
+    ``limit`` instructions), or ``None``."""
+    seen, frontier = {ins.name}, [ins]
+    while frontier and len(seen) < limit:
+        nxt = []
+        for node in frontier:
+            for user in users.get(node.name, ()):
+                if user.computation != ins.computation or user.name in seen:
+                    continue
+                if user.name in scoped:
+                    return scoped[user.name]
+                seen.add(user.name)
+                nxt.append(user)
+        frontier = nxt
+    return None
+
+
+def instruction_scopes(obj: Any) -> dict[str, str]:
+    """Instruction name -> innermost ``photon.*`` scope (see
+    :func:`instruction_scope_paths`); instructions under none are left
+    out. Join on an executable compiled by this tree (THE TRAP above)."""
+    return {k: v[-1] for k, v in instruction_scope_paths(obj).items()}
+
+
+#: the row of :func:`seconds_by_scope` for instructions under no scope
+UNSCOPED = "(no photon scope)"
+
+
+def seconds_by_scope(
+    op_seconds: Mapping[str, float], scopes: Mapping[str, Any]
+) -> dict[str, float]:
+    """``{instruction: seconds}`` (one module's device time by
+    instruction, as a trace reduction gives it) summed by the value
+    ``scopes`` holds for the instruction: the innermost scope
+    (:func:`instruction_scopes`) or a whole path, joined with ``/``.
+    What no scope covers is summed under :data:`UNSCOPED`."""
+    out: dict[str, float] = {}
+    for name, secs in op_seconds.items():
+        key = scopes.get(name, UNSCOPED)
+        if not isinstance(key, str):
+            key = "/".join(key)
+        out[key] = out.get(key, 0.0) + secs
+    return out
+
+
+_METADATA_RE = re.compile(r',?\s*metadata=\{(?:[^{}"]|"(?:[^"\\]|\\.)*")*\}')
+# the tables metadata's stack_frame_id points into, printed ahead of the
+# computations: a heading, numbered rows, a blank line
+_SOURCE_TABLES_RE = re.compile(
+    r"^(?:FileNames|FunctionNames|FileLocations|StackFrames)\n(?:\d+ .*\n)*\n*",
+    re.MULTILINE,
+)
+
+
+def strip_metadata(text: str) -> str:
+    """Module text without any ``metadata={...}`` and without the source
+    tables it points into: what is left is the program itself. Two builds
+    that differ only in scopes (or in the lines their source sits on) are
+    byte-identical after this."""
+    return _METADATA_RE.sub("", _SOURCE_TABLES_RE.sub("", text))
+
+
 # --- collective freedom ---------------------------------------------------
 
 

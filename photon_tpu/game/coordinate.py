@@ -44,6 +44,7 @@ from photon_tpu.models.coefficients import Coefficients
 from photon_tpu.models.glm import model_for_task
 from photon_tpu.obs import memory as obs_memory
 from photon_tpu.obs.health import sweep_health
+from photon_tpu.obs.scopes import scope
 from photon_tpu.ops.losses import POSITIVE_RESPONSE_THRESHOLD
 from photon_tpu.ops.normalization import NormalizationContext
 from photon_tpu.data.dataset import choose_sparse
@@ -909,6 +910,16 @@ class RandomEffectCoordinate(Coordinate):
         converged lanes), asserted by the sharded==unsharded parity
         tests.
         """
+        with scope("photon.re.solve"):
+            return self._solve_bucket_body(
+                features, labels, offsets, train_weights, sample_pos, w0,
+                res_pad, reg_weight,
+            )
+
+    def _solve_bucket_body(
+        self, features, labels, offsets, train_weights, sample_pos, w0,
+        res_pad, reg_weight,
+    ):
         problem = GLMProblem.build(self.problem_config)
         n_res = res_pad.shape[0] - 1
         # Residual fold OUTSIDE the unchecked region (VERDICT r5 weak #2):
@@ -1019,11 +1030,12 @@ class RandomEffectCoordinate(Coordinate):
         serializes, the unique path does not. The overflow tail holds
         exactly the pad rows (static per bucket) and is sliced off.
         """
-        c = coefs[score_slot].astype(score_feats.dtype)
-        s = jnp.einsum("md,md->m", score_feats, c)
-        out = jnp.zeros((self.num_samples + pad_slots,), dtype=s.dtype)
-        out = out.at[score_pos].add(s, unique_indices=True)
-        return out[: self.num_samples]
+        with scope("photon.re.rescore"):
+            c = coefs[score_slot].astype(score_feats.dtype)
+            s = jnp.einsum("md,md->m", score_feats, c)
+            out = jnp.zeros((self.num_samples + pad_slots,), dtype=s.dtype)
+            out = out.at[score_pos].add(s, unique_indices=True)
+            return out[: self.num_samples]
 
     @partial(jax.jit, static_argnums=(0, 5))
     def _score_flat(

@@ -72,6 +72,7 @@ import numpy as np
 
 from photon_tpu import obs
 from photon_tpu.obs import causal, slo
+from photon_tpu.obs.scopes import scope
 from photon_tpu.game.data import (
     GameData,
     _ceil_pow2,
@@ -530,6 +531,10 @@ class GameScorer:
     def _score_fn(self, params, batch):
         """Total margin + offsets for one padded batch — every coordinate
         in ONE program, so a steady-state batch is a single dispatch."""
+        with scope("photon.score.batch"):
+            return self._score_terms(params, batch)
+
+    def _score_terms(self, params, batch):
         total = batch["offsets"]
         for s in self._fixed:
             idx, val = batch["ell"][s.shard]

@@ -1,15 +1,16 @@
 """Runtime telemetry spine: span tracing + metrics + exporters.
 
-This package unifies the repo's observability fragments (``util/timed``,
-``util/profiler``, ``util/events``, ``util/compile_watch``,
-``util/dispatch_count``, the descent tracker rows) behind ONE runtime
-layer with three parts:
+ONE runtime layer with three parts (``util/timed``, ``util/events``,
+``util/compile_watch``, ``util/dispatch_count`` and the descent tracker
+rows bridge into it):
 
 - :mod:`photon_tpu.obs.tracer` — a thread-safe span :class:`Tracer`
   (monotonic clocks, nestable spans, a near-zero-overhead no-op when
-  disabled). Each recorded span also enters a
-  ``jax.profiler.TraceAnnotation`` so host spans line up with device
-  traces captured by the jax profiler.
+  disabled). Every span, recorded or not, also enters a
+  ``jax.profiler.TraceAnnotation`` named ``photon.<span>``, so host
+  phases line up with device traces captured by the jax profiler.
+  :mod:`photon_tpu.obs.scopes` is the device side: the ``photon.*``
+  scope vocabulary of the kernels and optimizer phases.
 - :mod:`photon_tpu.obs.metrics` — a :class:`MetricsRegistry` of
   counters / gauges / histograms with a flat ``snapshot()`` dict.
 - :mod:`photon_tpu.obs.export` — Chrome trace-event JSON (opens in
@@ -41,9 +42,9 @@ instrumentation sites stay one-liners::
 Telemetry is DISABLED by default (set ``PHOTON_OBS=1`` to enable at
 import, or call :func:`enable`). Disabled spans still measure wall time
 (two monotonic clock reads — descent derives its tracker rows from
-them) but record nothing, take no locks, and never touch the device:
-enabling or disabling telemetry cannot change the dispatch or read-back
-profile of a run.
+them) and still enter their profiler annotation, but record nothing,
+take no locks, and never touch the device: enabling or disabling
+telemetry cannot change the dispatch or read-back profile of a run.
 """
 from __future__ import annotations
 

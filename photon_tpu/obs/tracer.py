@@ -14,14 +14,22 @@ wall-clock time.
 Overhead discipline: a DISABLED tracer's ``span()`` returns a span that
 still measures (two clock reads, so callers like the descent tracker
 can read ``duration_s`` either way) but skips the lock, the record
-list, the parent stack, and the ``jax.profiler.TraceAnnotation`` — and
-it never dispatches device work in any mode, so telemetry cannot change
-a run's dispatch/read-back profile.
+list and the parent stack — and it never dispatches device work in any
+mode, so telemetry cannot change a run's dispatch/read-back profile.
+
+Device-trace alignment does NOT wait for telemetry: every span enters a
+``jax.profiler.TraceAnnotation`` named ``photon.<span name>``, enabled
+or not, so a profiler capture of any run (the benchmark sets no
+``PHOTON_*`` variable) carries the program's host phases on the device
+trace's clock, apart from a harness's own annotations by their prefix.
+With no profiler session live the annotation is a flag test; a process
+that never imported jax is not made to.
 """
 from __future__ import annotations
 
 import itertools
 import os
+import sys
 import threading
 import time
 from dataclasses import dataclass, field
@@ -45,21 +53,27 @@ class SpanRecord:
     instant: bool = False
 
 
-def _trace_annotation(name: str, **meta):
-    """A jax.profiler.TraceAnnotation for ``name`` carrying ``meta``
-    (span/trace IDs, so device-profiler slices join back to host spans
-    and causal traces), or None when the profiler is unavailable (host
-    spans then simply don't show up in device traces — everything else
-    keeps working)."""
-    try:
-        import jax.profiler
+#: what the profiler's trace shows a span under: ``photon.<span name>``
+ANNOTATION_PREFIX = "photon."
 
+
+def _trace_annotation(name: str, **meta):
+    """A jax.profiler.TraceAnnotation ``photon.<name>`` carrying ``meta``
+    (span/trace IDs, so device-profiler slices join back to host spans
+    and causal traces), or None when the profiler is unavailable or jax
+    was never imported (no capture can be live then; host spans simply
+    don't show up in device traces — everything else keeps working)."""
+    profiler = sys.modules.get("jax.profiler")
+    if profiler is None:
+        return None
+    name = ANNOTATION_PREFIX + name
+    try:
         try:
-            return jax.profiler.TraceAnnotation(name, **meta)
+            return profiler.TraceAnnotation(name, **meta)
         except TypeError:
             # older jax: TraceAnnotation takes no metadata kwargs —
             # fall back to the bare named annotation
-            return jax.profiler.TraceAnnotation(name)
+            return profiler.TraceAnnotation(name)
     except Exception:  # pragma: no cover - profiler unavailable
         return None
 
@@ -112,28 +126,31 @@ class Span:
         # enabled state is latched at entry so a mid-span toggle cannot
         # produce a half-recorded span
         self._recording = tracer.enabled
+        meta = {}
         if self._recording:
             self.span_id = next(tracer._ids)
             stack = tracer._stack()
             self._parent_id = stack[-1] if stack else None
             stack.append(self.span_id)
-            if tracer.annotate_device:
-                meta = {"span_id": self.span_id}
-                trace_id = causal.current_trace_id()
-                if trace_id is not None:
-                    meta["trace_id"] = trace_id
-                self._ann = _trace_annotation(self.name, **meta)
-                if self._ann is not None:
-                    self._ann.__enter__()
+            meta["span_id"] = self.span_id
+            trace_id = causal.current_trace_id()
+            if trace_id is not None:
+                meta["trace_id"] = trace_id
+        if tracer.annotate_device:
+            # always, recording or not: the annotation is what puts this
+            # span on a profiler capture's clock (module docstring)
+            self._ann = _trace_annotation(self.name, **meta)
+            if self._ann is not None:
+                self._ann.__enter__()
         self._t0_ns = time.perf_counter_ns()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
         self._dur_ns = time.perf_counter_ns() - self._t0_ns
-        if not self._recording:
-            return
         if self._ann is not None:
             self._ann.__exit__(exc_type, exc, tb)
+        if not self._recording:
+            return
         tracer = self._tracer
         stack = tracer._stack()
         if stack and stack[-1] == self.span_id:

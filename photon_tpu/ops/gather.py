@@ -38,6 +38,7 @@ import os
 import jax
 import jax.numpy as jnp
 
+from photon_tpu.obs.scopes import scope
 from photon_tpu.types import Array
 
 __all__ = ["chunked_take", "take_1d"]
@@ -73,6 +74,11 @@ def chunked_take(table: Array, idx: Array) -> Array:
     would produce backend-dependent values rather than a consistent
     clamp — all production index streams (ELL layouts, window rows) are
     built in-range by construction."""
+    with scope("photon.gather"):
+        return _chunked_take(table, idx)
+
+
+def _chunked_take(table: Array, idx: Array) -> Array:
     (d,) = table.shape
     n_rows = -(-d // 128)
     padded = jnp.zeros((n_rows * 128,), table.dtype).at[:d].set(table)
@@ -130,4 +136,5 @@ def take_1d(table: Array, idx: Array) -> Array:
         impl = "chunked" if platform == "tpu" else "plain"
     if impl == "chunked":
         return chunked_take(table, idx)
-    return table[idx]
+    with scope("photon.gather"):
+        return table[idx]

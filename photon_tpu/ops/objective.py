@@ -31,6 +31,7 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
+from photon_tpu.obs.scopes import scope
 from photon_tpu.ops.losses import PointwiseLoss
 from photon_tpu.ops.normalization import NormalizationContext
 from photon_tpu.optimize.common import (
@@ -52,6 +53,11 @@ def matvec(batch, v: Array) -> Array:
     path preserves the reference aggregator's never-densify property
     (ValueAndGradientAggregator.scala:36-80) on TPU.
     """
+    with scope("photon.matvec"):
+        return _matvec(batch, v)
+
+
+def _matvec(batch, v: Array) -> Array:
     if isinstance(batch, SparseBatch):
         from photon_tpu.ops.gather import take_1d
 
@@ -120,6 +126,11 @@ def rmatvec(batch, per_row: Array, dim: int, mesh=None) -> Array:
     one-hot MXU kernel is milliseconds. ``PHOTON_SPARSE_RMATVEC=segment``
     forces the plain path for A/B measurement.
     """
+    with scope("photon.rmatvec"):
+        return _rmatvec(batch, per_row, dim, mesh)
+
+
+def _rmatvec(batch, per_row: Array, dim: int, mesh) -> Array:
     if isinstance(batch, SparseBatch):
         if _use_windows(batch, per_row):
             return _windowed_rmatvec_dispatch(
@@ -161,11 +172,14 @@ class GLMObjective:
     # --- margins ----------------------------------------------------------
 
     def margins(self, coef: Array, batch) -> Array:
-        eff = self.normalization.effective_coefficients(coef)
-        z = matvec(batch, eff) + batch.offsets
-        if self.normalization.shifts is not None:
-            z = z + self.normalization.margin_shift(coef)
-        return z
+        # offsets and shift ride in the matvec's scope: the compiler fuses
+        # them onto the product, and a fusion is named by its last operation
+        with scope("photon.matvec"):
+            eff = self.normalization.effective_coefficients(coef)
+            z = _matvec(batch, eff) + batch.offsets
+            if self.normalization.shifts is not None:
+                z = z + self.normalization.margin_shift(coef)
+            return z
 
     def _back(self, per_row: Array, batch, dim: int) -> Array:
         """Xᵀ·per_row, mapped back through the normalization transform.
@@ -175,18 +189,20 @@ class GLMObjective:
         keeps the sparse path sparse (reference
         ValueAndGradientAggregator.scala:36-80).
         """
-        g = rmatvec(batch, per_row, dim, mesh=self.mesh)
-        if self.normalization.shifts is not None:
-            g = g - jnp.sum(per_row) * self.normalization.shifts
-        if self.normalization.factors is not None:
-            g = g * self.normalization.factors
-        return g
+        with scope("photon.rmatvec"):  # the corrections too, as in margins
+            g = _rmatvec(batch, per_row, dim, self.mesh)
+            if self.normalization.shifts is not None:
+                g = g - jnp.sum(per_row) * self.normalization.shifts
+            if self.normalization.factors is not None:
+                g = g * self.normalization.factors
+            return g
 
     # --- value / gradient -------------------------------------------------
 
     def value(self, coef: Array, batch) -> Array:
         z = self.margins(coef, batch)
-        raw = jnp.sum(batch.weights * self.loss.loss(z, batch.labels))
+        with scope("photon.loss"):
+            raw = jnp.sum(batch.weights * self.loss.loss(z, batch.labels))
         return raw + 0.5 * self.l2_weight * jnp.dot(coef, coef)
 
     def gradient(self, coef: Array, batch) -> Array:
@@ -202,14 +218,13 @@ class GLMObjective:
         and the directional oracle, so the two line-search modes can never
         drift onto different objectives."""
         z = self.margins(coef, batch)
-        losses, d1 = self.loss.loss_and_d1(z, batch.labels)
-        value = jnp.sum(batch.weights * losses) + 0.5 * self.l2_weight * jnp.dot(
-            coef, coef
-        )
-        grad = (
-            self._back(batch.weights * d1, batch, coef.shape[-1])
-            + self.l2_weight * coef
-        )
+        with scope("photon.loss"):
+            losses, d1 = self.loss.loss_and_d1(z, batch.labels)
+            value = jnp.sum(
+                batch.weights * losses
+            ) + 0.5 * self.l2_weight * jnp.dot(coef, coef)
+            per_row = batch.weights * d1
+        grad = self._back(per_row, batch, coef.shape[-1]) + self.l2_weight * coef
         return value, grad, z
 
     # --- second order -----------------------------------------------------
@@ -246,24 +261,26 @@ class GLMObjective:
             dd = jnp.dot(d, d)
 
             def phi(alpha):
-                z = carry_z + alpha * z_d
-                losses, d1 = self.loss.loss_and_d1(z, batch.labels)
-                reg = 0.5 * self.l2_weight * (
-                    xx + 2.0 * alpha * xd + alpha * alpha * dd
-                )
-                f = jnp.sum(batch.weights * losses) + reg
-                dphi = jnp.sum(batch.weights * d1 * z_d) + self.l2_weight * (
-                    xd + alpha * dd
-                )
+                with scope("photon.loss"):
+                    z = carry_z + alpha * z_d
+                    losses, d1 = self.loss.loss_and_d1(z, batch.labels)
+                    reg = 0.5 * self.l2_weight * (
+                        xx + 2.0 * alpha * xd + alpha * alpha * dd
+                    )
+                    f = jnp.sum(batch.weights * losses) + reg
+                    dphi = jnp.sum(
+                        batch.weights * d1 * z_d
+                    ) + self.l2_weight * (xd + alpha * dd)
                 return f, dphi, ()
 
             def accept(alpha):
-                z = carry_z + alpha * z_d
-                _, d1 = self.loss.loss_and_d1(z, batch.labels)
-                g = (
-                    self._back(batch.weights * d1, batch, x.shape[-1])
-                    + self.l2_weight * (x + alpha * d)
-                )
+                with scope("photon.loss"):
+                    z = carry_z + alpha * z_d
+                    _, d1 = self.loss.loss_and_d1(z, batch.labels)
+                    per_row = batch.weights * d1
+                g = self._back(
+                    per_row, batch, x.shape[-1]
+                ) + self.l2_weight * (x + alpha * d)
                 return g, z
 
             return phi, accept
@@ -277,17 +294,17 @@ class GLMObjective:
 
         def value_margins(x: Array):
             z = self.margins(x, batch)
-            f = jnp.sum(
-                batch.weights * self.loss.loss(z, batch.labels)
-            ) + 0.5 * self.l2_weight * jnp.dot(x, x)
+            with scope("photon.loss"):
+                f = jnp.sum(
+                    batch.weights * self.loss.loss(z, batch.labels)
+                ) + 0.5 * self.l2_weight * jnp.dot(x, x)
             return f, z
 
         def grad_from_margins(x: Array, z: Array):
-            _, d1 = self.loss.loss_and_d1(z, batch.labels)
-            return (
-                self._back(batch.weights * d1, batch, x.shape[-1])
-                + self.l2_weight * x
-            )
+            with scope("photon.loss"):
+                _, d1 = self.loss.loss_and_d1(z, batch.labels)
+                per_row = batch.weights * d1
+            return self._back(per_row, batch, x.shape[-1]) + self.l2_weight * x
 
         return SmoothMarginOracle(
             full=lambda x: self._value_grad_margins(x, batch),
@@ -303,15 +320,20 @@ class GLMObjective:
         times per trust-region step at a fixed center (TRON.scala:278-339),
         so hoisting it cuts each Hv from three feature passes to two.
         """
-        z = self.margins(coef, batch)
-        d2w = batch.weights * self.loss.d2(z, batch.labels)
+        with scope("photon.hvp"):
+            z = self.margins(coef, batch)
+            with scope("photon.loss"):
+                d2w = batch.weights * self.loss.d2(z, batch.labels)
         dim = coef.shape[-1]
 
         def hv(v: Array) -> Array:
-            xv = matvec(batch, self.normalization.effective_coefficients(v))
-            if self.normalization.shifts is not None:
-                xv = xv + self.normalization.margin_shift(v)
-            return self._back(d2w * xv, batch, dim) + self.l2_weight * v
+            with scope("photon.hvp"):
+                xv = matvec(
+                    batch, self.normalization.effective_coefficients(v)
+                )
+                if self.normalization.shifts is not None:
+                    xv = xv + self.normalization.margin_shift(v)
+                return self._back(d2w * xv, batch, dim) + self.l2_weight * v
 
         return hv
 
@@ -320,7 +342,8 @@ class GLMObjective:
         a sparse batch is densified here — FULL variance is O(D²) memory
         regardless, so it is only reachable when D is small anyway)."""
         z = self.margins(coef, batch)
-        d2 = batch.weights * self.loss.d2(z, batch.labels)
+        with scope("photon.loss"):
+            d2 = batch.weights * self.loss.d2(z, batch.labels)
         x = self._transformed_features(batch, coef.shape[-1])
         h = x.T @ (d2[:, None] * x)
         d = coef.shape[-1]
@@ -354,53 +377,59 @@ class GLMObjective:
         — two segment-sums plus a scalar, no densification.
         """
         z = self.margins(coef, batch)
-        d2 = batch.weights * self.loss.d2(z, batch.labels)
+        with scope("photon.loss"):
+            d2 = batch.weights * self.loss.d2(z, batch.labels)
         dim = coef.shape[-1]
         if isinstance(batch, SparseBatch):
-            windows = getattr(batch, "windows", None)
-            if _use_windows(batch, d2):
-                # same scatter-cliff reroute as rmatvec: Σᵢ d2ᵢ·xᵢⱼ² is a
-                # windowed Xᵀ·d2 with squared stored values
-                sq_windows = windows._replace(
-                    vals=jnp.square(windows.vals)
-                )
-                sq = _windowed_rmatvec_dispatch(
-                    sq_windows, d2, dim, self.mesh
-                )
-                if self.normalization.shifts is not None:
-                    lin = _windowed_rmatvec_dispatch(
-                        windows, d2, dim, self.mesh
-                    )
-                    shifts = self.normalization.shifts
-                    sq = (
-                        sq
-                        - 2.0 * shifts * lin
-                        + jnp.square(shifts) * jnp.sum(d2)
-                    )
-                diag = sq
-                if self.normalization.factors is not None:
-                    diag = diag * jnp.square(self.normalization.factors)
-                return diag + self.l2_weight
-            flat_idx = batch.indices.reshape(-1)
-            sq = jax.ops.segment_sum(
-                (jnp.square(batch.values) * d2[:, None]).reshape(-1),
-                flat_idx,
-                num_segments=dim,
+            # diag(X^T D X): the same reductions as rmatvec, squared values
+            with scope("photon.rmatvec"):
+                return self._sparse_hessian_diagonal(batch, d2, dim)
+        x = self._transformed_features(batch, dim)
+        return jnp.sum(d2[:, None] * jnp.square(x), axis=0) + self.l2_weight
+
+    def _sparse_hessian_diagonal(self, batch, d2: Array, dim: int) -> Array:
+        windows = getattr(batch, "windows", None)
+        if _use_windows(batch, d2):
+            # same scatter-cliff reroute as rmatvec: Σᵢ d2ᵢ·xᵢⱼ² is a
+            # windowed Xᵀ·d2 with squared stored values
+            sq_windows = windows._replace(
+                vals=jnp.square(windows.vals)
+            )
+            sq = _windowed_rmatvec_dispatch(
+                sq_windows, d2, dim, self.mesh
             )
             if self.normalization.shifts is not None:
-                lin = jax.ops.segment_sum(
-                    (batch.values * d2[:, None]).reshape(-1),
-                    flat_idx,
-                    num_segments=dim,
+                lin = _windowed_rmatvec_dispatch(
+                    windows, d2, dim, self.mesh
                 )
                 shifts = self.normalization.shifts
-                sq = sq - 2.0 * shifts * lin + jnp.square(shifts) * jnp.sum(d2)
+                sq = (
+                    sq
+                    - 2.0 * shifts * lin
+                    + jnp.square(shifts) * jnp.sum(d2)
+                )
             diag = sq
             if self.normalization.factors is not None:
                 diag = diag * jnp.square(self.normalization.factors)
             return diag + self.l2_weight
-        x = self._transformed_features(batch, dim)
-        return jnp.sum(d2[:, None] * jnp.square(x), axis=0) + self.l2_weight
+        flat_idx = batch.indices.reshape(-1)
+        sq = jax.ops.segment_sum(
+            (jnp.square(batch.values) * d2[:, None]).reshape(-1),
+            flat_idx,
+            num_segments=dim,
+        )
+        if self.normalization.shifts is not None:
+            lin = jax.ops.segment_sum(
+                (batch.values * d2[:, None]).reshape(-1),
+                flat_idx,
+                num_segments=dim,
+            )
+            shifts = self.normalization.shifts
+            sq = sq - 2.0 * shifts * lin + jnp.square(shifts) * jnp.sum(d2)
+        diag = sq
+        if self.normalization.factors is not None:
+            diag = diag * jnp.square(self.normalization.factors)
+        return diag + self.l2_weight
 
     # --- helpers ----------------------------------------------------------
 

@@ -57,6 +57,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from photon_tpu import obs
+from photon_tpu.obs.scopes import scope
 from photon_tpu.types import Array
 
 _ENV = "PHOTON_SPARSE_RMATVEC"
@@ -181,30 +183,40 @@ def build_column_windows(
     placement, where materializing the whole stream on one device first
     would be the exact single-device footprint the sharding avoids.
     """
+    with obs.span("windows.build", cat="build", num_features=num_features):
+        return _build_column_windows(
+            indices, values, num_features, window, instance_cap, chunk, host
+        )
+
+
+def _build_column_windows(
+    indices, values, num_features, window, instance_cap, chunk, host
+) -> ColumnWindows:
     arr_idx = np.ascontiguousarray(np.asarray(indices), dtype=np.int32)
     arr_val = np.asarray(values)
     n, k = arr_idx.shape
     num_windows = max(1, -(-num_features // window))
 
-    native = _native_histogram(arr_idx, arr_val, num_features)
-    if native is not None:
-        col_counts, nnz, nat_lib, nat_vals = native
-        counts = np.add.reduceat(
-            np.pad(col_counts, (0, num_windows * window - num_features)),
-            np.arange(num_windows) * window,
-        )
-    else:
-        flat_col = arr_idx.reshape(-1).astype(np.int64)
-        flat_val = arr_val.reshape(-1)
-        flat_row = np.repeat(np.arange(n, dtype=np.int64), k)
-        keep = flat_val != 0.0  # ELL padding slots carry value 0
-        flat_col, flat_val, flat_row = (
-            flat_col[keep],
-            flat_val[keep],
-            flat_row[keep],
-        )
-        nnz = flat_col.size
-        counts = np.bincount(flat_col // window, minlength=num_windows)
+    with obs.span("windows.histogram", cat="build"):
+        native = _native_histogram(arr_idx, arr_val, num_features)
+        if native is not None:
+            col_counts, nnz, nat_lib, nat_vals = native
+            counts = np.add.reduceat(
+                np.pad(col_counts, (0, num_windows * window - num_features)),
+                np.arange(num_windows) * window,
+            )
+        else:
+            flat_col = arr_idx.reshape(-1).astype(np.int64)
+            flat_val = arr_val.reshape(-1)
+            flat_row = np.repeat(np.arange(n, dtype=np.int64), k)
+            keep = flat_val != 0.0  # ELL padding slots carry value 0
+            flat_col, flat_val, flat_row = (
+                flat_col[keep],
+                flat_val[keep],
+                flat_row[keep],
+            )
+            nnz = flat_col.size
+            counts = np.bincount(flat_col // window, minlength=num_windows)
 
     # Round the spill cap itself to the instance length so FULL spill
     # instances carry zero padding — mid-stream padding (local col w−1
@@ -226,47 +238,55 @@ def build_column_windows(
     win_start = np.concatenate([[0], np.cumsum(counts)])
     w_inst += w_inst_pad
 
-    rows = np.zeros(w_inst * length, dtype=np.int32)
-    lcols = np.full(w_inst * length, window - 1, dtype=np.int32)
-
-    if native is not None:
-        vals = np.zeros(w_inst * length, dtype=np.float32)
-        if nnz > 0:  # all-padding layout needs no fill pass
-            _native_fill(
-                nat_lib, arr_idx, nat_vals, k, num_features, window, cap,
-                length, col_counts, win_start, inst_base, rows, lcols, vals,
+    # the native fill, or the argsort and scatter where the library is absent
+    with obs.span("windows.fill", cat="build", native=native is not None):
+        rows = np.zeros(w_inst * length, dtype=np.int32)
+        lcols = np.full(w_inst * length, window - 1, dtype=np.int32)
+        if native is not None:
+            vals = np.zeros(w_inst * length, dtype=np.float32)
+            if nnz > 0:  # all-padding layout needs no fill pass
+                _native_fill(
+                    nat_lib, arr_idx, nat_vals, k, num_features, window,
+                    cap, length, col_counts, win_start, inst_base, rows,
+                    lcols, vals,
+                )
+        else:
+            vals = np.zeros(w_inst * length, dtype=flat_val.dtype)
+            order = np.argsort(flat_col, kind="stable")
+            s_col, s_val, s_row = (
+                flat_col[order],
+                flat_val[order],
+                flat_row[order],
             )
-    else:
-        vals = np.zeros(w_inst * length, dtype=flat_val.dtype)
-        order = np.argsort(flat_col, kind="stable")
-        s_col, s_val, s_row = (
-            flat_col[order],
-            flat_val[order],
-            flat_row[order],
-        )
-        s_win = s_col // window
-        pos_in_win = np.arange(nnz, dtype=np.int64) - win_start[s_win]
-        dest = (inst_base[s_win] + pos_in_win // cap) * length + (
-            pos_in_win % cap
-        )
-        rows[dest] = s_row
-        lcols[dest] = s_col % window
-        vals[dest] = s_val
+            s_win = s_col // window
+            pos_in_win = np.arange(nnz, dtype=np.int64) - win_start[s_win]
+            dest = (inst_base[s_win] + pos_in_win // cap) * length + (
+                pos_in_win % cap
+            )
+            rows[dest] = s_row
+            lcols[dest] = s_col % window
+            vals[dest] = s_val
 
     inst2win = np.concatenate([
         np.repeat(np.arange(num_windows, dtype=np.int32), n_inst),
         np.full(w_inst_pad, num_windows - 1, dtype=np.int32),
     ])
     lcols2 = lcols.reshape(w_inst, length)
+    # the hand-over to the device is asynchronous and is not waited for
+    # here: the first five copies are in flight while the bounds are counted
     wrap = (lambda x: x) if host else jnp.asarray
-    return ColumnWindows(
-        rows=wrap(rows.reshape(w_inst, length)),
-        lcols=wrap(lcols2),
-        vals=wrap(vals.reshape(w_inst, length)),
-        inst2win=wrap(inst2win),
-        iota=wrap(np.arange(window, dtype=np.int32)),
-        bounds=wrap(_instance_bounds(lcols2, window)),
-    )
+    with obs.span("windows.place", cat="build", host=host):
+        placed = dict(
+            rows=wrap(rows.reshape(w_inst, length)),
+            lcols=wrap(lcols2),
+            vals=wrap(vals.reshape(w_inst, length)),
+            inst2win=wrap(inst2win),
+            iota=wrap(np.arange(window, dtype=np.int32)),
+        )
+    with obs.span("windows.bounds", cat="build"):
+        bounds = _instance_bounds(lcols2, window)
+    with obs.span("windows.place", cat="build", host=host):
+        return ColumnWindows(**placed, bounds=wrap(bounds))
 
 
 def _instance_bounds(lcols2: np.ndarray, window: int) -> np.ndarray:
@@ -295,13 +315,14 @@ def _combine(out_inst: Array, windows: ColumnWindows, dim: int) -> Array:
     """[W_inst, w] instance partials → [dim] gradient slice."""
     w = windows.window
     num_windows = max(1, -(-dim // w))
-    per_win = jax.ops.segment_sum(
-        out_inst,
-        windows.inst2win,
-        num_segments=num_windows,
-        indices_are_sorted=True,
-    )
-    return per_win.reshape(-1)[:dim]
+    with scope("photon.rmatvec.combine"):
+        per_win = jax.ops.segment_sum(
+            out_inst,
+            windows.inst2win,
+            num_segments=num_windows,
+            indices_are_sorted=True,
+        )
+        return per_win.reshape(-1)[:dim]
 
 
 def _contrib(windows: ColumnWindows, per_row: Array) -> Array:
@@ -369,16 +390,19 @@ def rmatvec_windows_prefix(
     # For biased contributions (the variance path's d2 > 0) the raw prefix
     # grows linearly in L; centered, it grows ~√L. The exact correction
     # μ·count uses the static per-column counts (bounds differences).
-    mu = jnp.mean(contrib, axis=1, keepdims=True)
-    s = jnp.cumsum(contrib - mu, axis=1)
-    s = jnp.concatenate(
-        [jnp.zeros((s.shape[0], 1), s.dtype), s], axis=1
-    )
-    g = jnp.take_along_axis(s, windows.bounds, axis=1)
-    counts = (windows.bounds[:, 1:] - windows.bounds[:, :-1]).astype(
-        contrib.dtype
-    )
-    return _combine(g[:, 1:] - g[:, :-1] + mu * counts, windows, dim)
+    with scope("photon.rmatvec.prefix"):
+        mu = jnp.mean(contrib, axis=1, keepdims=True)
+        s = jnp.cumsum(contrib - mu, axis=1)
+        s = jnp.concatenate(
+            [jnp.zeros((s.shape[0], 1), s.dtype), s], axis=1
+        )
+    with scope("photon.rmatvec.bounds"):
+        g = jnp.take_along_axis(s, windows.bounds, axis=1)
+        counts = (windows.bounds[:, 1:] - windows.bounds[:, :-1]).astype(
+            contrib.dtype
+        )
+        out_inst = g[:, 1:] - g[:, :-1] + mu * counts
+    return _combine(out_inst, windows, dim)
 
 
 #: instances per Pallas grid step — the TPU sublane rule requires the

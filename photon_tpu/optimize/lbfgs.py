@@ -22,6 +22,7 @@ from typing import Callable, NamedTuple
 import jax.numpy as jnp
 from jax import lax
 
+from photon_tpu.obs.scopes import scope
 from photon_tpu.optimize.common import (
     ConvergenceReason,
     DirectionalOracle,
@@ -179,123 +180,126 @@ def minimize_lbfgs(
         return s.reason == ConvergenceReason.NOT_CONVERGED
 
     def body(s: _LBFGSState) -> _LBFGSState:
-        direction = two_loop_direction(
-            s.g, s.s_hist, s.y_hist, s.rho, s.num_pairs, s.pos
-        )
-        # Guard: if the direction is not a descent direction (numerics), fall
-        # back to steepest descent.
-        descent = jnp.dot(direction, s.g) < 0
-        direction = jnp.where(descent, direction, -s.g)
-
-        gnorm = jnp.linalg.norm(s.g)
-        first = s.num_pairs == 0
-        init_step = jnp.where(
-            first, jnp.minimum(1.0, 1.0 / jnp.maximum(gnorm, 1e-12)), 1.0
-        ).astype(dtype)
-
-        if oracle.dir_setup is None:
-            ls = wolfe_line_search(
-                lambda x: eval_at(x)[:2],
-                s.x,
-                direction,
-                s.f,
-                s.g,
-                initial_step=init_step,
-                c1=config.ls_c1,
-                c2=config.ls_c2,
-                max_iterations=config.ls_max_iterations,
+        with scope("photon.lbfgs.direction"):
+            direction = two_loop_direction(
+                s.g, s.s_hist, s.y_hist, s.rho, s.num_pairs, s.pos
             )
-            x_new, f_new, g_new = ls.x, ls.value, ls.gradient
-            carry_new = s.carry
-            num_trials = ls.num_evals
-            passes = 2 * ls.num_evals
-        else:
-            phi, accept = oracle.dir_setup(s.carry, s.x, direction)
-            res = wolfe_search_phi(
-                phi,
-                s.f,
-                jnp.dot(s.g, direction),
-                (),
-                dtype=dtype,
-                initial_step=init_step,
-                c1=config.ls_c1,
-                c2=config.ls_c2,
-                max_iterations=config.ls_max_iterations,
-            )
-            x_new = s.x + res.step * direction
-            f_new = res.value
-            if has_box:
-                # the box path fully re-evaluates at the projected point
-                # below — don't pay accept()'s backward pass to discard it
-                g_new, carry_new = s.g, s.carry
-                passes = jnp.asarray(1, jnp.int32)  # direction margins
+            # Guard: if the direction is not a descent direction (numerics), fall
+            # back to steepest descent.
+            descent = jnp.dot(direction, s.g) < 0
+            direction = jnp.where(descent, direction, -s.g)
+
+            gnorm = jnp.linalg.norm(s.g)
+            first = s.num_pairs == 0
+            init_step = jnp.where(
+                first, jnp.minimum(1.0, 1.0 / jnp.maximum(gnorm, 1e-12)), 1.0
+            ).astype(dtype)
+
+        with scope("photon.lbfgs.linesearch"):
+            if oracle.dir_setup is None:
+                ls = wolfe_line_search(
+                    lambda x: eval_at(x)[:2],
+                    s.x,
+                    direction,
+                    s.f,
+                    s.g,
+                    initial_step=init_step,
+                    c1=config.ls_c1,
+                    c2=config.ls_c2,
+                    max_iterations=config.ls_max_iterations,
+                )
+                x_new, f_new, g_new = ls.x, ls.value, ls.gradient
+                carry_new = s.carry
+                num_trials = ls.num_evals
+                passes = 2 * ls.num_evals
             else:
-                g_new, carry_new = accept(res.step)
-                g_new = g_new.astype(dtype)
-                # one forward (direction margins) + one backward (gradient)
-                passes = jnp.asarray(2, jnp.int32)
-            num_trials = res.num_evals
-            ls = res  # for .success below
-        n_evals = s.n_evals + num_trials
-        n_passes = s.n_passes + passes
-        if has_box:
-            x_proj = project_to_box(x_new, config.lower_bounds, config.upper_bounds)
-            f_new, g_new, carry_new = eval_at(x_proj)
-            x_new = x_proj
-            n_evals = n_evals + 1
-            n_passes = n_passes + 2
+                phi, accept = oracle.dir_setup(s.carry, s.x, direction)
+                res = wolfe_search_phi(
+                    phi,
+                    s.f,
+                    jnp.dot(s.g, direction),
+                    (),
+                    dtype=dtype,
+                    initial_step=init_step,
+                    c1=config.ls_c1,
+                    c2=config.ls_c2,
+                    max_iterations=config.ls_max_iterations,
+                )
+                x_new = s.x + res.step * direction
+                f_new = res.value
+                if has_box:
+                    # the box path fully re-evaluates at the projected point
+                    # below — don't pay accept()'s backward pass to discard it
+                    g_new, carry_new = s.g, s.carry
+                    passes = jnp.asarray(1, jnp.int32)  # direction margins
+                else:
+                    g_new, carry_new = accept(res.step)
+                    g_new = g_new.astype(dtype)
+                    # one forward (direction margins) + one backward (gradient)
+                    passes = jnp.asarray(2, jnp.int32)
+                num_trials = res.num_evals
+                ls = res  # for .success below
+            n_evals = s.n_evals + num_trials
+            n_passes = s.n_passes + passes
+            if has_box:
+                x_proj = project_to_box(x_new, config.lower_bounds, config.upper_bounds)
+                f_new, g_new, carry_new = eval_at(x_proj)
+                x_new = x_proj
+                n_evals = n_evals + 1
+                n_passes = n_passes + 2
 
-        step_failed = ~ls.success
+            step_failed = ~ls.success
 
-        # Curvature pair update
-        s_vec = x_new - s.x
-        y_vec = g_new - s.g
-        sy = jnp.dot(s_vec, y_vec)
-        accept = sy > _CURVATURE_EPS
-        pos = s.pos
-        s_hist = jnp.where(
-            accept, s.s_hist.at[pos].set(s_vec), s.s_hist
-        )
-        y_hist = jnp.where(
-            accept, s.y_hist.at[pos].set(y_vec), s.y_hist
-        )
-        rho = jnp.where(
-            accept, s.rho.at[pos].set(1.0 / jnp.where(accept, sy, 1.0)), s.rho
-        )
-        pos = jnp.where(accept, (pos + 1) % m, pos)
-        num_pairs = jnp.where(accept, s.num_pairs + 1, s.num_pairs)
+        with scope("photon.lbfgs.history"):
+            # Curvature pair update
+            s_vec = x_new - s.x
+            y_vec = g_new - s.g
+            sy = jnp.dot(s_vec, y_vec)
+            accept = sy > _CURVATURE_EPS
+            pos = s.pos
+            s_hist = jnp.where(
+                accept, s.s_hist.at[pos].set(s_vec), s.s_hist
+            )
+            y_hist = jnp.where(
+                accept, s.y_hist.at[pos].set(y_vec), s.y_hist
+            )
+            rho = jnp.where(
+                accept, s.rho.at[pos].set(1.0 / jnp.where(accept, sy, 1.0)), s.rho
+            )
+            pos = jnp.where(accept, (pos + 1) % m, pos)
+            num_pairs = jnp.where(accept, s.num_pairs + 1, s.num_pairs)
 
-        it = s.it + 1
-        gnorm_new = jnp.linalg.norm(g_new)
-        reason = convergence_check(
-            it=it,
-            value=f_new,
-            prev_value=s.f,
-            grad_norm=gnorm_new,
-            loss_abs_tol=loss_abs_tol,
-            grad_abs_tol=grad_abs_tol,
-            max_iterations=t,
-            step_failed=step_failed,
-        )
+            it = s.it + 1
+            gnorm_new = jnp.linalg.norm(g_new)
+            reason = convergence_check(
+                it=it,
+                value=f_new,
+                prev_value=s.f,
+                grad_norm=gnorm_new,
+                loss_abs_tol=loss_abs_tol,
+                grad_abs_tol=grad_abs_tol,
+                max_iterations=t,
+                step_failed=step_failed,
+            )
 
-        return _LBFGSState(
-            it=it,
-            x=x_new,
-            f=f_new,
-            g=g_new,
-            prev_f=s.f,
-            s_hist=s_hist,
-            y_hist=y_hist,
-            rho=rho,
-            num_pairs=num_pairs,
-            pos=pos,
-            reason=reason,
-            loss_hist=s.loss_hist.at[it].set(f_new),
-            gnorm_hist=s.gnorm_hist.at[it].set(gnorm_new),
-            n_evals=n_evals,
-            n_passes=n_passes,
-            carry=carry_new,
-        )
+            return _LBFGSState(
+                it=it,
+                x=x_new,
+                f=f_new,
+                g=g_new,
+                prev_f=s.f,
+                s_hist=s_hist,
+                y_hist=y_hist,
+                rho=rho,
+                num_pairs=num_pairs,
+                pos=pos,
+                reason=reason,
+                loss_hist=s.loss_hist.at[it].set(f_new),
+                gnorm_hist=s.gnorm_hist.at[it].set(gnorm_new),
+                n_evals=n_evals,
+                n_passes=n_passes,
+                carry=carry_new,
+            )
 
     s = lax.while_loop(cond, body, init)
 

@@ -17,6 +17,8 @@ from typing import Callable, NamedTuple
 import jax.numpy as jnp
 from jax import lax
 
+from photon_tpu import obs
+from photon_tpu.obs.scopes import scope
 from photon_tpu.optimize.common import (
     ConvergenceReason,
     OptimizeResult,
@@ -59,6 +61,19 @@ class _OWLQNState(NamedTuple):
     loss_abs_tol: Array
     grad_abs_tol: Array
     carry: object  # margins of the smooth part at x (oracle mode), else ()
+
+
+class SegmentProgress(NamedTuple):
+    """What ``SegmentedOWLQN.advance`` reads back after a segment."""
+
+    iterations: int
+    n_evals: int  # line-search trials so far, the start point's two included
+    reason: int  # a ``ConvergenceReason`` code; 0 while the solve goes on
+    value: float  # the full objective F at the current point
+
+    @property
+    def done(self) -> bool:
+        return self.reason != int(ConvergenceReason.NOT_CONVERGED)
 
 
 def _owlqn_machinery(
@@ -138,177 +153,180 @@ def _owlqn_machinery(
         return s.reason == ConvergenceReason.NOT_CONVERGED
 
     def body(s: _OWLQNState) -> _OWLQNState:
-        pg = pseudo_gradient(s.x, s.g_smooth, l1)
-        direction = two_loop_direction(
-            pg, s.s_hist, s.y_hist, s.rho, s.num_pairs, s.pos
-        )
-        # Orthant alignment: zero any component not descending w.r.t. pg.
-        direction = jnp.where(direction * pg < 0.0, direction, 0.0)
-        # Fall back to -pg if alignment annihilated the direction.
-        degenerate = jnp.dot(direction, direction) == 0.0
-        direction = jnp.where(degenerate, -pg, direction)
-
-        # Choice orthant: sign(x), or sign(-pg) at zero coordinates.
-        xi = jnp.where(s.x != 0.0, jnp.sign(s.x), jnp.sign(-pg))
-
-        first = s.num_pairs == 0
-        pg_norm = jnp.linalg.norm(pg)
-        init_step = jnp.where(
-            first, jnp.minimum(1.0, 1.0 / jnp.maximum(pg_norm, 1e-12)), 1.0
-        ).astype(dtype)
-
-        # Backtracking line search with orthant projection.
-        def project(x_cand):
-            return jnp.where(jnp.sign(x_cand) == xi, x_cand, 0.0)
-
-        def ls_cond(carry):
-            i, step, done, *_ = carry
-            return (~done) & (i < config.ls_max_iterations)
-
-        def _armijo(x_cand, f_cand):
-            # Armijo on F with the directional derivative measured along the
-            # *projected* displacement (Andrew & Gao eq. 4).
-            dx = x_cand - s.x
-            suff = f_cand <= s.f + config.ls_c1 * jnp.dot(pg, dx)
-            moved = jnp.dot(dx, dx) > 0.0
-            return suff & moved
-
-        if oracle.value_margins is None:
-            def ls_body(carry):
-                i, step, done, x_b, f_b, g_b, ok = carry
-                x_cand = project(s.x + step * direction)
-                f_s, g_cand, _ = eval_smooth(x_cand)
-                f_cand = full_value(f_s, x_cand)
-                accept = _armijo(x_cand, f_cand)
-                return (
-                    i + 1,
-                    step * 0.5,
-                    done | accept,
-                    jnp.where(accept, x_cand, x_b),
-                    jnp.where(accept, f_cand, f_b),
-                    jnp.where(accept, g_cand, g_b),
-                    ok | accept,
-                )
-
-            ls_iters, _, _, x_new, f_new, g_new, ls_ok = lax.while_loop(
-                ls_cond,
-                ls_body,
-                (
-                    jnp.zeros((), jnp.int32),
-                    init_step,
-                    jnp.zeros((), bool),
-                    s.x,
-                    s.f,
-                    s.g_smooth,
-                    jnp.zeros((), bool),
-                ),
+        with scope("photon.owlqn.direction"):
+            pg = pseudo_gradient(s.x, s.g_smooth, l1)
+            direction = two_loop_direction(
+                pg, s.s_hist, s.y_hist, s.rho, s.num_pairs, s.pos
             )
-            carry_new = s.carry
-            passes = 2 * ls_iters
-        else:
-            # value-only trials (1 pass each); margins ride the carry so the
-            # accepted gradient is one backward pass after the loop
-            def ls_body(carry):
-                i, step, done, x_b, f_b, z_b, ok = carry
-                x_cand = project(s.x + step * direction)
-                f_s, z_cand = oracle.value_margins(x_cand)
-                f_cand = full_value(f_s.astype(dtype), x_cand)
-                accept = _armijo(x_cand, f_cand)
-                z_b = jnp.where(accept, z_cand, z_b)
-                return (
-                    i + 1,
-                    step * 0.5,
-                    done | accept,
-                    jnp.where(accept, x_cand, x_b),
-                    jnp.where(accept, f_cand, f_b),
-                    z_b,
-                    ok | accept,
-                )
+            # Orthant alignment: zero any component not descending w.r.t. pg.
+            direction = jnp.where(direction * pg < 0.0, direction, 0.0)
+            # Fall back to -pg if alignment annihilated the direction.
+            degenerate = jnp.dot(direction, direction) == 0.0
+            direction = jnp.where(degenerate, -pg, direction)
 
-            ls_iters, _, _, x_new, f_new, z_new, ls_ok = lax.while_loop(
-                ls_cond,
-                ls_body,
-                (
-                    jnp.zeros((), jnp.int32),
-                    init_step,
-                    jnp.zeros((), bool),
-                    s.x,
-                    s.f,
-                    s.carry,
-                    jnp.zeros((), bool),
-                ),
-            )
-            if has_box:
-                # the box path fully re-evaluates at the projected point —
-                # don't pay a backward pass only to discard it
-                g_new, carry_new = s.g_smooth, z_new
-                passes = ls_iters
+            # Choice orthant: sign(x), or sign(-pg) at zero coordinates.
+            xi = jnp.where(s.x != 0.0, jnp.sign(s.x), jnp.sign(-pg))
+
+            first = s.num_pairs == 0
+            pg_norm = jnp.linalg.norm(pg)
+            init_step = jnp.where(
+                first, jnp.minimum(1.0, 1.0 / jnp.maximum(pg_norm, 1e-12)), 1.0
+            ).astype(dtype)
+
+        with scope("photon.owlqn.linesearch"):
+            # Backtracking line search with orthant projection.
+            def project(x_cand):
+                return jnp.where(jnp.sign(x_cand) == xi, x_cand, 0.0)
+
+            def ls_cond(carry):
+                i, step, done, *_ = carry
+                return (~done) & (i < config.ls_max_iterations)
+
+            def _armijo(x_cand, f_cand):
+                # Armijo on F with the directional derivative measured
+                # along the *projected* displacement (Andrew & Gao eq. 4).
+                dx = x_cand - s.x
+                suff = f_cand <= s.f + config.ls_c1 * jnp.dot(pg, dx)
+                moved = jnp.dot(dx, dx) > 0.0
+                return suff & moved
+
+            if oracle.value_margins is None:
+                def ls_body(carry):
+                    i, step, done, x_b, f_b, g_b, ok = carry
+                    x_cand = project(s.x + step * direction)
+                    f_s, g_cand, _ = eval_smooth(x_cand)
+                    f_cand = full_value(f_s, x_cand)
+                    accept = _armijo(x_cand, f_cand)
+                    return (
+                        i + 1,
+                        step * 0.5,
+                        done | accept,
+                        jnp.where(accept, x_cand, x_b),
+                        jnp.where(accept, f_cand, f_b),
+                        jnp.where(accept, g_cand, g_b),
+                        ok | accept,
+                    )
+
+                ls_iters, _, _, x_new, f_new, g_new, ls_ok = lax.while_loop(
+                    ls_cond,
+                    ls_body,
+                    (
+                        jnp.zeros((), jnp.int32),
+                        init_step,
+                        jnp.zeros((), bool),
+                        s.x,
+                        s.f,
+                        s.g_smooth,
+                        jnp.zeros((), bool),
+                    ),
+                )
+                carry_new = s.carry
+                passes = 2 * ls_iters
             else:
-                g_new = oracle.grad_from_margins(x_new, z_new).astype(dtype)
-                carry_new = z_new
-                passes = ls_iters + 1
-        n_passes = s.n_passes + passes
-        if has_box:
-            # box projection after every step, like the reference OWLQN
-            # (constraintMap flows through the LBFGS base, LBFGS.scala:59-82)
-            x_proj = project_to_box(
-                x_new, config.lower_bounds, config.upper_bounds
+                # value-only trials (1 pass each); margins ride the carry so the
+                # accepted gradient is one backward pass after the loop
+                def ls_body(carry):
+                    i, step, done, x_b, f_b, z_b, ok = carry
+                    x_cand = project(s.x + step * direction)
+                    f_s, z_cand = oracle.value_margins(x_cand)
+                    f_cand = full_value(f_s.astype(dtype), x_cand)
+                    accept = _armijo(x_cand, f_cand)
+                    z_b = jnp.where(accept, z_cand, z_b)
+                    return (
+                        i + 1,
+                        step * 0.5,
+                        done | accept,
+                        jnp.where(accept, x_cand, x_b),
+                        jnp.where(accept, f_cand, f_b),
+                        z_b,
+                        ok | accept,
+                    )
+
+                ls_iters, _, _, x_new, f_new, z_new, ls_ok = lax.while_loop(
+                    ls_cond,
+                    ls_body,
+                    (
+                        jnp.zeros((), jnp.int32),
+                        init_step,
+                        jnp.zeros((), bool),
+                        s.x,
+                        s.f,
+                        s.carry,
+                        jnp.zeros((), bool),
+                    ),
+                )
+                if has_box:
+                    # the box path fully re-evaluates at the projected point —
+                    # don't pay a backward pass only to discard it
+                    g_new, carry_new = s.g_smooth, z_new
+                    passes = ls_iters
+                else:
+                    g_new = oracle.grad_from_margins(x_new, z_new).astype(dtype)
+                    carry_new = z_new
+                    passes = ls_iters + 1
+            n_passes = s.n_passes + passes
+            if has_box:
+                # box projection after every step, like the reference OWLQN
+                # (constraintMap flows through the LBFGS base, LBFGS.scala:59-82)
+                x_proj = project_to_box(
+                    x_new, config.lower_bounds, config.upper_bounds
+                )
+                f_s, g_new, carry_new = eval_smooth(x_proj)
+                f_new = full_value(f_s, x_proj)
+                x_new = x_proj
+                ls_iters = ls_iters + 1
+                n_passes = n_passes + 2
+
+        with scope("photon.owlqn.history"):
+            # History update with smooth gradients.
+            s_vec = x_new - s.x
+            y_vec = g_new - s.g_smooth
+            sy = jnp.dot(s_vec, y_vec)
+            accept_pair = sy > _CURVATURE_EPS
+            pos = s.pos
+            s_hist = jnp.where(accept_pair, s.s_hist.at[pos].set(s_vec), s.s_hist)
+            y_hist = jnp.where(accept_pair, s.y_hist.at[pos].set(y_vec), s.y_hist)
+            rho = jnp.where(
+                accept_pair,
+                s.rho.at[pos].set(1.0 / jnp.where(accept_pair, sy, 1.0)),
+                s.rho,
             )
-            f_s, g_new, carry_new = eval_smooth(x_proj)
-            f_new = full_value(f_s, x_proj)
-            x_new = x_proj
-            ls_iters = ls_iters + 1
-            n_passes = n_passes + 2
+            pos = jnp.where(accept_pair, (pos + 1) % m, pos)
+            num_pairs = jnp.where(accept_pair, s.num_pairs + 1, s.num_pairs)
 
-        # History update with smooth gradients.
-        s_vec = x_new - s.x
-        y_vec = g_new - s.g_smooth
-        sy = jnp.dot(s_vec, y_vec)
-        accept_pair = sy > _CURVATURE_EPS
-        pos = s.pos
-        s_hist = jnp.where(accept_pair, s.s_hist.at[pos].set(s_vec), s.s_hist)
-        y_hist = jnp.where(accept_pair, s.y_hist.at[pos].set(y_vec), s.y_hist)
-        rho = jnp.where(
-            accept_pair,
-            s.rho.at[pos].set(1.0 / jnp.where(accept_pair, sy, 1.0)),
-            s.rho,
-        )
-        pos = jnp.where(accept_pair, (pos + 1) % m, pos)
-        num_pairs = jnp.where(accept_pair, s.num_pairs + 1, s.num_pairs)
+            it = s.it + 1
+            pg_new = pseudo_gradient(x_new, g_new, l1)
+            pg_new_norm = jnp.linalg.norm(pg_new)
+            reason = convergence_check(
+                it=it,
+                value=f_new,
+                prev_value=s.f,
+                grad_norm=pg_new_norm,
+                loss_abs_tol=s.loss_abs_tol,
+                grad_abs_tol=s.grad_abs_tol,
+                max_iterations=t,
+                step_failed=~ls_ok,
+            )
 
-        it = s.it + 1
-        pg_new = pseudo_gradient(x_new, g_new, l1)
-        pg_new_norm = jnp.linalg.norm(pg_new)
-        reason = convergence_check(
-            it=it,
-            value=f_new,
-            prev_value=s.f,
-            grad_norm=pg_new_norm,
-            loss_abs_tol=s.loss_abs_tol,
-            grad_abs_tol=s.grad_abs_tol,
-            max_iterations=t,
-            step_failed=~ls_ok,
-        )
-
-        return _OWLQNState(
-            it=it,
-            x=x_new,
-            f=f_new,
-            g_smooth=g_new,
-            s_hist=s_hist,
-            y_hist=y_hist,
-            rho=rho,
-            num_pairs=num_pairs,
-            pos=pos,
-            reason=reason,
-            loss_hist=s.loss_hist.at[it].set(f_new),
-            gnorm_hist=s.gnorm_hist.at[it].set(pg_new_norm),
-            n_evals=s.n_evals + ls_iters,
-            n_passes=n_passes,
-            loss_abs_tol=s.loss_abs_tol,
-            grad_abs_tol=s.grad_abs_tol,
-            carry=carry_new,
-        )
+            return _OWLQNState(
+                it=it,
+                x=x_new,
+                f=f_new,
+                g_smooth=g_new,
+                s_hist=s_hist,
+                y_hist=y_hist,
+                rho=rho,
+                num_pairs=num_pairs,
+                pos=pos,
+                reason=reason,
+                loss_hist=s.loss_hist.at[it].set(f_new),
+                gnorm_hist=s.gnorm_hist.at[it].set(pg_new_norm),
+                n_evals=s.n_evals + ls_iters,
+                n_passes=n_passes,
+                loss_abs_tol=s.loss_abs_tol,
+                grad_abs_tol=s.grad_abs_tol,
+                carry=carry_new,
+            )
 
     def finalize(s: _OWLQNState) -> OptimizeResult:
         pg_final = pseudo_gradient(s.x, s.g_smooth, l1)
@@ -437,11 +455,47 @@ class SegmentedOWLQN:
             final_f,
         )
 
+    # The step handle: what ``__call__`` is written on, for a caller that
+    # paces the solve itself (a checkpoint or a deadline between segments,
+    # a benchmark that times one segment). Each piece runs in a host span,
+    # so a profiler capture shows which of them an idle device waited on.
+
+    def start(self, x0: Array, data: object = ()) -> _OWLQNState:
+        """The solve's state at ``x0``, dispatched and not waited for. Its
+        ``reason`` is NOT_CONVERGED: the first test is the first
+        segment's."""
+        with obs.span("owlqn.init", cat="solve"):
+            return self._init_f(x0, data)
+
+    def advance(
+        self, state: _OWLQNState, data: object = ()
+    ) -> tuple[_OWLQNState, SegmentProgress]:
+        """One segment on: at most ``segment_iters`` iterations on the
+        device, then the one read-back that paces the loop. Returns the
+        new state (on the device) and its counters (on the host)."""
+        import jax
+
+        with obs.span("owlqn.segment", cat="solve"):
+            state = self._segment_f(state, data)
+        with obs.span("owlqn.sync", cat="solve"):
+            # phl-ok: PHL002 the segment's ONE read-back: it paces the host loop
+            it, n_evals, reason, value = jax.device_get(
+                (state.it, state.n_evals, state.reason, state.f)
+            )
+        # phl-ok: PHL002 host scalars already, fetched by the read-back above
+        return state, SegmentProgress(int(it), int(n_evals), int(reason), float(value))
+
+    def finish(self, state: _OWLQNState, data: object = ()) -> OptimizeResult:
+        """The ``OptimizeResult`` of a solve at ``state``, on the device."""
+        with obs.span("owlqn.final", cat="solve"):
+            return self._final_f(state, data)
+
     def __call__(self, x0: Array, data: object = ()) -> OptimizeResult:
-        s = self._init_f(x0, data)
-        n_seg = 0
-        while int(s.reason) == int(ConvergenceReason.NOT_CONVERGED):
-            s = self._segment_f(s, data)
+        s = self.start(x0, data)
+        n_seg, done = 0, False
+        while not done:
+            s, progress = self.advance(s, data)
+            done = progress.done
             n_seg += 1
         self.last_num_segments = n_seg
-        return self._final_f(s, data)
+        return self.finish(s, data)

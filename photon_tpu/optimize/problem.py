@@ -120,27 +120,30 @@ class GLMProblem:
         normalization: NormalizationContext = NormalizationContext(),
         mesh=None,
     ) -> "GLMProblem":
-        loss = loss_for_task(config.task)
-        if config.optimizer == OptimizerType.TRON and not loss.twice_diff:
-            raise ValueError(
-                f"TRON requires a twice-differentiable loss; {loss.name} is not "
-                "(reference restricts smoothed hinge to LBFGS/OWLQN)"
-            )
-        l1 = config.regularization.l1_weight(config.regularization_weight)
-        l2 = config.regularization.l2_weight(config.regularization_weight)
-        if l1 > 0 and config.optimizer not in (
-            OptimizerType.LBFGS,
-            OptimizerType.OWLQN,
+        with obs.span(
+            "problem.build", cat="setup", optimizer=config.optimizer.name
         ):
-            raise ValueError("L1/elastic-net requires OWLQN")
-        objective = GLMObjective(
-            loss=loss,
-            l2_weight=l2,
-            l1_weight=l1,
-            normalization=normalization,
-            mesh=mesh,
-        )
-        return GLMProblem(config=config, objective=objective)
+            loss = loss_for_task(config.task)
+            if config.optimizer == OptimizerType.TRON and not loss.twice_diff:
+                raise ValueError(
+                    f"TRON requires a twice-differentiable loss; {loss.name} is not "
+                    "(reference restricts smoothed hinge to LBFGS/OWLQN)"
+                )
+            l1 = config.regularization.l1_weight(config.regularization_weight)
+            l2 = config.regularization.l2_weight(config.regularization_weight)
+            if l1 > 0 and config.optimizer not in (
+                OptimizerType.LBFGS,
+                OptimizerType.OWLQN,
+            ):
+                raise ValueError("L1/elastic-net requires OWLQN")
+            objective = GLMObjective(
+                loss=loss,
+                l2_weight=l2,
+                l1_weight=l1,
+                normalization=normalization,
+                mesh=mesh,
+            )
+            return GLMProblem(config=config, objective=objective)
 
     # --- solving ----------------------------------------------------------
 

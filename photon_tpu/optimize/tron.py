@@ -19,6 +19,7 @@ from typing import Callable, NamedTuple
 import jax.numpy as jnp
 from jax import lax
 
+from photon_tpu.obs.scopes import scope
 from photon_tpu.optimize.common import (
     ConvergenceReason,
     OptimizeResult,
@@ -202,88 +203,90 @@ def minimize_tron(
         return s.reason == ConvergenceReason.NOT_CONVERGED
 
     def body(s: _TronState) -> _TronState:
-        step, r, cg_iters = _truncated_cg(
-            hvp_factory(s.x),
-            s.g,
-            s.delta,
-            max_iterations=config.max_cg_iterations,
-            tolerance=config.cg_tolerance,
-        )
-        snorm = jnp.linalg.norm(step)
-        gs = jnp.dot(s.g, step)
-        prered = -0.5 * (gs - jnp.dot(step, r))
-
-        x_cand = s.x + step
-        if has_box:
-            # project into the box after the optimization step (reference
-            # TRON.scala:226-228) and evaluate at the projected point
-            x_cand = project_to_box(
-                x_cand, config.lower_bounds, config.upper_bounds
+        with scope("photon.tron.cg"):
+            step, r, cg_iters = _truncated_cg(
+                hvp_factory(s.x),
+                s.g,
+                s.delta,
+                max_iterations=config.max_cg_iterations,
+                tolerance=config.cg_tolerance,
             )
-        f_new, g_new = eval_at(x_cand)
-        actred = s.f - f_new
+        with scope("photon.tron.step"):
+            snorm = jnp.linalg.norm(step)
+            gs = jnp.dot(s.g, step)
+            prered = -0.5 * (gs - jnp.dot(step, r))
 
-        # Radius update (TRON.scala:152-251 / LIBLINEAR tron.cpp).
-        denom = f_new - s.f - gs
-        alpha = jnp.where(
-            denom <= 0, _SIGMA3, jnp.maximum(_SIGMA1, -0.5 * (gs / jnp.where(denom == 0, 1.0, denom)))
-        )
-        first = s.it == 0
-        delta = jnp.where(first, jnp.minimum(s.delta, snorm), s.delta)
-        delta = jnp.where(
-            actred < _ETA0 * prered,
-            jnp.minimum(jnp.maximum(alpha, _SIGMA1) * snorm, _SIGMA2 * delta),
-            jnp.where(
-                actred < _ETA1 * prered,
-                jnp.maximum(_SIGMA1 * delta, jnp.minimum(alpha * snorm, _SIGMA2 * delta)),
+            x_cand = s.x + step
+            if has_box:
+                # project into the box after the optimization step (reference
+                # TRON.scala:226-228) and evaluate at the projected point
+                x_cand = project_to_box(
+                    x_cand, config.lower_bounds, config.upper_bounds
+                )
+            f_new, g_new = eval_at(x_cand)
+            actred = s.f - f_new
+
+            # Radius update (TRON.scala:152-251 / LIBLINEAR tron.cpp).
+            denom = f_new - s.f - gs
+            alpha = jnp.where(
+                denom <= 0, _SIGMA3, jnp.maximum(_SIGMA1, -0.5 * (gs / jnp.where(denom == 0, 1.0, denom)))
+            )
+            first = s.it == 0
+            delta = jnp.where(first, jnp.minimum(s.delta, snorm), s.delta)
+            delta = jnp.where(
+                actred < _ETA0 * prered,
+                jnp.minimum(jnp.maximum(alpha, _SIGMA1) * snorm, _SIGMA2 * delta),
                 jnp.where(
-                    actred < _ETA2 * prered,
-                    jnp.maximum(_SIGMA1 * delta, jnp.minimum(alpha * snorm, _SIGMA3 * delta)),
-                    jnp.maximum(delta, jnp.minimum(alpha * snorm, _SIGMA3 * delta)),
+                    actred < _ETA1 * prered,
+                    jnp.maximum(_SIGMA1 * delta, jnp.minimum(alpha * snorm, _SIGMA2 * delta)),
+                    jnp.where(
+                        actred < _ETA2 * prered,
+                        jnp.maximum(_SIGMA1 * delta, jnp.minimum(alpha * snorm, _SIGMA3 * delta)),
+                        jnp.maximum(delta, jnp.minimum(alpha * snorm, _SIGMA3 * delta)),
+                    ),
                 ),
-            ),
-        )
+            )
 
-        accept = actred > _ETA0 * prered
-        x_out = jnp.where(accept, x_cand, s.x)
-        f_out = jnp.where(accept, f_new, s.f)
-        g_out = jnp.where(accept, g_new, s.g)
+            accept = actred > _ETA0 * prered
+            x_out = jnp.where(accept, x_cand, s.x)
+            f_out = jnp.where(accept, f_new, s.f)
+            g_out = jnp.where(accept, g_new, s.g)
 
-        it = s.it + 1
-        gnorm_out = jnp.linalg.norm(g_out)
-        reason = convergence_check(
-            it=it,
-            value=f_out,
-            prev_value=s.f,
-            grad_norm=gnorm_out,
-            loss_abs_tol=loss_abs_tol,
-            grad_abs_tol=grad_abs_tol,
-            max_iterations=t,
-            # A rejected step with a tiny radius cannot make progress.
-            step_failed=(~accept) & (delta <= 1e-12),
-        )
-        # A rejected step leaves the loss unchanged; don't let the
-        # function-values test fire on a rejection (reference keeps iterating
-        # with a shrunken radius).
-        reason = jnp.where(
-            (~accept)
-            & (reason == ConvergenceReason.FUNCTION_VALUES_CONVERGED),
-            ConvergenceReason.NOT_CONVERGED,
-            reason,
-        ).astype(jnp.int32)
+            it = s.it + 1
+            gnorm_out = jnp.linalg.norm(g_out)
+            reason = convergence_check(
+                it=it,
+                value=f_out,
+                prev_value=s.f,
+                grad_norm=gnorm_out,
+                loss_abs_tol=loss_abs_tol,
+                grad_abs_tol=grad_abs_tol,
+                max_iterations=t,
+                # A rejected step with a tiny radius cannot make progress.
+                step_failed=(~accept) & (delta <= 1e-12),
+            )
+            # A rejected step leaves the loss unchanged; don't let the
+            # function-values test fire on a rejection (reference keeps iterating
+            # with a shrunken radius).
+            reason = jnp.where(
+                (~accept)
+                & (reason == ConvergenceReason.FUNCTION_VALUES_CONVERGED),
+                ConvergenceReason.NOT_CONVERGED,
+                reason,
+            ).astype(jnp.int32)
 
-        return _TronState(
-            it=it,
-            x=x_out,
-            f=f_out,
-            g=g_out,
-            delta=delta,
-            reason=reason,
-            loss_hist=s.loss_hist.at[it].set(f_out),
-            gnorm_hist=s.gnorm_hist.at[it].set(gnorm_out),
-            n_evals=s.n_evals + 1,
-            n_hvp=s.n_hvp + cg_iters,
-        )
+            return _TronState(
+                it=it,
+                x=x_out,
+                f=f_out,
+                g=g_out,
+                delta=delta,
+                reason=reason,
+                loss_hist=s.loss_hist.at[it].set(f_out),
+                gnorm_hist=s.gnorm_hist.at[it].set(gnorm_out),
+                n_evals=s.n_evals + 1,
+                n_hvp=s.n_hvp + cg_iters,
+            )
 
     s = lax.while_loop(cond, body, init)
 

@@ -1,12 +1,12 @@
 """Cross-cutting utilities (reference photon-lib/photon-client ``util/`` and
-``event/`` packages): block timing, persistent job logging, lifecycle events,
-date-partitioned input resolution, and profiler tracing."""
+``event/`` packages): block timing, persistent job logging, lifecycle events
+and date-partitioned input resolution. Profiler annotations are
+``photon_tpu.obs.span``'s."""
 from photon_tpu.util.dates import DateRange, DaysRange, resolve_date_range_paths
 from photon_tpu.util.events import Event, EventEmitter, EventListener
 from photon_tpu.util.io_utils import prepare_output_dir
 from photon_tpu.util.logging import PhotonLogger
 from photon_tpu.util.timed import Timed, timed
-from photon_tpu.util.profiler import trace_phase
 
 __all__ = [
     "DateRange",
@@ -19,5 +19,4 @@ __all__ = [
     "prepare_output_dir",
     "resolve_date_range_paths",
     "timed",
-    "trace_phase",
 ]

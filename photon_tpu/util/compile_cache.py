@@ -11,6 +11,8 @@ from __future__ import annotations
 
 import os
 
+from photon_tpu import obs
+
 #: ``<checkout>/.jax_cache`` (listed in .gitignore)
 DEFAULT_CACHE_DIR = os.path.join(
     os.path.dirname(
@@ -27,8 +29,10 @@ def enable_persistent_cache() -> str:
     harness) — is left as it is."""
     import jax
 
-    if jax.config.jax_compilation_cache_dir is None:
-        jax.config.update("jax_compilation_cache_dir", DEFAULT_CACHE_DIR)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
-    return jax.config.jax_compilation_cache_dir
+    with obs.span("compile_cache.enable", cat="setup") as sp:
+        if jax.config.jax_compilation_cache_dir is None:
+            jax.config.update("jax_compilation_cache_dir", DEFAULT_CACHE_DIR)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
+        sp.set(directory=jax.config.jax_compilation_cache_dir)
+        return jax.config.jax_compilation_cache_dir

@@ -3,6 +3,7 @@ widths stay; rows and entities shrink). ``main`` itself refuses to run
 without a TPU — that refusal is tested too."""
 import json
 import os
+import time
 
 import numpy as np
 import pytest
@@ -30,6 +31,7 @@ def tiny(monkeypatch, tmp_path):
 def test_train_score_serve_phases(tiny, capsys):
     workdir, sizes, data = tiny
     devices = jax.devices()[:1]
+    began = time.perf_counter()
     out = chip_smoke.training_phase(
         "train",
         chip_smoke.training_args(workdir, os.path.join(workdir, "train_out")),
@@ -46,8 +48,9 @@ def test_train_score_serve_phases(tiny, capsys):
         if line.startswith("{")
     ]
     assert [row["phase"] for row in lines] == ["train", "score", "serve"]
-    assert lines[0]["sweep_compiles"][1] == 0
-    assert lines[2]["compiles_while_serving"] == 0
+    compiled = chip_smoke.compiled_since(began)
+    assert lines[0]["sweep_compiles"][1] == 0, compiled
+    assert lines[2]["compiles_while_serving"] == 0, compiled
 
 
 def test_meshed_fit_matches_one_device(tiny):

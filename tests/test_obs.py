@@ -129,6 +129,46 @@ def test_disabled_tracer_measures_but_records_nothing():
     assert tr.spans() == []
 
 
+def test_disabled_span_still_enters_a_photon_annotation(monkeypatch):
+    """A profiler capture sees the program's host phases with telemetry
+    off: a disabled tracer's span enters ``photon.<name>`` (no IDs: none
+    are minted) and still records nothing; an enabled one stamps its ID."""
+    import jax.profiler
+
+    log = []
+
+    class Annotation:
+        def __init__(self, name, **meta):
+            self.name, self.meta = name, meta
+
+        def __enter__(self):
+            log.append(("enter", self.name, self.meta))
+
+        def __exit__(self, *exc):
+            log.append(("exit", self.name))
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Annotation)
+    tr = Tracer(enabled=False)
+    with tr.span("quiet", cat="phase", k=1) as sp:
+        assert log == [("enter", "photon.quiet", {})]
+    assert log[-1] == ("exit", "photon.quiet")
+    assert sp.duration_s >= 0 and tr.spans() == []
+
+    del log[:]
+    on = Tracer(enabled=True)
+    with on.span("loud"):
+        pass
+    (rec,) = on.spans()
+    assert log == [
+        ("enter", "photon.loud", {"span_id": rec.span_id}),
+        ("exit", "photon.loud"),
+    ]
+    del log[:]
+    with Tracer(enabled=False, annotate_device=False).span("silent"):
+        pass
+    assert log == []
+
+
 def test_span_records_error_class_on_exception():
     tr = Tracer(enabled=True, annotate_device=False)
     with pytest.raises(RuntimeError):

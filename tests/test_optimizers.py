@@ -285,3 +285,65 @@ def test_segmented_owlqn_oracle_factory_data_as_argument():
     np.testing.assert_allclose(
         np.asarray(ref.x), np.asarray(seg.x), rtol=5e-4, atol=1e-5
     )
+
+
+def test_segmented_owlqn_step_handle_is_the_call_in_pieces():
+    """``start``/``advance``/``finish`` are what ``__call__`` runs: the same
+    bits as ``__call__`` and as the private trio driven by hand, one
+    read-back a segment, and one host span a piece."""
+    from photon_tpu import obs
+    from photon_tpu.optimize.common import ConvergenceReason
+    from photon_tpu.optimize.owlqn import SegmentedOWLQN
+
+    rng = np.random.default_rng(13)
+    A = jnp.asarray(rng.normal(size=(200, D)).astype(np.float32))
+    b = jnp.asarray(rng.normal(size=200).astype(np.float32))
+
+    def vg(x):
+        r = A @ x - b
+        return 0.5 * jnp.dot(r, r), A.T @ r
+
+    cfg = OptimizerConfig(max_iterations=60, tolerance=1e-9)
+    solver = SegmentedOWLQN(vg, 0.3, cfg, segment_iters=2)
+    x0 = jnp.zeros((D,), jnp.float32)
+    whole = solver(x0)
+    n_seg = solver.last_num_segments
+    assert n_seg >= 2
+
+    s = solver._init_f(x0, ())
+    by_hand = 0
+    while int(s.reason) == int(ConvergenceReason.NOT_CONVERGED):
+        s = solver._segment_f(s, ())
+        by_hand += 1
+    trio = solver._final_f(s, ())
+    assert by_hand == n_seg
+
+    obs.reset()
+    obs.enable()
+    try:
+        state = solver.start(x0)
+        seen = []
+        while not seen or not seen[-1].done:
+            state, progress = solver.advance(state)
+            seen.append(progress)
+        stepped = solver.finish(state)
+        spans = [r.name for r in obs.get_tracer().spans()]
+    finally:
+        obs.reset()
+        obs.disable()
+    assert len(seen) == n_seg
+    assert [p.done for p in seen] == [False] * (n_seg - 1) + [True]
+    assert seen[-1].iterations == int(whole.iterations)
+    assert seen[-1].n_evals == int(whole.n_evals)
+    assert seen[-1].reason == int(whole.reason)
+    assert seen[-1].value == float(whole.value)
+    assert all(a.iterations < b.iterations for a, b in zip(seen, seen[1:]))
+    for got in (trio, stepped):
+        for name in whole._fields:
+            np.testing.assert_array_equal(
+                np.asarray(getattr(got, name)), np.asarray(getattr(whole, name))
+            )
+    assert {n: spans.count(n) for n in set(spans)} == {
+        "owlqn.init": 1, "owlqn.segment": n_seg, "owlqn.sync": n_seg,
+        "owlqn.final": 1,
+    }

@@ -1,0 +1,333 @@
+"""The ``photon.*`` device scopes: the table and the code agree, the two
+benchmark cells' solver programs carry every scope their path uses, every
+instruction that reads the data sits under one, and a scope changes nothing
+but metadata.
+
+The programs are compiled here for the CPU at the cells' ``rehearse``
+shapes, with the TPU-only branches steered by the knobs the program already
+has (``PHOTON_SPARSE_WINDOWS``, ``PHOTON_SPARSE_RMATVEC``,
+``PHOTON_SPARSE_GATHER``). The persistent compile cache is off around them:
+metadata is not part of its key, so a program served from it would carry
+the scopes of whichever tree wrote the entry.
+"""
+import contextlib
+import json
+import os
+import re
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from photon_tpu.analysis import hlo
+from photon_tpu.obs import scopes
+from photon_tpu.obs.scopes import SCOPES
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+PACKAGE = os.path.join(ROOT, "photon_tpu")
+
+SPARSE_PATH = {
+    "photon.matvec", "photon.rmatvec", "photon.gather", "photon.loss",
+    "photon.rmatvec.prefix", "photon.rmatvec.bounds", "photon.rmatvec.combine",
+    "photon.owlqn.direction", "photon.owlqn.linesearch", "photon.owlqn.history",
+}
+DENSE_PATH = {
+    "photon.matvec", "photon.rmatvec", "photon.loss", "photon.hvp",
+    "photon.tron.cg", "photon.tron.step",
+}
+#: instructions that hand a value on unchanged or enclose others: they read
+#: no data themselves
+PASS_THROUGH = {
+    "parameter", "tuple", "get-tuple-element", "while", "conditional",
+    "call", "bitcast", "copy", "constant",
+}
+
+
+# --- the table and the code ------------------------------------------------
+
+
+def _scope_calls() -> dict[str, list[str]]:
+    """Every literal name passed to ``scope(...)`` in the package -> the
+    files that pass it."""
+    found: dict[str, list[str]] = {}
+    for dirpath, _, files in os.walk(PACKAGE):
+        for name in files:
+            if not name.endswith(".py"):
+                continue
+            path = os.path.join(dirpath, name)
+            with open(path) as f:
+                text = f.read()
+            for m in re.finditer(r'\bscope\(\s*"([^"]+)"', text):
+                found.setdefault(m.group(1), []).append(
+                    os.path.relpath(path, ROOT)
+                )
+    return found
+
+
+@pytest.mark.parametrize("name", sorted(SCOPES))
+def test_every_scope_of_the_table_is_used(name):
+    assert name in _scope_calls(), f"{name} is in SCOPES and no code opens it"
+    layer, meaning = SCOPES[name]
+    assert layer and meaning and "\n" not in meaning
+
+
+def test_every_scope_the_code_opens_is_in_the_table():
+    calls = _scope_calls()
+    assert calls, "found no scope(...) call: the scan is broken"
+    stray = {n: files for n, files in calls.items() if n not in SCOPES}
+    assert not stray, f"scopes opened but not in SCOPES: {stray}"
+    with pytest.raises(KeyError):
+        scopes.scope("photon.not_in_the_table")
+
+
+def test_named_scope_is_used_through_the_helper_only():
+    users = []
+    for dirpath, _, files in os.walk(PACKAGE):
+        for name in files:
+            path = os.path.join(dirpath, name)
+            if name.endswith(".py") and "named_scope(" in open(path).read():
+                users.append(os.path.relpath(path, PACKAGE))
+    assert users == [os.path.join("obs", "scopes.py")]
+
+
+# --- the two cells' programs -----------------------------------------------
+
+
+@pytest.fixture()
+def fresh_compiles():
+    """No persistent cache (module docstring)."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _config(name: str) -> dict:
+    with open(os.path.join(ROOT, "benchmarks", "configs", f"{name}.json")) as f:
+        config = json.load(f)
+    return {**config, **config["rehearse"]}
+
+
+def _sparse_segment_program(monkeypatch):
+    """``sparse_poisson``'s ``SegmentedOWLQN`` segment, window layout forced
+    on, as the TPU takes it: prefix rmatvec, chunked gather."""
+    from photon_tpu.ops.losses import loss_for_task
+    from photon_tpu.ops.objective import GLMObjective
+    from photon_tpu.ops.sparse_windows import maybe_build_windows
+    from photon_tpu.optimize.common import OptimizerConfig
+    from photon_tpu.optimize.owlqn import SegmentedOWLQN
+    from photon_tpu.types import SparseBatch, TaskType
+
+    monkeypatch.setenv("PHOTON_SPARSE_WINDOWS", "1")
+    monkeypatch.setenv("PHOTON_SPARSE_RMATVEC", "prefix")
+    monkeypatch.setenv("PHOTON_SPARSE_GATHER", "chunked")
+    config = _config("sparse_poisson")
+    feat, solver = config["features"], config["solver"]
+    n, d, k = feat["n"], feat["d"], feat["nnz_per_row"]
+    rng = np.random.default_rng(0)
+    idx = rng.integers(0, d, size=(n, k), dtype=np.int32)
+    vals = rng.normal(size=(n, k)).astype(np.float32)
+    windows = maybe_build_windows(idx, vals, d)
+    assert windows is not None and windows.bounds is not None
+    batch = SparseBatch(
+        indices=jnp.asarray(idx),
+        values=jnp.asarray(vals),
+        labels=jnp.ones((n,), jnp.float32),
+        offsets=jnp.zeros((n,), jnp.float32),
+        weights=jnp.ones((n,), jnp.float32),
+        windows=windows,
+    )
+    lam, alpha = solver["regularization_weight"], solver["elastic_net_alpha"]
+    objective = GLMObjective(
+        loss=loss_for_task(TaskType[config["task"]]),
+        l2_weight=(1 - alpha) * lam,
+        l1_weight=alpha * lam,
+    )
+    seg = SegmentedOWLQN(
+        None,
+        alpha * lam,
+        OptimizerConfig(
+            max_iterations=solver["max_iterations"],
+            tolerance=solver["tolerance"],
+        ),
+        oracle_factory=objective.smooth_margin_oracle,
+        segment_iters=solver["segment_iters"],
+    )
+    state = jax.eval_shape(seg._init_f, jnp.zeros((d,), jnp.float32), batch)
+    data_shapes = {
+        _shape(batch.indices), _shape(batch.values), _shape(windows.rows),
+        _shape(windows.vals), _shape(windows.bounds),
+    }
+    return seg._segment_f.lower(state, batch).compile(), data_shapes
+
+
+def _dense_tron_program(monkeypatch):
+    """``linear_tron``'s whole TRON solve under one jit, as the benchmark's
+    runner builds it."""
+    from photon_tpu.optimize.common import OptimizerConfig
+    from photon_tpu.optimize.problem import (
+        GLMProblem,
+        GLMProblemConfig,
+        RegularizationContext,
+        RegularizationType,
+    )
+    from photon_tpu.types import LabeledBatch, OptimizerType, TaskType
+
+    config = _config("linear_tron")
+    feat, solver = config["features"], config["solver"]
+    n, d = feat["n"], feat["d"]
+    problem = GLMProblem.build(
+        GLMProblemConfig(
+            task=TaskType[config["task"]],
+            optimizer=OptimizerType.TRON,
+            optimizer_config=OptimizerConfig(
+                max_iterations=solver["max_iterations"],
+                tolerance=solver["tolerance"],
+                max_cg_iterations=solver["max_cg_iterations"],
+                cg_tolerance=solver["cg_tolerance"],
+            ),
+            regularization=RegularizationContext(RegularizationType.L2),
+            regularization_weight=solver["l2_weight"],
+        )
+    )
+    batch = LabeledBatch(
+        features=jax.ShapeDtypeStruct((n, d), jnp.float32),
+        labels=jax.ShapeDtypeStruct((n,), jnp.float32),
+        offsets=jax.ShapeDtypeStruct((n,), jnp.float32),
+        weights=jax.ShapeDtypeStruct((n,), jnp.float32),
+    )
+
+    @jax.jit
+    def tron_solve(batch, w0):
+        return problem.solve(batch, w0)
+
+    w0 = jax.ShapeDtypeStruct((d,), jnp.float32)
+    return tron_solve.lower(batch, w0).compile(), {_shape(batch.features)}
+
+
+def _shape(a) -> str:
+    """``f32[16384,8]`` as HLO text writes an operand's type."""
+    dtype = {"float32": "f32", "int32": "s32"}[str(a.dtype)]
+    return f"{dtype}[{','.join(str(s) for s in a.shape)}]"
+
+
+PROGRAMS = {
+    "sparse_poisson": (_sparse_segment_program, SPARSE_PATH),
+    "linear_tron": (_dense_tron_program, DENSE_PATH),
+}
+
+
+def _scoped_modules():
+    """Every module that took ``scope`` by name."""
+    import importlib
+
+    files = {f for fs in _scope_calls().values() for f in fs}
+    names = {f[: -len(".py")].replace(os.sep, ".") for f in files}
+    return [importlib.import_module(n) for n in sorted(names)]
+
+
+@pytest.mark.parametrize("cell", sorted(PROGRAMS))
+def test_cell_program_carries_its_scopes(cell, monkeypatch, fresh_compiles):
+    build, expected = PROGRAMS[cell]
+    compiled, data_shapes = build(monkeypatch)
+    scope_of = hlo.instruction_scopes(compiled)
+    assert expected <= set(scope_of.values()), (
+        f"missing: {sorted(expected - set(scope_of.values()))}"
+    )
+    assert set(scope_of.values()) <= set(SCOPES)
+    # every instruction that reads the feature block, the index stream or
+    # the window layout does so under a photon.* scope
+    instrs = hlo.parse_instructions(compiled)
+    fused = {ins.calls for ins in instrs.values() if ins.calls}
+    readers, outside = 0, []
+    for ins in instrs.values():
+        # what a trace names: not the inside of a fusion
+        if ins.opcode in PASS_THROUGH or ins.computation in fused:
+            continue
+        operand_types = {
+            instrs[name].shape.split("{")[0]
+            for name in ins.operand_names
+            if name in instrs
+        }
+        if data_shapes & operand_types:
+            readers += 1
+            if ins.name not in scope_of:
+                outside.append((ins.name, ins.opcode, ins.op_name))
+    assert readers > 0, "no instruction reads the data: the shapes are wrong"
+    assert not outside, f"read the data under no photon.* scope: {outside}"
+    # the per-scope sum loses no time and names what it could not place
+    seconds = {name: 1.0 for name in instrs}
+    by_scope = hlo.seconds_by_scope(seconds, scope_of)
+    assert sum(by_scope.values()) == pytest.approx(len(seconds))
+    assert by_scope[hlo.UNSCOPED] == len(seconds) - len(scope_of)
+
+
+@pytest.mark.parametrize("cell", sorted(PROGRAMS))
+def test_scopes_change_nothing_but_metadata(cell, monkeypatch, fresh_compiles):
+    build, _ = PROGRAMS[cell]
+    scoped = build(monkeypatch)[0].as_text()
+    for module in _scoped_modules():
+        monkeypatch.setattr(
+            module, "scope", lambda name: contextlib.nullcontext()
+        )
+    bare = build(monkeypatch)[0].as_text()
+    assert "photon." in scoped and "photon." not in bare
+    assert hlo.strip_metadata(scoped) == hlo.strip_metadata(bare)
+    assert "metadata=" not in hlo.strip_metadata(scoped)
+
+
+def test_scope_path_and_fusion_root_and_inheritance():
+    text = """
+HloModule m
+
+%fused_computation (p: f32[8]) -> f32[8] {
+  %p = f32[8]{0} parameter(0)
+  ROOT %add.1 = f32[8]{0} add(%p, %p), metadata={op_name="jit(f)/photon.matvec/photon.gather/add"}
+}
+
+%wide.body (s: (s32[], f32[8])) -> (s32[], f32[8]) {
+  %s = (s32[], f32[8]{0}) parameter(0)
+  %get-tuple-element.7 = f32[8]{0} get-tuple-element(%s), index=1
+  %dynamic-update-slice.9 = f32[8]{0} dynamic-update-slice(%get-tuple-element.7, %get-tuple-element.7)
+  %get-tuple-element.8 = s32[] get-tuple-element(%s), index=0
+  ROOT %tuple.2 = (s32[], f32[8]{0}) tuple(%get-tuple-element.8, %dynamic-update-slice.9)
+}
+
+ENTRY %main (a: f32[8], b: (s32[], f32[8])) -> f32[8] {
+  %a = f32[8]{0} parameter(0)
+  %b = (s32[], f32[8]{0}) parameter(1)
+  %while.5 = (s32[], f32[8]{0}) while(%b), condition=%wide.cond, body=%wide.body
+  %get-tuple-element.6 = f32[8]{0} get-tuple-element(%while.5), index=1
+  %fusion.3 = f32[8]{0} fusion(%get-tuple-element.6), kind=kLoop, calls=%fused_computation
+  %reduce-window.1 = f32[8]{0} reduce-window(%fusion.3, %a), window={size=8}, metadata={op_name="reduce_window_sum"}
+  %neg.2 = f32[8]{0} negate(%a), metadata={op_name="jit(f)/neg"}
+  %exp.3 = f32[8]{0} exponential(%neg.2), metadata={op_name="jit(f)/exp"}
+  ROOT %mul.4 = f32[8]{0} multiply(%reduce-window.1, %a), metadata={op_name="jit(f)/while/body/photon.loss/mul" stack_frame_id=3}
+}
+"""
+    paths = hlo.instruction_scope_paths(text)
+    gather = ("photon.matvec", "photon.gather")
+    assert paths["add.1"] == gather
+    assert paths["fusion.3"] == gather  # its root's
+    assert paths["reduce-window.1"] == gather  # its producer's
+    assert paths["while.5"] == gather  # a generated loop: its user's
+    assert paths["dynamic-update-slice.9"] == gather  # and the loop's body
+    for moves_nothing in ("a", "b", "s", "get-tuple-element.6", "tuple.2"):
+        assert moves_nothing not in paths
+    assert "neg.2" not in paths and "exp.3" not in paths  # reach no scope
+    assert hlo.instruction_scopes(text) == {
+        "add.1": "photon.gather", "fusion.3": "photon.gather",
+        "reduce-window.1": "photon.gather", "while.5": "photon.gather",
+        "dynamic-update-slice.9": "photon.gather", "mul.4": "photon.loss",
+    }
+    by_path = hlo.seconds_by_scope({"fusion.3": 2.0, "neg.2": 0.5}, paths)
+    assert by_path == {"photon.matvec/photon.gather": 2.0, hlo.UNSCOPED: 0.5}
+    stripped = hlo.strip_metadata(text)
+    assert "metadata" not in stripped
+    assert "multiply(%reduce-window.1, %a)" in stripped

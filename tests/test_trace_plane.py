@@ -2,7 +2,7 @@
 head-sampling and worst-K exemplar retention, the Chrome-trace export's
 flow hygiene and schema contract, fan-in de-duplication through the
 serving engine, fault-instant attachment, the fixed serve stage enum,
-trace_phase's device-annotation bridge, concurrent /slo + /trace scrapes
+obs.span's device-annotation bridge, concurrent /slo + /trace scrapes
 under live traffic, and the bench trace-overhead band semantics."""
 from __future__ import annotations
 
@@ -387,20 +387,40 @@ def test_scoring_stream_chain_validates_with_faults():
 # -- tracer bridge ----------------------------------------------------------
 
 
-def test_trace_phase_bridges_to_obs_span_with_trace_id():
-    from photon_tpu.util.profiler import trace_phase
+def test_obs_span_annotation_carries_span_and_trace_id(monkeypatch):
+    """What ``trace_phase`` was for, now ``obs.span``'s own: inside a causal
+    trace the span's profiler annotation is stamped with its span ID and
+    the trace ID, so a device-profiler slice joins back to both."""
+    import jax.profiler
 
+    entered = []
+
+    class Annotation:
+        def __init__(self, name, **meta):
+            self.row = (name, meta)
+
+        def __enter__(self):
+            entered.append(self.row)
+
+        def __exit__(self, *exc):
+            return None
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Annotation)
     obs.enable()
     causal.install(sample_n=1)
     ctx = causal.mint("req")
     with ctx.active():
         assert causal.current_trace_id() == ctx.trace_id
-        with trace_phase("unit_phase"):
+        with obs.span("unit_phase", cat="device"):
             pass
     (rec,) = [
         r for r in obs.get_tracer().spans() if r.name == "unit_phase"
     ]
     assert rec.cat == "device"
+    assert entered == [
+        ("photon.unit_phase",
+         {"span_id": rec.span_id, "trace_id": ctx.trace_id})
+    ]
     assert causal.current_trace_id() is None
 
 

@@ -16,7 +16,6 @@ from photon_tpu.util import (
     prepare_output_dir,
     resolve_date_range_paths,
     timed,
-    trace_phase,
 )
 
 
@@ -148,11 +147,6 @@ def test_prepare_output_dir(tmp_path):
         prepare_output_dir(out)
     prepare_output_dir(out, override=True)
     assert os.path.isdir(out) and not os.listdir(out)
-
-
-def test_trace_phase_noop():
-    with trace_phase("anything"):
-        pass
 
 
 def test_put_with_retry_transient_then_success(caplog):
