@@ -24,6 +24,8 @@ SCOPES: dict[str, tuple[str, str]] = {
     "photon.matvec": ("kernels", "X.v: margins from coefficients, dense or sparse ELL"),
     "photon.rmatvec": ("kernels", "X^T.r: gradient side, dense, flat scatter or windowed"),
     "photon.gather": ("kernels", "1-element table gather: row fetch and lane select, or the plain gather"),
+    "photon.gather.fetch": ("kernels", "the gather's first half: the 128-lane row each element lives in, one segment's block"),
+    "photon.gather.select": ("kernels", "the gather's second half: each element's lane out of its fetched row"),
     "photon.rmatvec.prefix": ("kernels", "windowed X^T.r: centring and the cumsum of contributions"),
     "photon.rmatvec.bounds": ("kernels", "windowed X^T.r: prefix sums read at the static column bounds"),
     "photon.rmatvec.combine": ("kernels", "windowed X^T.r: instance partials summed into their windows"),

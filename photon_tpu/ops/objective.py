@@ -25,6 +25,7 @@ NormalizationContext is active (see ops/normalization.py).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import os
 from typing import Callable
 
@@ -59,23 +60,14 @@ def matvec(batch, v: Array) -> Array:
 
 def _matvec(batch, v: Array) -> Array:
     if isinstance(batch, SparseBatch):
-        from photon_tpu.ops.gather import take_1d
-
-        # take_1d: XLA:TPU's element gather serializes at ~110M elem/s;
-        # the chunked row-fetch form is bandwidth-bound (ops/gather.py).
         # PHOTON_SPARSE_BF16_TABLE=1 stores the gathered coefficient
-        # table bf16: the row fetch is the dominant HBM stream (128·
-        # itemsize B per useful element), so halving the table halves
-        # the fetched bytes; products accumulate in f32. Opt-in until
-        # the on-chip A/B lands (trace-time binding, like the gather
-        # strategy knob).
+        # table bf16: the fetched rows are 128·itemsize B per useful
+        # element, so halving the table halves them; products accumulate
+        # in f32. Opt-in (trace-time binding, like the gather strategy
+        # knob); a bf16 table is a different result.
         if os.environ.get("PHOTON_SPARSE_BF16_TABLE", "0") == "1":
-            tv = take_1d(v.astype(jnp.bfloat16), batch.indices).astype(
-                jnp.float32
-            )
-        else:
-            tv = take_1d(v, batch.indices)
-        return jnp.sum(tv * batch.values, axis=-1)
+            v = v.astype(jnp.bfloat16)
+        return _ell_matvec(v, batch.indices, batch.values)
     x = batch.features
     if x.dtype == jnp.bfloat16:
         return jax.lax.dot_general(
@@ -85,6 +77,36 @@ def _matvec(batch, v: Array) -> Array:
             preferred_element_type=jnp.float32,
         )
     return x @ v
+
+
+def _ell_matvec(table: Array, indices: Array, values: Array) -> Array:
+    """Σₖ table[indices[:, k]] · values[:, k] over a padded-ELL block.
+
+    XLA:TPU's element gather is serialized, so on a TPU the K coefficient
+    slots of a row come through ops/gather's row fetch and lane select.
+    Where the block's fetched rows pass ``_SEG_BYTES`` the pass runs the
+    segment loop itself: a segment is a block of R rows with all K slots,
+    taken by a dynamic slice of the [K, n] view (how the [n, K] arrays lie
+    on the chip, slot-major: the transpose is a relabelling), and the body
+    (``gather.fetch_select_dot``) fetches, selects, multiplies by the values
+    and sums over K, so the loop stacks [R] margins and the fetched rows
+    never leave fast memory."""
+    from photon_tpu.ops import gather
+
+    if indices.ndim == 2 and gather.gather_strategy(table) == "chunked":
+        plan = gather.segment_plan(
+            *indices.shape, table.dtype.itemsize, 128
+        )
+        if plan.steps > 1:
+            t2 = gather.lane_rows(table)
+            rows_block = functools.partial(gather.fetch_select_dot, t2)
+            return gather.map_segments(
+                rows_block, (indices.T, values.T), plan, axis=1
+            )
+    tv = gather.take_1d(table, indices)
+    if table.dtype == jnp.bfloat16:  # products still accumulate in f32
+        tv = tv.astype(jnp.float32)
+    return jnp.sum(tv * values, axis=-1)
 
 
 def _use_windows(batch, per_row: Array) -> bool:

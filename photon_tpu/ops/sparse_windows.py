@@ -26,8 +26,16 @@ The fix is a build-time layout + an MXU trick:
   pure-XLA ``lax.scan`` fallback computes the identical algebra for
   CPU/debug, and a flat pre-sorted ``segment_sum`` variant exists for
   comparison (padding uses local col w−1 so flat indices stay sorted).
-- **Gather side stays XLA**: contrib = vals · r[rows] is a gather from a
-  [N] vector, which XLA handles well; only the scatter needed rescue.
+- **The gather side is the floor**: contrib = vals · r[rows] is a
+  1-element gather from a [N] vector, which XLA:TPU serializes; it goes
+  through ops/gather's row fetch and lane select and is 75 % of a
+  backward pass (PERF.md §5). ``_over_instances`` runs it in the segment
+  loop of the pass: a segment is a block of whole instances whose fetched
+  rows fit fast memory (``gather.segment_plan``: 32 instances of 4096
+  slots), the loop's body carries the variant's consumer (the prefix
+  variant's centring, cumsum and bounds reads, [I, L] → [I, w]), and the
+  build pads the instance count to whole segments, so [W_inst, L] is
+  read as it lies and no pass pads, slices or stacks a value per slot.
 
 Instance partials combine with one [W_inst, w] → [W, w] sorted
 segment-sum (thousands of rows, not millions — off the cliff).
@@ -63,6 +71,9 @@ from photon_tpu.types import Array
 
 _ENV = "PHOTON_SPARSE_RMATVEC"
 
+#: the TPU sublane rule: the second-to-last dim of a block is a multiple of 8
+_SUBLANES = 8
+
 
 class ColumnWindows(NamedTuple):
     """Static column-sorted instance layout (see module docstring).
@@ -75,8 +86,9 @@ class ColumnWindows(NamedTuple):
     (bounds[i, c] = #slots in instance i with lcol < c) — static segment
     boundaries for the prefix-sum rmatvec; ``None`` on layouts built before
     the field existed. Padding slots: row 0, local col w−1, value 0.
-    W_inst is padded to a multiple of 8 at build time (inert instances) so
-    the Pallas block shape (8, L) satisfies the TPU sublane rule.
+    W_inst is padded at build time (inert instances) to a multiple of 8, so
+    the Pallas block shape (8, L) satisfies the TPU sublane rule, and of
+    the instances per segment of the backward pass (``instance_multiple``).
     """
 
     rows: Array
@@ -93,6 +105,18 @@ class ColumnWindows(NamedTuple):
     @property
     def instance_len(self) -> int:
         return self.rows.shape[1]
+
+
+def instance_multiple(w_inst: int, length: int, itemsize: int) -> int:
+    """What a layout's instance count is padded to a multiple of: the
+    instances in a segment of the backward pass (ops/gather.segment_plan
+    for a per-row table of ``itemsize``-byte entries), or 8 where the pass
+    is one segment."""
+    from photon_tpu.ops.gather import segment_plan
+
+    w_inst += (-w_inst) % _SUBLANES
+    plan = segment_plan(w_inst, length, itemsize, _SUBLANES)
+    return _SUBLANES if plan.steps == 1 else plan.per
 
 
 def _native_histogram(arr_idx, arr_val, num_features):
@@ -230,10 +254,14 @@ def _build_column_windows(
     length = cap
     n_inst = np.maximum(1, -(-counts // cap))
     w_inst = int(n_inst.sum())
-    # Round the instance count to a multiple of 8 with inert instances
-    # (vals 0 / lcol w−1 / last window id) so the Pallas kernel's (8, L)
-    # block shape meets the TPU sublane-divisibility rule for any layout.
-    w_inst_pad = (-w_inst) % 8
+    # Round the instance count with inert instances (vals 0 / lcol w−1 /
+    # last window id) to a multiple of 8, so the Pallas kernel's (8, L)
+    # block shape meets the TPU sublane-divisibility rule for any layout,
+    # and of the backward pass's instances per segment, so that its loop
+    # runs over whole segments and no pass pads or slices the streams.
+    w_inst_pad = (-w_inst) % instance_multiple(
+        w_inst, length, arr_val.dtype.itemsize
+    )
     inst_base = np.concatenate([[0], np.cumsum(n_inst)])[:-1]
     win_start = np.concatenate([[0], np.cumsum(counts)])
     w_inst += w_inst_pad
@@ -325,15 +353,45 @@ def _combine(out_inst: Array, windows: ColumnWindows, dim: int) -> Array:
         return per_win.reshape(-1)[:dim]
 
 
-def _contrib(windows: ColumnWindows, per_row: Array) -> Array:
-    """vals · r[rows] — the gather-side product (padding rows hit r[0] with
-    value 0, contributing nothing). Routed through ops/gather.take_1d: the
-    r4 on-chip finding is that this gather, not the scatter, is the floor
-    of every windowed rmatvec variant (~110M elem/s serialized vs ~362M
-    chunked)."""
-    from photon_tpu.ops.gather import take_1d
+def _over_instances(
+    windows: ColumnWindows, per_row: Array, consumer, *streams: Array
+) -> Array:
+    """``consumer(contrib, *blocks)`` over the layout, ``contrib`` being
+    vals · r[rows] — the gather-side product (padding rows hit r[0] with
+    value 0, contributing nothing) — and ``blocks`` the same instances of
+    each [W_inst, ·] array in ``streams``.
 
-    return windows.vals * take_1d(per_row, windows.rows)
+    This gather, not the scatter, is the floor of every windowed rmatvec
+    variant, and what it costs is where its fetched rows land (ops/gather's
+    module docstring). So where the layout's fetched rows pass one segment
+    the backward pass runs the segment loop here: a segment is a block of
+    whole instances (``segment_plan``; the build pads the instance count to
+    a multiple of it), its body fetches ``r[rows]``, selects, multiplies by
+    ``vals`` and hands the [instances, L] block to ``consumer``, and the
+    loop stacks what the consumer returns. One segment, or the plain
+    gather: ``consumer`` gets the whole layout at once, as before."""
+    from photon_tpu.ops import gather
+
+    plan = gather.segment_plan(
+        *windows.rows.shape, per_row.dtype.itemsize, _SUBLANES
+    )
+    if plan.steps == 1 or gather.gather_strategy(per_row) != "chunked":
+        contrib = windows.vals * gather.take_1d(per_row, windows.rows)
+        return consumer(contrib, *streams)
+    t2 = gather.lane_rows(per_row)
+
+    def instances_block(rows, vals, *blocks):
+        return consumer(vals * gather.fetch_select(t2, rows), *blocks)
+
+    return gather.map_segments(
+        instances_block, (windows.rows, windows.vals, *streams), plan, axis=0
+    )
+
+
+def _contrib(windows: ColumnWindows, per_row: Array) -> Array:
+    """[W_inst, L] contributions, one per slot, for the variants that
+    consume them whole (flat, pallas)."""
+    return _over_instances(windows, per_row, lambda contrib: contrib)
 
 
 def rmatvec_windows_flat(
@@ -380,11 +438,21 @@ def rmatvec_windows_prefix(
     build sorts by column), so the per-column sums are differences of the
     contribution prefix sum at build-time-static boundaries — a cumsum plus
     a [W_inst, w+1] gather. Fully dense, no scatter, no custom kernel: the
-    lowering-proof TPU path (measured on-chip r4: the sorted segment_sum
-    runs ~90M updates/s while this is plain bandwidth)."""
+    lowering-proof TPU path. The algebra (``_prefix_partials``) runs per
+    block of instances inside the gather's segment loop, so what is stacked
+    is [W_inst, w], not the [W_inst, L] contributions."""
     if windows.bounds is None:
         return rmatvec_windows_onehot(windows, per_row, dim)
-    contrib = _contrib(windows, per_row)
+    out_inst = _over_instances(
+        windows, per_row, _prefix_partials, windows.bounds
+    )
+    return _combine(out_inst, windows, dim)
+
+
+def _prefix_partials(contrib: Array, bounds: Array) -> Array:
+    """[I, L] contributions and their [I, w+1] bounds → [I, w] column sums
+    of those instances (rows are independent, so any block of whole
+    instances gives the same numbers as the whole layout)."""
     # Mean-centering bounds the f32 cumsum drift: a segment sum becomes the
     # difference of two prefixes, whose rounding error scales with |prefix|.
     # For biased contributions (the variance path's d2 > 0) the raw prefix
@@ -397,17 +465,14 @@ def rmatvec_windows_prefix(
             [jnp.zeros((s.shape[0], 1), s.dtype), s], axis=1
         )
     with scope("photon.rmatvec.bounds"):
-        g = jnp.take_along_axis(s, windows.bounds, axis=1)
-        counts = (windows.bounds[:, 1:] - windows.bounds[:, :-1]).astype(
-            contrib.dtype
-        )
-        out_inst = g[:, 1:] - g[:, :-1] + mu * counts
-    return _combine(out_inst, windows, dim)
+        g = jnp.take_along_axis(s, bounds, axis=1)
+        counts = (bounds[:, 1:] - bounds[:, :-1]).astype(contrib.dtype)
+        return g[:, 1:] - g[:, :-1] + mu * counts
 
 
 #: instances per Pallas grid step — the TPU sublane rule requires the
 #: second-to-last block dim be a multiple of 8 (block (1, L) fails to lower)
-_PALLAS_BLK = 8
+_PALLAS_BLK = _SUBLANES
 
 
 def _pallas_kernel_factory(length: int, w: int, chunk: int):

@@ -33,17 +33,27 @@ import numpy as np
 from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from photon_tpu.ops.sparse_windows import ColumnWindows, windowed_rmatvec
+from photon_tpu.ops.sparse_windows import (
+    ColumnWindows,
+    instance_multiple,
+    windowed_rmatvec,
+)
 from photon_tpu.types import Array
 
 
 def pad_windows_for_mesh(
     windows: ColumnWindows, num_shards: int, num_features: int
 ) -> ColumnWindows:
-    """Pad the instance axis to a multiple of ``num_shards`` with inert
-    instances (vals 0, lcol w−1, last window id)."""
+    """Pad the instance axis with inert instances (vals 0, lcol w−1, last
+    window id) to ``num_shards`` equal runs, each a multiple of the
+    instances per segment its shard's backward pass loops over (the
+    build's own padding rule, per shard)."""
     w_inst, length = windows.rows.shape
-    pad = (-w_inst) % num_shards
+    per_shard = -(-w_inst // num_shards)
+    per_shard += (-per_shard) % instance_multiple(
+        per_shard, length, np.dtype(windows.vals.dtype).itemsize
+    )
+    pad = per_shard * num_shards - w_inst
     if pad == 0:
         return windows
     w = windows.window

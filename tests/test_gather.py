@@ -13,7 +13,15 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from photon_tpu.ops.gather import _num_segments, chunked_take, take_1d
+import photon_tpu.ops.gather as gather_mod
+from photon_tpu.ops.gather import (
+    chunked_take,
+    fetch_select,
+    lane_rows,
+    map_segments,
+    segment_plan,
+    take_1d,
+)
 from photon_tpu.ops.objective import matvec
 from photon_tpu.ops.sparse_windows import (
     build_column_windows,
@@ -54,35 +62,131 @@ def test_chunked_take_under_jit_and_grad():
     np.testing.assert_allclose(np.asarray(g), expect, rtol=1e-6)
 
 
-def test_num_segments_bounds_fetch_for_any_slot_count():
+def test_segment_plan_bounds_fetch_for_any_slot_count():
     # odd counts must segment too (a [slots, 128] f32 fetch at 31M odd
     # slots is ~16 GB — past a v5e's HBM if segmentation silently bailed)
+    budget = gather_mod._SEG_BYTES
     for n in [1, 8, 56 << 20, (1 << 23) * 7, 1_000_001 * 31, 3 * 5 * 7]:
-        segs = _num_segments(n)
-        per_seg = -(-n // segs) * 512
-        assert per_seg <= (1 << 30) + 512 * segs
+        plan = segment_plan(n, 1, 4, 1024)
+        assert plan.segments * plan.per + plan.tail == n
+        assert max(plan.per, plan.tail) * 512 <= max(budget, n and 512)
+        assert plan.tail < plan.per or plan.steps == 1
 
 
-def test_num_segments_scales_with_table_itemsize():
+def test_segment_plan_scales_with_table_itemsize():
     # per-slot fetch is 128 lanes x itemsize: a float64 table doubles the
-    # row traffic past a 4-byte budget (must segment ~2x more), bf16
-    # halves it (must not over-segment). ADVICE r4.
+    # fetched bytes past a 4-byte budget (must segment ~2x more), bf16
+    # halves them (must not over-segment). ADVICE r4.
+    budget = gather_mod._SEG_BYTES
     for n in [56 << 20, (1 << 23) * 7, 1_000_001 * 31]:
+        steps = {}
         for itemsize in (2, 4, 8):
-            segs = _num_segments(n, itemsize)
-            per_seg_bytes = -(-n // segs) * 128 * itemsize
-            assert per_seg_bytes <= (1 << 30) + 128 * itemsize * segs
+            plan = segment_plan(n, 1, itemsize, 1024)
+            assert plan.per * 128 * itemsize <= budget
+            steps[itemsize] = plan.steps
         # monotone in itemsize and within rounding of proportional
-        assert _num_segments(n, 8) >= _num_segments(n, 4) >= _num_segments(n, 2)
-        assert _num_segments(n, 8) <= 2 * _num_segments(n, 4) + 1
+        assert steps[8] >= steps[4] >= steps[2]
+        assert steps[8] <= 2 * steps[4] + 1
+
+
+#: (units, slots per unit, itemsize, align) of the passes PERF.md quotes, and
+#: their (segments, per, tail) at _SEG_BYTES = 2^26: the benchmark's sparse
+#: cell forward ([2^22, 56] ELL) and backward (58 384 instances of 4096
+#: slots, padded to 58 400), chip_smoke's FE coordinate (game_ctr_scale:
+#: 2^18 rows x 25 slots; 2056 instances padded to 2080), one slot, a
+#: float64 table, and a per-entity block that is one segment
+_PLANS = {
+    "cell_forward": ((1 << 22, 56, 4, 128), (1820, 2304, 1024)),
+    "cell_backward": ((58400, 4096, 4, 8), (1825, 32, 0)),
+    "game_ctr_forward": ((1 << 18, 25, 4, 128), (51, 5120, 1024)),
+    "game_ctr_backward": ((2080, 4096, 4, 8), (65, 32, 0)),
+    "one_slot": ((1, 1, 4, 1024), (1, 1, 0)),
+    "float64_table": ((1 << 22, 56, 8, 128), (3640, 1152, 1024)),
+    "fits_one_segment": ((256, 16, 4, 128), (1, 256, 0)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_PLANS))
+def test_segment_plan_at_quoted_shapes(case):
+    args, expect = _PLANS[case]
+    plan = segment_plan(*args)
+    assert tuple(plan) == expect
+    units, slots, itemsize, align = args
+    assert plan.segments * plan.per + plan.tail == units
+    if plan.steps > 1:
+        assert plan.per % align == 0
+        # the block the compiler is to keep in fast memory
+        assert plan.per * slots * 128 * itemsize <= gather_mod._SEG_BYTES
+
+
+@pytest.fixture
+def small_segments(monkeypatch):
+    """Segments of 4 KiB of fetched rows: 8 float32 slots."""
+    monkeypatch.setattr(gather_mod, "_SEG_BYTES", 1 << 12)
+
+
+@pytest.mark.parametrize(
+    "n,align",
+    [(8 * 3, 8), (8 * 3 + 5, 8), (1009, 8), (7, 8), (40, 16)],
+)
+def test_map_segments_ragged_end_bit_identical(small_segments, n, align):
+    """Three or more segments and a ragged end: every slot comes back, in
+    order, bit-equal to table[idx]."""
+    rng = np.random.default_rng(n)
+    t = jnp.asarray(rng.standard_normal(777).astype(np.float32))
+    ix = jnp.asarray(rng.integers(0, 777, size=(n,)).astype(np.int32))
+    plan = segment_plan(n, 1, 4, align)
+    assert plan.segments * plan.per + plan.tail == n
+    t2 = lane_rows(t)
+    out = map_segments(lambda b: fetch_select(t2, b), (ix,), plan, 0)
+    assert np.array_equal(np.asarray(out), np.asarray(t[ix]))
+
+
+@pytest.mark.parametrize("k,r", [(1, 8), (3, 16), (13, 24), (56, 8)])
+def test_fetch_select_dot_is_the_weighted_row_sum(k, r):
+    """The forward body: K slots of R rows, summed lane by lane and then
+    across the lanes; the products are table[idx] . weights exactly, their
+    sum agrees at float32 rounding, and it differentiates."""
+    from photon_tpu.ops.gather import fetch_select_dot
+
+    rng = np.random.default_rng(k * r)
+    t = rng.standard_normal(700).astype(np.float32)
+    ix = rng.integers(0, 700, size=(k, r)).astype(np.int32)
+    w = rng.standard_normal((k, r)).astype(np.float32)
+    t2 = lane_rows(jnp.asarray(t))
+    got = np.asarray(fetch_select_dot(t2, jnp.asarray(ix), jnp.asarray(w)))
+    expect = np.sum(t[ix].astype(np.float64) * w, axis=0)
+    np.testing.assert_allclose(got, expect, rtol=2e-6, atol=2e-6)
+    one_hot = np.zeros((k, r), np.float32)
+    one_hot[k // 2] = 1.0  # a single live slot a row: the sum is exact
+    alone = fetch_select_dot(t2, jnp.asarray(ix), jnp.asarray(one_hot))
+    assert np.array_equal(np.asarray(alone), t[ix[k // 2]])
+    g = jax.grad(
+        lambda tt: jnp.sum(
+            fetch_select_dot(lane_rows(tt), jnp.asarray(ix), jnp.asarray(w))
+        )
+    )(jnp.asarray(t))
+    dense = np.zeros(700, np.float64)
+    np.add.at(dense, ix.reshape(-1), w.reshape(-1).astype(np.float64))
+    np.testing.assert_allclose(np.asarray(g), dense, rtol=1e-5, atol=1e-5)
+
+
+def test_map_segments_slices_every_stream_along_axis(small_segments):
+    """Two streams cut along axis 1, the body's result stacked along the
+    unit axis: what the forward pass does with its [K, n] views."""
+    k, n = 3, 4 * 8 + 3
+    a = jnp.arange(k * n, dtype=jnp.float32).reshape(k, n)
+    b = 2.0 * a
+    plan = segment_plan(n, 1, 4, 8)
+    assert (plan.segments, plan.per, plan.tail) == (4, 8, 3)
+    out = map_segments(lambda x, y: jnp.sum(x + y, axis=0), (a, b), plan, 1)
+    assert np.array_equal(np.asarray(out), np.asarray(jnp.sum(3.0 * a, 0)))
 
 
 def test_chunked_take_odd_slot_count_segments():
     rng = np.random.default_rng(5)
     t = jnp.asarray(rng.standard_normal(777).astype(np.float32))
     ix = jnp.asarray(rng.integers(0, 777, size=(1009,)).astype(np.int32))
-    import photon_tpu.ops.gather as gather_mod
-
     orig = gather_mod._SEG_BYTES
     try:
         gather_mod._SEG_BYTES = 1 << 12  # force multi-segment + padding
@@ -105,6 +209,29 @@ def test_chunked_take_nonfinite_isolation():
     assert np.isinf(out[3])
     assert out[4] == 0 and out[5] == 0
     assert np.isnan(out[6])
+
+
+def test_segmented_take_nonfinite_isolation(small_segments):
+    """The same through the segment loop: a NaN/Inf entry reaches the
+    slots that select it, in whichever segment they fall, and no other."""
+    t = np.arange(256, dtype=np.float32)
+    t[7], t[130] = np.inf, np.nan
+    ix = np.tile(np.array([0, 6, 8, 7, 129, 131, 130], np.int32), 5)
+    out = np.asarray(chunked_take(jnp.asarray(t), jnp.asarray(ix)))
+    assert segment_plan(ix.size, 1, 4, 1024).steps == 1  # align floors it
+    plan = segment_plan(ix.size, 1, 4, 8)
+    assert plan.steps >= 3
+    seg = np.asarray(
+        map_segments(
+            lambda b: fetch_select(lane_rows(jnp.asarray(t)), b),
+            (jnp.asarray(ix),),
+            plan,
+            0,
+        )
+    )
+    for got in (out, seg):
+        assert np.array_equal(got, t[ix], equal_nan=True)
+        assert np.isinf(got).sum() == 5 and np.isnan(got).sum() == 5
 
 
 def test_take_1d_env_dispatch(monkeypatch):

@@ -153,3 +153,135 @@ def test_bf16_feature_block_matches_f32_within_tolerance():
     np.testing.assert_allclose(
         np.asarray(h16), np.asarray(h32), rtol=0.1, atol=0.1
     )
+
+
+# --- the segmented sparse forward pass (ops/objective._ell_matvec) ----------
+
+
+def _sparse_case(seed, n, k, d):
+    from photon_tpu.types import SparseBatch
+
+    rng = np.random.default_rng(seed)
+    idx = rng.integers(0, d, size=(n, k)).astype(np.int32)
+    val = rng.standard_normal((n, k)).astype(np.float32)
+    v = rng.standard_normal(d).astype(np.float32)
+    batch = SparseBatch(
+        indices=jnp.asarray(idx),
+        values=jnp.asarray(val),
+        labels=jnp.zeros((n,), jnp.float32),
+        offsets=jnp.zeros((n,), jnp.float32),
+        weights=jnp.ones((n,), jnp.float32),
+        windows=None,
+    )
+    return idx, val, v, batch
+
+
+@pytest.fixture
+def row_fetch_in_small_segments(monkeypatch):
+    """The TPU's gather on the CPU, a segment = 64 KiB of fetched rows."""
+    import photon_tpu.ops.gather as gather_mod
+
+    monkeypatch.setenv("PHOTON_SPARSE_GATHER", "chunked")
+    monkeypatch.setattr(gather_mod, "_SEG_BYTES", 1 << 16)
+    return gather_mod
+
+
+@pytest.mark.parametrize(
+    "n,k",
+    [
+        (128 * 3, 1),       # whole segments, one slot a row
+        (128 * 3 + 77, 1),  # rows not a multiple of the rows per segment
+        (128 * 4 + 1, 1),   # a one-row ragged end
+        (1000, 1),
+        (128 * 3 + 5, 2),   # 64 rows would fit: floored at the 128 lanes
+        (128 * 5 + 9, 13),  # K not a multiple of 8, a segment over budget
+    ],
+)
+def test_segmented_ell_matvec_matches_table_lookup(
+    row_fetch_in_small_segments, n, k
+):
+    """Three or more segments and a ragged end: the gathered values are
+    bit-equal to table[idx], the row sums agree at float32 rounding."""
+    from photon_tpu.ops.objective import matvec
+
+    gather_mod = row_fetch_in_small_segments
+    d = 1000
+    idx, val, v, batch = _sparse_case(n + k, n, k, d)
+    plan = gather_mod.segment_plan(n, k, 4, 128)
+    assert plan.steps >= 3 and plan.per == 128
+    assert plan.segments * plan.per + plan.tail == n
+    got = np.asarray(jax.jit(matvec)(batch, jnp.asarray(v)))
+    t2 = gather_mod.lane_rows(jnp.asarray(v))
+    gathered = gather_mod.map_segments(
+        lambda b: gather_mod.fetch_select(t2, b).T,
+        (batch.indices.T,),
+        plan,
+        axis=1,
+    )
+    assert np.array_equal(np.asarray(gathered), v[idx])
+    expect = np.sum(v[idx].astype(np.float64) * val, axis=1)
+    np.testing.assert_allclose(got, expect, rtol=2e-6, atol=2e-6)
+
+
+def test_segmented_ell_matvec_nonfinite_entry_reaches_only_its_rows(
+    row_fetch_in_small_segments,
+):
+    from photon_tpu.ops.objective import matvec
+
+    n, k, d = 128 * 3 + 40, 3, 512
+    idx, val, v, batch = _sparse_case(5, n, k, d)
+    idx[idx == 130] = 131
+    idx[idx == 7] = 8
+    idx[3, 1], idx[200, 0], idx[n - 1, 2] = 130, 7, 130  # loop, loop, tail
+    v[130], v[7] = np.nan, np.inf
+    batch = batch._replace(indices=jnp.asarray(idx))
+    got = np.asarray(matvec(batch, jnp.asarray(v)))
+    bad = np.zeros(n, bool)
+    bad[[3, 200, n - 1]] = True
+    assert not np.isfinite(got[bad]).any()
+    assert np.isfinite(got[~bad]).all()
+    clean = np.sum(v[idx][~bad].astype(np.float64) * val[~bad], axis=1)
+    np.testing.assert_allclose(got[~bad], clean, rtol=2e-6, atol=2e-6)
+
+
+def test_one_segment_matvec_under_vmap_lowers_as_before(monkeypatch):
+    """Per-entity solves call matvec under vmap on blocks of one segment:
+    their program is the loop-free one of before the segment loop (the
+    row fetch and lane select over the whole block), operation for
+    operation."""
+    from photon_tpu.ops.objective import matvec
+    from photon_tpu.types import SparseBatch
+
+    monkeypatch.setenv("PHOTON_SPARSE_GATHER", "chunked")
+    e, n, k, d = 6, 40, 5, 300
+
+    def before(v, idx, val):  # ops/gather.chunked_take as PR 29 had it
+        n_rows = -(-d // 128)
+        padded = jnp.zeros((n_rows * 128,), v.dtype).at[:d].set(v)
+        t2 = padded.reshape(n_rows, 128)
+        flat = idx.reshape(-1)
+        lane_iota = jax.lax.broadcasted_iota(jnp.int32, (1, 128), 1)
+        rows = t2[flat >> 7]
+        sel = (flat & 127)[:, None] == lane_iota
+        tv = jnp.sum(jnp.where(sel, rows, 0), axis=1).reshape(idx.shape)
+        return jnp.sum(tv * val, axis=-1)
+
+    def now(v, idx, val):
+        z = jnp.zeros((n,), jnp.float32)
+        return matvec(
+            SparseBatch(indices=idx, values=val, labels=z, offsets=z,
+                        weights=z, windows=None),
+            v,
+        )
+
+    args = (
+        jax.ShapeDtypeStruct((e, d), jnp.float32),
+        jax.ShapeDtypeStruct((e, n, k), jnp.int32),
+        jax.ShapeDtypeStruct((e, n, k), jnp.float32),
+    )
+    texts = [
+        jax.jit(jax.vmap(f)).lower(*args).as_text().replace(name, "f")
+        for f, name in ((before, "before"), (now, "now"))
+    ]
+    assert "while" not in texts[1]
+    assert texts[0] == texts[1]

@@ -28,8 +28,11 @@ from photon_tpu.obs.scopes import SCOPES
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 PACKAGE = os.path.join(ROOT, "photon_tpu")
 
+#: innermost scopes: nothing sits under ``photon.gather`` itself, its two
+#: halves are named apart (PR 30)
 SPARSE_PATH = {
-    "photon.matvec", "photon.rmatvec", "photon.gather", "photon.loss",
+    "photon.matvec", "photon.rmatvec", "photon.gather.fetch",
+    "photon.gather.select", "photon.loss",
     "photon.rmatvec.prefix", "photon.rmatvec.bounds", "photon.rmatvec.combine",
     "photon.owlqn.direction", "photon.owlqn.linesearch", "photon.owlqn.history",
 }
@@ -266,6 +269,35 @@ def test_cell_program_carries_its_scopes(cell, monkeypatch, fresh_compiles):
     by_scope = hlo.seconds_by_scope(seconds, scope_of)
     assert sum(by_scope.values()) == pytest.approx(len(seconds))
     assert by_scope[hlo.UNSCOPED] == len(seconds) - len(scope_of)
+
+
+def test_segment_loop_names_the_gathers_two_halves(monkeypatch, fresh_compiles):
+    """The segment program with its passes cut into several segments (the
+    rehearsal shapes at a 2^20 B segment): under ``photon.gather`` a scope
+    join finds ``photon.gather.fetch`` and ``photon.gather.select`` in both
+    passes and nothing else, and the passes' consumers stay outside it."""
+    import photon_tpu.ops.gather as gather_mod
+
+    monkeypatch.setattr(gather_mod, "_SEG_BYTES", 1 << 20)
+    config = _config("sparse_poisson")["features"]
+    assert gather_mod.segment_plan(
+        config["n"], config["nnz_per_row"], 4, 128
+    ).steps >= 3
+    compiled, _ = _sparse_segment_program(monkeypatch)
+    assert " while(" in compiled.as_text()
+    paths = set(hlo.instruction_scope_paths(compiled).values())
+    under = {p for p in paths if "photon.gather" in p}
+    halves = ("photon.gather.fetch", "photon.gather.select")
+    assert {p[-1] for p in under} == set(halves), sorted(under)
+    for half in halves:
+        for outer in ("photon.matvec", "photon.rmatvec"):
+            assert any(
+                p[-3:] == (outer, "photon.gather", half) for p in under
+            ), (outer, half, sorted(under))
+    # the backward pass's consumer runs in the loop, under its own names
+    assert {"photon.rmatvec.prefix", "photon.rmatvec.bounds"} <= {
+        p[-1] for p in paths
+    }
 
 
 @pytest.mark.parametrize("cell", sorted(PROGRAMS))

@@ -530,3 +530,154 @@ def test_windows_survive_jit_closure():
     out1, out2 = f(windows, r1), f(windows, r2)
     assert calls["n"] == 1
     assert out1.shape == out2.shape == (128,)
+
+
+# --- the segmented backward pass (whole instances to a segment) -------------
+
+
+@pytest.fixture
+def row_fetch(monkeypatch):
+    """The TPU's gather on the CPU; returns a setter for the segment size."""
+    import photon_tpu.ops.gather as gather_mod
+
+    monkeypatch.setenv("PHOTON_SPARSE_GATHER", "chunked")
+
+    def set_segment(slots):
+        monkeypatch.setattr(gather_mod, "_SEG_BYTES", slots * 512)
+        return gather_mod
+
+    return set_segment
+
+
+def _layout(seed, n=1100, k=5, d=300, **build):
+    rng = np.random.default_rng(seed)
+    idx, val = _random_ell(rng, n, k, d, hot_column=True)
+    r = rng.standard_normal(n).astype(np.float32)
+    build = {"window": 32, "instance_cap": 32, "chunk": 16, **build}
+    return idx, val, r, build_column_windows(idx, val, d, **build)
+
+
+@pytest.mark.parametrize(
+    "per,expect_tail",
+    [(8, False), (16, False), (32, True), (40, True), (56, True)],
+)
+def test_segmented_contrib_bit_identical(row_fetch, per, expect_tail):
+    """A layout built for one segment size, run at another: three or more
+    segments, an instance count that is not a multiple of the instances
+    per segment, and every slot's vals . r[rows] bit-equal to the plain
+    lookup's."""
+    from photon_tpu.ops.sparse_windows import _contrib
+
+    idx, val, r, windows = _layout(11)
+    w_inst, length = windows.rows.shape
+    gather_mod = row_fetch(per * length)
+    plan = gather_mod.segment_plan(w_inst, length, 4, 8)
+    assert plan.per == per and plan.steps >= 3
+    assert bool(plan.tail) == expect_tail, (w_inst, plan)
+    got = np.asarray(jax.jit(_contrib)(windows, jnp.asarray(r)))
+    expect = np.asarray(windows.vals) * r[np.asarray(windows.rows)]
+    assert np.array_equal(got, expect)
+
+
+@pytest.mark.parametrize("per", [8, 16, 24])
+@pytest.mark.parametrize("impl", ["prefix", "flat", "pallas"])
+def test_segmented_rmatvec_matches_reference(row_fetch, impl, per):
+    idx, val, r, windows = _layout(12)
+    d = 300
+    w_inst, length = windows.rows.shape
+    assert row_fetch(per * length).segment_plan(
+        w_inst, length, 4, 8
+    ).steps >= 3
+    fn = {
+        "prefix": rmatvec_windows_prefix,
+        "flat": rmatvec_windows_flat,
+        "pallas": lambda w, r_, d_: rmatvec_windows_pallas(
+            w, r_, d_, interpret=True
+        ),
+    }[impl]
+    got = np.asarray(fn(windows, jnp.asarray(r), d))
+    np.testing.assert_allclose(
+        got, _reference_rmatvec(idx, val, r, d), rtol=2e-4, atol=1e-4
+    )
+
+
+def test_segmented_prefix_agrees_with_one_segment_prefix(row_fetch):
+    """Instances are independent: the loop's [I, w] partials are the
+    whole layout's, to the rounding of a mean and a cumsum whose order
+    the compiler picks by block shape."""
+    idx, val, r, windows = _layout(13)
+    w_inst, length = windows.rows.shape
+    row_fetch(w_inst * length)
+    whole = np.asarray(rmatvec_windows_prefix(windows, jnp.asarray(r), 300))
+    row_fetch(8 * length)
+    cut = np.asarray(rmatvec_windows_prefix(windows, jnp.asarray(r), 300))
+    np.testing.assert_allclose(whole, cut, rtol=1e-5, atol=1e-5)
+
+
+def test_segmented_backward_nonfinite_row_reaches_only_its_columns(row_fetch):
+    idx, val, r, windows = _layout(14, n=400, d=256)
+    val[val == 0] = 0.5  # every slot stored, so row 130 has 5 live columns
+    idx[:, 1:] = np.where(idx[:, 1:] == 0, 1, idx[:, 1:])
+    windows = build_column_windows(
+        idx, val, 256, window=32, instance_cap=32, chunk=16
+    )
+    r[130] = np.nan
+    row_fetch(8 * windows.rows.shape[1])
+    got = np.asarray(rmatvec_windows_prefix(windows, jnp.asarray(r), 256))
+    hit = np.zeros(256, bool)
+    hit[idx[130]] = True
+    # a prefix sum carries a NaN to the later columns of its INSTANCE and no
+    # further: the other instances, segments and windows stay finite
+    assert np.isnan(got[hit]).all()
+    poisoned = np.isnan(got)
+    wins = np.unique(idx[130] // 32)
+    assert np.isin(np.flatnonzero(poisoned) // 32, wins).all()
+
+
+def test_build_pads_instances_to_the_segment(row_fetch):
+    """Inert instances (row 0, local column w-1, value 0) bring the count
+    to a multiple of the instances per segment, so the loop has no ragged
+    end; a layout of one segment keeps the multiple of 8."""
+    from photon_tpu.ops.sparse_windows import instance_multiple
+
+    row_fetch(1 << 20)
+    w8 = _layout(15)[3].rows.shape[0]
+    row_fetch(40 * 32)
+    idx, val, r, windows = _layout(15)
+    w_inst, length = windows.rows.shape
+    assert w8 % 8 == 0 and w8 % 40 != 0
+    assert length == 32 and w_inst == -(-w8 // 40) * 40
+    assert instance_multiple(w8, length, 4) == 40
+    assert np.asarray(windows.inst2win).size == w_inst
+    inert = slice(w8, w_inst)
+    assert not np.asarray(windows.vals)[inert].any()
+    assert (np.asarray(windows.lcols)[inert] == 31).all()
+    assert (np.asarray(windows.rows)[inert] == 0).all()
+    assert (np.asarray(windows.bounds)[inert, -1] == length).all()
+    assert (np.asarray(windows.bounds)[inert, :-1] == 0).all()
+    got = np.asarray(rmatvec_windows_prefix(windows, jnp.asarray(r), 300))
+    np.testing.assert_allclose(
+        got, _reference_rmatvec(idx, val, r, 300), rtol=2e-4, atol=1e-4
+    )
+    row_fetch(1 << 20)
+    assert instance_multiple(w_inst, length, 4) == 8
+
+
+def test_pad_windows_for_mesh_pads_each_shard_to_the_segment(row_fetch):
+    from photon_tpu.parallel.sparse import pad_windows_for_mesh
+
+    row_fetch(1 << 20)
+    idx, val, r, windows = _layout(16)
+    w_inst, length = windows.rows.shape
+    gather_mod = row_fetch(16 * length)
+    padded = pad_windows_for_mesh(windows, 4, 300)
+    per_shard = padded.rows.shape[0] // 4
+    assert padded.rows.shape[0] % 4 == 0 and per_shard % 16 == 0
+    assert gather_mod.segment_plan(per_shard, length, 4, 8).tail == 0
+    assert padded.bounds.shape[0] == padded.rows.shape[0]
+    got = np.asarray(rmatvec_windows_prefix(
+        jax.tree_util.tree_map(jnp.asarray, padded), jnp.asarray(r), 300
+    ))
+    np.testing.assert_allclose(
+        got, _reference_rmatvec(idx, val, r, 300), rtol=2e-4, atol=1e-4
+    )

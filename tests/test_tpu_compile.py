@@ -343,3 +343,128 @@ def test_pallas_rmatvec_compiles_for_v5e(
     )
     _fits(compiled)
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# --- the sparse cell's two passes, from shapes alone ------------------------
+
+#: ``benchmarks/configs/sparse_poisson.json`` and the layout its structure
+#: builds: 58 384 instances of 4096 slots over windows of 128 columns,
+#: before the build pads the count to the backward pass's segment
+CELL_N, CELL_K, CELL_D = 1 << 22, 56, 1 << 20
+CELL_INSTANCES, CELL_LENGTH, CELL_WINDOW = 58384, 4096, 128
+
+
+@pytest.fixture(scope="module")
+def cell_passes(one_chip):
+    """(name, compiled, slots) of the forward and the backward pass at the
+    cell's shapes, as a TPU takes them; no data, seconds each."""
+    from photon_tpu.ops.objective import matvec
+    from photon_tpu.types import SparseBatch
+
+    mp = pytest.MonkeyPatch()
+    mp.setenv("PHOTON_SPARSE_GATHER", "chunked")
+    try:
+        sds = lambda shape, dt=jnp.float32: jax.ShapeDtypeStruct(  # noqa: E731
+            shape, dt, sharding=one_chip
+        )
+        n, k, d = CELL_N, CELL_K, CELL_D
+        batch = SparseBatch(
+            indices=sds((n, k), jnp.int32), values=sds((n, k)),
+            labels=sds((n,)), offsets=sds((n,)), weights=sds((n,)),
+            windows=None,
+        )
+        forward = jax.jit(matvec).lower(batch, sds((d,))).compile()
+        w_inst = CELL_INSTANCES
+        w_inst += (-w_inst) % sparse_windows.instance_multiple(
+            w_inst, CELL_LENGTH, 4
+        )
+        windows = sparse_windows.ColumnWindows(
+            rows=sds((w_inst, CELL_LENGTH), jnp.int32),
+            lcols=sds((w_inst, CELL_LENGTH), jnp.int32),
+            vals=sds((w_inst, CELL_LENGTH)),
+            inst2win=sds((w_inst,), jnp.int32),
+            iota=sds((CELL_WINDOW,), jnp.int32),
+            bounds=sds((w_inst, CELL_WINDOW + 1), jnp.int32),
+        )
+        backward = (
+            jax.jit(sparse_windows.rmatvec_windows_prefix, static_argnums=2)
+            .lower(windows, sds((n,)), d)
+            .compile()
+        )
+    finally:
+        mp.undo()
+    return {
+        "forward": (forward, n * k),
+        "backward": (backward, w_inst * CELL_LENGTH),
+    }
+
+
+def _row_fetches(text):
+    """(table type, fetched block type) of every fusion that gathers
+    128-lane rows, memory spaces and all."""
+    import re
+
+    out = []
+    for m in re.finditer(
+        r"\n%fused_computation[^\s]* \(.*?\) -> [^\n]*\{\n(.*?)\n\}", text, re.S
+    ):
+        lines = m.group(1).splitlines()
+        if not any("slice_sizes={1,128}" in ln for ln in lines):
+            continue
+        table = next(ln for ln in lines if " parameter(0)" in ln)
+        root = next(ln for ln in lines if ln.strip().startswith("ROOT"))
+        out.append(
+            (table.split(" = ")[1].split(" ")[0], root.split(" = ")[1].split(" ")[0])
+        )
+    return out
+
+
+@pytest.mark.parametrize("which", ["forward", "backward"])
+def test_cell_pass_keeps_fetched_rows_in_fast_memory(cell_passes, which):
+    """What PR 30 is for: the table AND the segment's block of fetched rows
+    are assigned to memory space 1 (``S(1)``), so neither the fetch nor the
+    select goes through HBM."""
+    from photon_tpu.ops.gather import _SEG_BYTES
+
+    compiled, _ = cell_passes[which]
+    fetches = _row_fetches(compiled.as_text())
+    assert fetches, "no 128-lane row fetch in the program"
+    slots = lambda f: int(f[1].split("[")[1].split(",")[0])  # noqa: E731
+    # the loop's fetch (a ragged end's runs once, on a smaller block)
+    table, block = max(fetches, key=slots)
+    assert "S(1)" in table, f"the table is read from HBM: {table}"
+    assert "S(1)" in block, f"the fetched rows go to HBM: {block}"
+    assert _SEG_BYTES // 2 < slots((table, block)) * 512 <= _SEG_BYTES
+    assert all("S(1)" in b for _, b in fetches), fetches
+
+
+@pytest.mark.parametrize("which", ["forward", "backward"])
+def test_cell_pass_temporaries_and_relayouts(cell_passes, which):
+    """Under 1.5 GB of temporaries (4.30 and 4.17 GB before the segment
+    loop), and under ``photon.gather`` no copy, reshape, pad, slice or
+    dynamic-update-slice of a whole stream."""
+    import math
+
+    from photon_tpu.analysis import hlo
+
+    compiled, slots = cell_passes[which]
+    _fits(compiled)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
+    text = compiled.as_text()
+    paths = hlo.instruction_scope_paths(text)
+    relayouts = []
+    for ins in hlo.parse_instructions(text).values():
+        if "photon.gather" not in paths.get(ins.name, ()):
+            continue
+        if ins.opcode not in (
+            "copy", "reshape", "pad", "slice", "dynamic-update-slice"
+        ):
+            continue
+        dims = ins.shape.split("[")[1].split("]")[0]
+        if math.prod(int(x) for x in dims.split(",") if x) >= slots:
+            relayouts.append((ins.name, ins.opcode, ins.shape))
+    assert not relayouts, relayouts
+    under = {p for p in paths.values() if "photon.gather" in p}
+    assert under and all(
+        p[-1] in ("photon.gather.fetch", "photon.gather.select") for p in under
+    ), sorted(under)
