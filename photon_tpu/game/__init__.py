@@ -4,7 +4,7 @@ from photon_tpu.game.config import (  # noqa: F401
     RandomEffectCoordinateConfig,
 )
 from photon_tpu.game.data import CSRMatrix, GameData  # noqa: F401
-from photon_tpu.game.estimator import GameEstimator  # noqa: F401
+from photon_tpu.game.estimator import BuiltFit, GameEstimator  # noqa: F401
 from photon_tpu.game.model import (  # noqa: F401
     FixedEffectModel,
     GameModel,

@@ -21,7 +21,7 @@ from __future__ import annotations
 import collections
 import dataclasses
 import logging
-from functools import partial
+from functools import partial, wraps
 
 import jax
 import jax.numpy as jnp
@@ -69,6 +69,52 @@ logger = logging.getLogger(__name__)
 #: regression test pins them.
 TRACE_COUNTERS: collections.Counter = collections.Counter()
 
+#: Device bytes one bucket's vmapped solve may hold in temporaries. A
+#: bucket that would need more is solved as a loop over entity chunks
+#: inside the same program (``RandomEffectCoordinate._solve_bucket_body``).
+#: 2**29: the per-user coordinate of the ``glmix_ctr`` cell (1.9 M
+#: single-row entities in one bucket) then runs in 9 chunks with 0.41 GB
+#: of temporaries where the whole bucket at once asks for 3.5 GB (compiled
+#: for a described v5e, PERF.md PR 31), and every bucket the tests and
+#: ``chip_smoke.py`` build stays one chunk.
+RE_SOLVE_BYTES = 1 << 29
+
+#: a chunk is whole tiles of entities: the compiler lays the entity axis
+#: of these arrays on the 128 lanes, 8 sublanes deep
+_CHUNK_MULTIPLE = 1024
+
+
+def solve_entity_bytes(
+    rows: int, d: int, optimizer_config, itemsize: int = 4
+) -> int:
+    """Device bytes one entity of a ``[E, rows, d]`` bucket holds in
+    temporaries while its L-BFGS solve runs, reckoned from what the solver
+    carries through its loops: the block's rows with their label, offset,
+    weight, margin and direction margin, twice (a loop's carry is held
+    going in and coming out); the curvature history, 2 x m vectors of d,
+    twice; and a dozen vectors of d (point, gradient, direction, trial
+    point, the line search's brackets). For [E, 1, 16] and 5 iterations
+    that is 2.3 KB against the 1.9 KB the compiler reports."""
+    m = max(
+        1,
+        min(optimizer_config.num_corrections, optimizer_config.max_iterations),
+    )
+    return itemsize * (2 * rows * (d + 5) + (4 * m + 12) * d)
+
+
+def solve_chunk_entities(
+    entities: int, rows: int, d: int, optimizer_config, itemsize: int = 4
+) -> int:
+    """How many of a ``[entities, rows, d]`` bucket's entities one vmapped
+    solve takes at a time: all of them where their temporaries fit
+    ``RE_SOLVE_BYTES``, else the largest whole number of tiles that do."""
+    fit = RE_SOLVE_BYTES // solve_entity_bytes(
+        rows, d, optimizer_config, itemsize
+    )
+    if fit >= entities:
+        return entities
+    return max(_CHUNK_MULTIPLE, fit // _CHUNK_MULTIPLE * _CHUNK_MULTIPLE)
+
 
 def sweep_donation_enabled() -> bool:
     """Whether the fused sweep step donates its total/score/state buffers.
@@ -95,17 +141,25 @@ def sweep_donation_enabled() -> bool:
     return jax.default_backend() != "cpu"
 
 
-def _make_sweep_jits(body, static_argnums, donate_argnums):
+def _make_sweep_jits(body, static_argnums, donate_argnums, name):
     """The fused sweep step compiles as a (donating, non-donating) pair;
     ``Coordinate._active_sweep_jit`` picks per backend. One construction
     site so a future donation quirk (like the XLA:CPU corruption that
-    motivated the split) lands in one place."""
+    motivated the split) lands in one place. ``name`` names the compiled
+    program (HLO module ``jit_<name>``): a device trace tells a fixed
+    effect's sweep from a random effect's by it."""
+
+    @wraps(body)
+    def named(*args):
+        return body(*args)
+
+    named.__name__ = named.__qualname__ = name
     return (
         partial(
             jax.jit, static_argnums=static_argnums,
             donate_argnums=donate_argnums,
-        )(body),
-        partial(jax.jit, static_argnums=static_argnums)(body),
+        )(named),
+        partial(jax.jit, static_argnums=static_argnums)(named),
     )
 
 
@@ -570,19 +624,22 @@ class FixedEffectCoordinate(Coordinate):
         TRACE_COUNTERS["fe_sweep"] += 1
         from photon_tpu.parallel.mesh import constrain_rows
 
-        residual = constrain_rows(total - score, self.mesh)
+        with scope("photon.descent.residual"):
+            residual = constrain_rows(total - score, self.mesh)
         res = self._traced_problem(norm_args).solve(
             batch, state, reg_weight, extra_offsets=residual
         )
-        new_score = self._score_body(batch, norm_args, res.x)
-        new_total = constrain_rows(residual + new_score, self.mesh)
+        with scope("photon.descent.rescore"):
+            new_score = self._score_body(batch, norm_args, res.x)
+            new_total = constrain_rows(residual + new_score, self.mesh)
         # health scalars fold into THIS program (coefficients and the
         # solve outputs are replicated under a mesh, so the reductions
         # stay collective-free); descent reads them back as the barrier
         return res.x, new_score, new_total, res, sweep_health(res.x, res)
 
     _sweep_jit, _sweep_jit_nodonate = _make_sweep_jits(
-        _sweep_body, static_argnums=0, donate_argnums=(3, 4, 5)
+        _sweep_body, static_argnums=0, donate_argnums=(3, 4, 5),
+        name="fe_sweep",
     )
 
     def _state_sds(self):
@@ -929,8 +986,8 @@ class RandomEffectCoordinate(Coordinate):
         extra = res_pad[jnp.minimum(sample_pos, n_res)]
         offsets_eff = offsets + extra
 
-        def vmapped_solve(features, labels, offsets_eff, train_weights,
-                          w0, reg_weight):
+        def solve_all(features, labels, offsets_eff, train_weights, w0,
+                      reg_weight):
             def solve_one(f, l, o, w, w0_e):
                 batch = LabeledBatch(
                     features=f, labels=l, offsets=o, weights=w
@@ -940,6 +997,51 @@ class RandomEffectCoordinate(Coordinate):
             return jax.vmap(solve_one)(
                 features, labels, offsets_eff, train_weights, w0
             )
+
+        def vmapped_solve(features, labels, offsets_eff, train_weights,
+                          w0, reg_weight):
+            """The bucket's solves, ``chunk`` entities at a time where the
+            whole bucket's temporaries would pass ``RE_SOLVE_BYTES``: one
+            loop inside the program, its buffers reused from chunk to
+            chunk. The last chunk is moved back to end on the last entity,
+            so every chunk has one shape and the entities it shares with
+            the chunk before are solved twice, to the same result: a
+            lane's arithmetic does not depend on the lanes beside it (the
+            while-loop batching rule freezes a lane that has stopped)."""
+            lanes = (features, labels, offsets_eff, train_weights, w0)
+            e = features.shape[0]
+            chunk = solve_chunk_entities(
+                e, features.shape[1], features.shape[2],
+                self.problem_config.optimizer_config,
+                jnp.dtype(features.dtype).itemsize,
+            )
+            if chunk >= e:
+                return solve_all(*lanes, reg_weight)
+
+            def one_chunk(i, out):
+                start = jnp.minimum(i * chunk, e - chunk)
+                with scope("photon.re.chunk"):
+                    res = solve_all(
+                        *(
+                            jax.lax.dynamic_slice_in_dim(a, start, chunk, 0)
+                            for a in lanes
+                        ),
+                        reg_weight,
+                    )
+                return jax.tree_util.tree_map(
+                    lambda o, r: jax.lax.dynamic_update_slice_in_dim(
+                        o, r, start, 0
+                    ),
+                    out, res,
+                )
+
+            shapes = jax.eval_shape(
+                solve_all, *(a[:chunk] for a in lanes), reg_weight
+            )
+            out = jax.tree_util.tree_map(
+                lambda sd: jnp.zeros((e,) + sd.shape[1:], sd.dtype), shapes
+            )
+            return jax.lax.fori_loop(0, -(-e // chunk), one_chunk, out)
 
         if self.mesh is None:
             return vmapped_solve(
@@ -1092,22 +1194,28 @@ class RandomEffectCoordinate(Coordinate):
         TRACE_COUNTERS["re_sweep"] += 1
         from photon_tpu.parallel.mesh import constrain_rows
 
-        residual = constrain_rows(total - score, self.mesh)
-        res_pad = jnp.concatenate([residual, jnp.zeros((1,), residual.dtype)])
+        with scope("photon.descent.residual"):
+            residual = constrain_rows(total - score, self.mesh)
+            res_pad = jnp.concatenate(
+                [residual, jnp.zeros((1,), residual.dtype)]
+            )
         infos = [
             self._solve_bucket(f, l, o, tw, sp, w0, res_pad, reg_weight)
             for (f, l, o, tw, sp), w0 in zip(bucket_args, state)
         ]
         new_state = [r.x for r in infos]
-        new_score = jnp.zeros((self.num_samples,), dtype=self.dtype)
-        for (sf, ss, sp), coefs, pad in zip(score_args, new_state, pad_slots):
-            new_score = new_score + self._score_bucket_body(
-                sf, ss, sp, coefs, pad
-            )
-        # same row-sharding pin as _score_all_jit: GSPMD otherwise
-        # replicates the scatter-built [N] outputs across the mesh
-        new_score = constrain_rows(new_score, self.mesh)
-        new_total = constrain_rows(residual + new_score, self.mesh)
+        with scope("photon.descent.rescore"):
+            new_score = jnp.zeros((self.num_samples,), dtype=self.dtype)
+            for (sf, ss, sp), coefs, pad in zip(
+                score_args, new_state, pad_slots
+            ):
+                new_score = new_score + self._score_bucket_body(
+                    sf, ss, sp, coefs, pad
+                )
+            # same row-sharding pin as _score_all_jit: GSPMD otherwise
+            # replicates the scatter-built [N] outputs across the mesh
+            new_score = constrain_rows(new_score, self.mesh)
+            new_total = constrain_rows(residual + new_score, self.mesh)
         # health fold only off-mesh: reducing entity-SHARDED per-bucket
         # values/gradients to replicated scalars would put an all-reduce
         # into the RE sweep program, breaking the no-collectives contract
@@ -1118,7 +1226,8 @@ class RandomEffectCoordinate(Coordinate):
         return new_state, new_score, new_total, infos, health
 
     _sweep_jit, _sweep_jit_nodonate = _make_sweep_jits(
-        _sweep_body, static_argnums=(0, 6), donate_argnums=(3, 4, 5)
+        _sweep_body, static_argnums=(0, 6), donate_argnums=(3, 4, 5),
+        name="re_sweep",
     )
 
     def _state_sds_list(self) -> list:
@@ -1514,7 +1623,8 @@ class MatrixFactorizationCoordinate(Coordinate):
         return (u, v), new_score, new_total, res, sweep_health((u, v), res)
 
     _sweep_jit, _sweep_jit_nodonate = _make_sweep_jits(
-        _sweep_body, static_argnums=0, donate_argnums=(2, 3, 4)
+        _sweep_body, static_argnums=0, donate_argnums=(2, 3, 4),
+        name="mf_sweep",
     )
 
     def _state_sds_pair(self):

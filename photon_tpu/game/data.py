@@ -464,19 +464,6 @@ def _ceil_pow2_vec(arr: np.ndarray, floor: int) -> np.ndarray:
     return (1 << np.ceil(np.log2(a)).astype(np.int64)).astype(np.int64)
 
 
-def re_bucket_entity_cap() -> int:
-    """Normalized PHOTON_RE_MAX_BUCKET_ENTITIES (single parse site — the
-    checkpoint fingerprint must hash the SAME value the build uses, or
-    equivalent configs spuriously hard-fail resume as stale)."""
-    cap_env = os.environ.get("PHOTON_RE_MAX_BUCKET_ENTITIES", "").strip()
-    ent_cap = int(cap_env) if cap_env else 8_000_000
-    if ent_cap < 1:
-        raise ValueError(
-            f"PHOTON_RE_MAX_BUCKET_ENTITIES must be >= 1, got {ent_cap}"
-        )
-    return ent_cap
-
-
 #: default cap on the TOTAL distinct (rows, d) bucket shapes across the
 #: RE coordinates of one fit (split across d-groups — see ShapePool and
 #: _split_shape_budget). Chosen from the measured config-5 CPU-shape
@@ -1184,24 +1171,21 @@ def build_random_effect_dataset(
     inv_order = np.argsort(shape_inv, kind="stable")
     shape_counts = np.bincount(shape_inv, minlength=len(shape_keys))
     shape_bounds = np.concatenate(([0], np.cumsum(shape_counts)))
-    # Cap entities per bucket: one bucket = one vmapped solve program, and
-    # an unbounded entity axis makes that program's inter-collective
-    # interval (the while-loop's cross-device convergence reduce) and its
-    # single-dispatch execution size unbounded too. At 10⁹-coefficient
-    # scale a ~50M-entity singleton bucket blew XLA:CPU's hardcoded 40 s
-    # all-reduce rendezvous abort on the virtual mesh. Same-shape chunks share one
-    # compiled program (jit keys on shapes).
-    ent_cap = re_bucket_entity_cap()
+    # One bucket per shape, however many entities: a bucket is one vmapped
+    # solve inside the coordinate's sweep program, and that solve bounds its
+    # own device memory by looping over entity chunks (game/coordinate.py,
+    # RE_SOLVE_BYTES); under a mesh every shard loops alone, with no
+    # collective to keep short.
     # bucket_specs is shape-major by construction: np.unique returns
     # ascending packed (n<<32|d) keys, which orders like (n, d) tuples
-    bucket_specs: list[tuple[int, int, np.ndarray]] = []
-    for bi, key in enumerate(shape_keys):
-        ents = ent_list[inv_order[shape_bounds[bi] : shape_bounds[bi + 1]]]
-        shape = (int(key >> 32), int(key & 0xFFFFFFFF))
-        for s0 in range(0, len(ents), ent_cap):
-            bucket_specs.append(
-                (shape[0], shape[1], ents[s0 : s0 + ent_cap])
-            )
+    bucket_specs: list[tuple[int, int, np.ndarray]] = [
+        (
+            int(key >> 32),
+            int(key & 0xFFFFFFFF),
+            ent_list[inv_order[shape_bounds[bi] : shape_bounds[bi + 1]]],
+        )
+        for bi, key in enumerate(shape_keys)
+    ]
 
     # per-entity slot assignment within its bucket (shard-major balanced
     # when an entity mesh axis exists; load = active rows, the per-sweep
@@ -1230,10 +1214,9 @@ def build_random_effect_dataset(
     flat_row = flat_start_of_entity[kept_ent] + row_rank
 
     # Rows grouped by bucket ONCE (stable sort + range bounds): a per-
-    # bucket boolean scan over every kept row is O(buckets × rows) — with
-    # the entity cap splitting the 10⁹-coefficient build into ~30 buckets,
-    # that alone re-read 70M-row masks thirty times and pushed the host
-    # build past its budget.
+    # bucket boolean scan over every kept row is O(buckets × rows): at the
+    # 10⁹-coefficient build's ~30 buckets that alone re-read 70M-row masks
+    # thirty times and pushed the host build past its budget.
     order_rb = np.argsort(row_bucket, kind="stable")
     rb_bounds = np.searchsorted(
         row_bucket[order_rb], np.arange(len(bucket_specs) + 1)

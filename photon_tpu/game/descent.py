@@ -276,15 +276,25 @@ def _read_health(
     for cid in order:
         h = health_dev[cid]
         flat.extend((h["loss"], h["gnorm"], h["finite"]))
+        # the solves' counters, one pair per solve (obs/health.py); a
+        # coordinate kind that folds none gives empty lists
+        flat.extend(h.get("iterations", ()))
+        flat.extend(h.get("evaluations", ()))
     # phl-ok: PHL002 THE per-sweep barrier read-back — health scalars ride the existing sync
-    vals = fetch_scalars(flat, barrier=barrier)
+    vals = fetch_scalars(flat, barrier=barrier).tolist()
     out: dict[str, dict] = {}
-    for i, cid in enumerate(order):
-        loss, gnorm, finite = vals[3 * i : 3 * i + 3].tolist()
+    at = 0
+    for cid in order:
+        solves = len(health_dev[cid].get("iterations", ()))
+        loss, gnorm, finite = vals[at : at + 3]
+        counts = [int(v) for v in vals[at + 3 : at + 3 + 2 * solves]]
+        at += 3 + 2 * solves
         out[cid] = {
             "loss": loss,
             "gnorm": gnorm,
             "finite": bool(finite),
+            "iterations": counts[:solves],
+            "evaluations": counts[solves:],
         }
     return out
 

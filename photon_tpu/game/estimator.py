@@ -115,6 +115,52 @@ class GameTrainingResult:
 
 
 @dataclasses.dataclass
+class BuiltFit:
+    """A fit that is built and not yet run: what ``GameEstimator.build``
+    hands back and ``GameEstimator.fit`` itself runs on. The coordinates
+    hold their placed data (FE batch and window layout, RE bucket blocks)
+    and compile their sweep programs at the first sweep, or hold them
+    already where the estimator precompiles. Anything that wants sweeps
+    without a whole ``fit`` drives them as ``fit`` does::
+
+        built = estimator.build(data)
+        run_coordinate_descent(
+            built.coordinates, built.update_sequence,
+            built.descent_iterations,
+            initial_states=built.initial_states(),
+            locked_coordinates=built.locked_coordinates,
+        )
+    """
+
+    coordinates: dict
+    #: cid -> the host-side RandomEffectDataset its coordinate was placed
+    #: from (vocabulary, buckets, which rows each entity trains on)
+    re_datasets: dict
+    update_sequence: tuple
+    locked_coordinates: frozenset
+    descent_iterations: int
+    #: placed states of the coordinates an initial model covers; None
+    #: without one
+    warm_states: dict | None = None
+    #: ``precompile_coordinates``' report where the estimator precompiles
+    precompile_report: dict | None = None
+    #: cid -> {"buckets": s, "place": s}: host seconds of the coordinate's
+    #: bucketing (RE only) and of its layout build and placement, the
+    #: ``photon.game.prepare.*`` spans
+    prepare_seconds: dict = dataclasses.field(default_factory=dict)
+
+    def initial_states(self) -> dict:
+        """cid -> the state descent starts from: the warm start where an
+        initial model covers the coordinate, else the coordinate's own
+        zero state, placed as its sweep program expects it."""
+        warm = self.warm_states or {}
+        return {
+            cid: warm[cid] if cid in warm else coord.initial_state()
+            for cid, coord in self.coordinates.items()
+        }
+
+
+@dataclasses.dataclass
 class GameEstimator:
     """Train a GAME model by block coordinate descent.
 
@@ -338,6 +384,7 @@ class GameEstimator:
     ):
         coords = {}
         re_datasets = {}
+        seconds: dict = {}
         norm = self.normalization_contexts or {}
         stream_telemetry = None
         if stream_cfg is not None:
@@ -363,31 +410,39 @@ class GameEstimator:
                         telemetry=stream_telemetry,
                     )
                     continue
-                coords[cid] = FixedEffectCoordinate.build(
-                    data,
-                    cfg,
-                    norm.get(cfg.feature_shard, NormalizationContext()),
-                    self.dtype,
-                    seed=self.seed,
-                    mesh=self.mesh,
-                )
+                with obs.span(
+                    "game.prepare.place", cat="build", coordinate=cid
+                ) as place:
+                    coords[cid] = FixedEffectCoordinate.build(
+                        data,
+                        cfg,
+                        norm.get(cfg.feature_shard, NormalizationContext()),
+                        self.dtype,
+                        seed=self.seed,
+                        mesh=self.mesh,
+                    )
+                seconds[cid] = {"place": place.duration_s}
             elif isinstance(cfg, RandomEffectCoordinateConfig):
                 entity_shards = 1
                 if self.mesh is not None:
                     from photon_tpu.parallel.mesh import ENTITY_AXIS
 
                     entity_shards = dict(self.mesh.shape).get(ENTITY_AXIS, 1)
-                ds = build_random_effect_dataset(
-                    data,
-                    cfg,
-                    seed=self.seed,
-                    entity_shards=entity_shards,
-                    existing_model_keys=self._existing_model_keys(
-                        cid, initial_model
-                    ),
-                    shape_pool=shape_pool,
-                )
+                with obs.span(
+                    "game.prepare.buckets", cat="build", coordinate=cid
+                ) as bucketing:
+                    ds = build_random_effect_dataset(
+                        data,
+                        cfg,
+                        seed=self.seed,
+                        entity_shards=entity_shards,
+                        existing_model_keys=self._existing_model_keys(
+                            cid, initial_model
+                        ),
+                        shape_pool=shape_pool,
+                    )
                 re_datasets[cid] = ds
+                seconds[cid] = {"buckets": bucketing.duration_s}
                 if stream_cfg is not None:
                     from photon_tpu.game.streaming import (
                         StreamingRandomEffectCoordinate,
@@ -400,9 +455,13 @@ class GameEstimator:
                         )
                     )
                 else:
-                    coords[cid] = RandomEffectCoordinate.build(
-                        data, ds, cfg, self.dtype, mesh=self.mesh
-                    )
+                    with obs.span(
+                        "game.prepare.place", cat="build", coordinate=cid
+                    ) as place:
+                        coords[cid] = RandomEffectCoordinate.build(
+                            data, ds, cfg, self.dtype, mesh=self.mesh
+                        )
+                    seconds[cid]["place"] = place.duration_s
                 waste = ds.padding_waste()
                 logger.info(
                     "coordinate %s: %d entities in %d buckets "
@@ -419,7 +478,7 @@ class GameEstimator:
                 )
             else:
                 raise TypeError(f"unknown coordinate config for {cid}")
-        return coords, re_datasets
+        return coords, re_datasets, seconds
 
     def _grid_length(self) -> int:
         return max(
@@ -678,29 +737,41 @@ class GameEstimator:
                 )
             return results
 
-    def _fit_impl(
+    def build(
         self,
         data: GameData,
         *,
-        validation_data,
-        initial_model,
-        grid_callback,
-        checkpoint_dir,
-        checkpoint_every,
-        shape_pool,
-        stream_cfg=None,
-    ) -> list[GameTrainingResult]:
+        initial_model: GameModel | None = None,
+        shape_pool=None,
+        stream=None,
+    ) -> BuiltFit:
+        """Everything of a fit that comes before its first sweep: the data
+        padded to the mesh, every coordinate built and its data placed
+        (``photon.game.prepare`` and, under it, ``.buckets`` and ``.place``
+        spans), the sweep programs precompiled where ``precompile`` is
+        set, and the states an initial model gives. ``fit`` runs on what
+        this returns; so can anything else that wants to drive sweeps
+        (:class:`BuiltFit`). ``shape_pool`` and ``stream`` are ``fit``'s."""
+        if stream is None:
+            stream = self.stream
+        stream_cfg = None
+        if stream is not None and stream is not False:
+            from photon_tpu.game.streaming import StreamConfig
+
+            stream_cfg = StreamConfig.resolve(stream)
         if self.ignore_threshold_for_new_models and initial_model is None:
             raise ValueError(
                 "ignore_threshold_for_new_models requires an initial model "
                 "(reference GameEstimator validation :226)"
             )
-        with obs.span("fit.data_build", num_samples=int(data.num_samples)):
+        with obs.span(
+            "fit.data_build", num_samples=int(data.num_samples)
+        ), obs.span("game.prepare", cat="build"):
             if self.mesh is not None:
                 from photon_tpu.game.data import pad_game_data
 
                 data = pad_game_data(data, int(self.mesh.devices.size))
-            coordinates, re_datasets = self._build_coordinates(
+            coordinates, re_datasets, seconds = self._build_coordinates(
                 data, initial_model, shape_pool=shape_pool,
                 stream_cfg=stream_cfg,
             )
@@ -724,14 +795,12 @@ class GameEstimator:
         # built coordinates retained only on request (keep_coordinates):
         # audit tooling reads the fit's own AOT executables and live
         # table placements from here; everyone else gets the device
-        # memory back when fit's locals drop
+        # memory back when the BuiltFit drops
         self.last_coordinates = coordinates if self.keep_coordinates else None
         # phase-boundary memory censuses (photon_tpu/obs/memory.py):
         # host-metadata snapshots of every live device buffer — gated
         # no-ops that never dispatch or read back
         obs.memory.census("data_build")
-
-        from photon_tpu.util import compile_watch
 
         precompile_report = None
         if self.precompile:
@@ -747,16 +816,48 @@ class GameEstimator:
                 )
             obs.memory.census("precompile")
 
-        init_states = None
+        warm_states = None
         if initial_model is not None:
             with obs.span("fit.warm_start"):
-                init_states = self._place_states(
+                warm_states = self._place_states(
                     self._states_from_model(
                         initial_model, coordinates, re_datasets
                     ),
                     coordinates,
                 )
             obs.memory.census("warm_start")
+        return BuiltFit(
+            coordinates=coordinates,
+            re_datasets=re_datasets,
+            update_sequence=tuple(self.update_sequence),
+            locked_coordinates=frozenset(self.locked_coordinates),
+            descent_iterations=self.descent_iterations,
+            warm_states=warm_states,
+            precompile_report=precompile_report,
+            prepare_seconds=seconds,
+        )
+
+    def _fit_impl(
+        self,
+        data: GameData,
+        *,
+        validation_data,
+        initial_model,
+        grid_callback,
+        checkpoint_dir,
+        checkpoint_every,
+        shape_pool,
+        stream_cfg=None,
+    ) -> list[GameTrainingResult]:
+        built = self.build(
+            data, initial_model=initial_model, shape_pool=shape_pool,
+            stream=stream_cfg if stream_cfg is not None else False,
+        )
+        coordinates = built.coordinates
+        precompile_report = built.precompile_report
+        init_states = built.warm_states
+
+        from photon_tpu.util import compile_watch
 
         validation_fn = None
         if validation_data is not None and self.validation_evaluator is not None:
@@ -782,10 +883,7 @@ class GameEstimator:
 
             # stale-config guard: resuming state trained under different
             # hyperparameters must be a hard error, not silent reuse
-            from photon_tpu.game.data import (
-                re_bucket_entity_cap,
-                re_shape_budget,
-            )
+            from photon_tpu.game.data import re_shape_budget
 
             from photon_tpu.parallel.mesh import mesh_fingerprint
 
@@ -808,13 +906,12 @@ class GameEstimator:
                     # another must be the clean stale-config error, not
                     # a silent reshard or an unflatten failure
                     mesh_fingerprint(self.mesh),
-                    # layout knobs: a different bucket-entity cap or shape
-                    # budget changes the per-bucket state SHAPES — resuming
-                    # across either must be the clean stale-config error,
-                    # not a cryptic unflatten failure. Normalized via the
-                    # build's own parse sites so equivalent configs never
-                    # spuriously invalidate (the env overrides ride along).
-                    re_bucket_entity_cap(),
+                    # layout knob: a different shape budget changes the
+                    # per-bucket state SHAPES — resuming across it must be
+                    # the clean stale-config error, not a cryptic unflatten
+                    # failure. Normalized via the build's own parse site so
+                    # equivalent configs never spuriously invalidate (the
+                    # env override rides along).
                     sorted(
                         (cid, re_shape_budget(cfg.shape_budget))
                         for cid, cfg in self.coordinate_configs.items()

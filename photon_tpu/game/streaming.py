@@ -561,7 +561,8 @@ class StreamingFixedEffectCoordinate(FixedEffectCoordinate):
         return self._score_body(batch, norm_args, state)
 
     _stream_score_jit, _stream_score_jit_nodonate = _make_sweep_jits(
-        _stream_score_body, static_argnums=0, donate_argnums=(1,)
+        _stream_score_body, static_argnums=0, donate_argnums=(1,),
+        name="stream_fe_score",
     )
 
     def score(self, state) -> np.ndarray:
@@ -826,7 +827,8 @@ class StreamingRandomEffectCoordinate(RandomEffectCoordinate):
         return res.x, res.value.astype(jnp.float32), gsq
 
     _solve_chunk_jit, _solve_chunk_jit_nodonate = _make_sweep_jits(
-        _solve_chunk_body, static_argnums=0, donate_argnums=(1, 2, 3, 4, 5)
+        _solve_chunk_body, static_argnums=0, donate_argnums=(1, 2, 3, 4, 5),
+        name="stream_re_solve",
     )
 
     def _score_chunk_body(self, score_feats, coef_rows):
@@ -840,7 +842,8 @@ class StreamingRandomEffectCoordinate(RandomEffectCoordinate):
         return jnp.einsum("md,md->m", score_feats, c)
 
     _score_chunk_jit, _score_chunk_jit_nodonate = _make_sweep_jits(
-        _score_chunk_body, static_argnums=0, donate_argnums=(1, 2)
+        _score_chunk_body, static_argnums=0, donate_argnums=(1, 2),
+        name="stream_re_score",
     )
 
     def _chunk_exes(self, donate=None):

@@ -76,15 +76,20 @@ def resolve_policy(policy: str | None) -> str:
 
 
 def sweep_health(state, info) -> dict:
-    """Per-coordinate health triple as 0-d device arrays, computed from a
-    sweep step's EXISTING outputs (works traced — inside the fused sweep
-    program — and eagerly on the unfused reference path):
+    """Per-coordinate health triple and the solves' counters as 0-d device
+    arrays, computed from a sweep step's EXISTING outputs (works traced —
+    inside the fused sweep program — and eagerly on the unfused reference
+    path):
 
     - ``loss``: Σ of the optimizer's final objective values (a scalar
       for FE/MF; summed over the per-entity lanes of every RE bucket);
     - ``gnorm``: global L2 norm over every final gradient leaf;
     - ``finite``: fused sentinel — loss AND gnorm AND every float state
-      leaf finite. Any NaN/Inf anywhere in the new state flips it.
+      leaf finite. Any NaN/Inf anywhere in the new state flips it;
+    - ``iterations``, ``evaluations``: one entry per solve (an RE
+      coordinate has one per bucket), the optimizer iterations and the
+      objective evaluations of its slowest lane. They ride home with the
+      triple in the sweep's one read-back.
 
     ``info`` is one OptimizeResult-like or a list of them (the RE
     multi-bucket case); ``state`` is the coordinate's new state pytree.
@@ -108,4 +113,6 @@ def sweep_health(state, info) -> dict:
         "loss": jnp.asarray(loss, jnp.float32),
         "gnorm": jnp.asarray(gnorm, jnp.float32),
         "finite": finite,
+        "iterations": tuple(jnp.max(jnp.asarray(r.iterations)) for r in infos),
+        "evaluations": tuple(jnp.max(jnp.asarray(r.n_evals)) for r in infos),
     }

@@ -42,7 +42,10 @@ SCOPES: dict[str, tuple[str, str]] = {
     "photon.tron.step": ("optimizer programs", "candidate evaluation, radius update, acceptance, convergence test"),
     # --- GAME programs (game/) --------------------------------------------
     "photon.re.solve": ("random-effect programs", "one size bucket's vmapped per-entity solves"),
+    "photon.re.chunk": ("random-effect programs", "one chunk of a bucket too large to solve at once: the solver's loops over that chunk's entities"),
     "photon.re.rescore": ("random-effect programs", "one bucket's flat scoring scattered back to rows"),
+    "photon.descent.residual": ("descent loop", "a coordinate's residual: the total less its own old score, the offsets it trains on"),
+    "photon.descent.rescore": ("descent loop", "a coordinate's new score and the total rebuilt from it"),
     "photon.score.batch": ("scorer", "GameScorer's fused batch program: every coordinate's margin"),
 }
 

@@ -2,7 +2,7 @@
 
 TPU-native replacement for the reference's Breeze-backed LBFGS
 (optimization/LBFGS.scala:59-156): two-loop recursion over a fixed-size
-circular (S, Y) history, strong-Wolfe line search
+(S, Y) history kept newest pair first, strong-Wolfe line search
 (optimize/linesearch.py), optional box-constraint projection after every
 step (reference OptimizationUtils.projectCoefficientsToSubspace via
 LBFGS.scala:72 — this also serves as the LBFGSB variant), and the reference
@@ -46,11 +46,10 @@ class _LBFGSState(NamedTuple):
     f: Array
     g: Array
     prev_f: Array
-    s_hist: Array  # [m, D]
+    s_hist: Array  # [m, D], newest pair first
     y_hist: Array  # [m, D]
     rho: Array  # [m]
     num_pairs: Array
-    pos: Array  # circular write index
     reason: Array
     loss_hist: Array
     gnorm_hist: Array
@@ -71,6 +70,8 @@ def two_loop_direction(
 
     Fixed m iterations with validity masks so the shapes are static; the
     initial Hessian scale is γ = s·y / y·y of the newest pair (Nocedal 7.20).
+    The history is circular and ``pos`` its write index (OWL-QN's; L-BFGS
+    keeps its own newest first, ``_two_loop_newest_first``).
     """
     m = s_hist.shape[0]
     n_valid = jnp.minimum(num_pairs, m)
@@ -107,6 +108,35 @@ def two_loop_direction(
     return -r
 
 
+def _two_loop_newest_first(
+    g: Array, s_hist: Array, y_hist: Array, rho: Array, num_pairs: Array
+) -> Array:
+    """The same recursion over a history kept newest pair first: slot j is
+    read by the loop counter alone. Under ``vmap`` a per-lane write index
+    turns every read of a circular history into a gather (the 59 KB an
+    entity of PERF.md, PR 31); a shifted one has none."""
+    m = s_hist.shape[0]
+
+    def first_loop(j, carry):
+        q, alphas = carry
+        alpha = jnp.where(j < num_pairs, rho[j] * jnp.dot(s_hist[j], q), 0.0)
+        return q - alpha * y_hist[j], alphas.at[j].set(alpha)
+
+    q, alphas = lax.fori_loop(
+        0, m, first_loop, (g, jnp.zeros((m,), dtype=g.dtype))
+    )
+    sy = jnp.dot(s_hist[0], y_hist[0])
+    yy = jnp.dot(y_hist[0], y_hist[0])
+    gamma = jnp.where((num_pairs > 0) & (yy > 0), sy / jnp.where(yy > 0, yy, 1.0), 1.0)
+
+    def second_loop(jj, r):
+        j = m - 1 - jj
+        beta = jnp.where(j < num_pairs, rho[j] * jnp.dot(y_hist[j], r), 0.0)
+        return r + s_hist[j] * (alphas[j] - beta)
+
+    return -lax.fori_loop(0, m, second_loop, gamma * q)
+
+
 def minimize_lbfgs(
     value_and_grad: Callable[[Array], tuple[Array, Array]] | None,
     x0: Array,
@@ -128,8 +158,10 @@ def minimize_lbfgs(
     """
     dtype = x0.dtype
     d = x0.shape[-1]
-    m = config.num_corrections
     t = config.max_iterations
+    # a solve of t iterations stores at most t pairs: slots past that are
+    # never valid, and each costs every lane of a vmapped solve its bytes
+    m = max(1, min(config.num_corrections, t))
     has_box = config.lower_bounds is not None or config.upper_bounds is not None
 
     if oracle is None:
@@ -167,7 +199,6 @@ def minimize_lbfgs(
         y_hist=jnp.zeros((m, d), dtype),
         rho=jnp.zeros((m,), dtype),
         num_pairs=jnp.zeros((), jnp.int32),
-        pos=jnp.zeros((), jnp.int32),
         reason=jnp.zeros((), jnp.int32),
         loss_hist=jnp.full((t + 1,), f0, dtype),
         gnorm_hist=jnp.full((t + 1,), jnp.linalg.norm(g0), dtype),
@@ -181,8 +212,8 @@ def minimize_lbfgs(
 
     def body(s: _LBFGSState) -> _LBFGSState:
         with scope("photon.lbfgs.direction"):
-            direction = two_loop_direction(
-                s.g, s.s_hist, s.y_hist, s.rho, s.num_pairs, s.pos
+            direction = _two_loop_newest_first(
+                s.g, s.s_hist, s.y_hist, s.rho, s.num_pairs
             )
             # Guard: if the direction is not a descent direction (numerics), fall
             # back to steepest descent.
@@ -256,17 +287,17 @@ def minimize_lbfgs(
             y_vec = g_new - s.g
             sy = jnp.dot(s_vec, y_vec)
             accept = sy > _CURVATURE_EPS
-            pos = s.pos
-            s_hist = jnp.where(
-                accept, s.s_hist.at[pos].set(s_vec), s.s_hist
-            )
-            y_hist = jnp.where(
-                accept, s.y_hist.at[pos].set(y_vec), s.y_hist
-            )
-            rho = jnp.where(
-                accept, s.rho.at[pos].set(1.0 / jnp.where(accept, sy, 1.0)), s.rho
-            )
-            pos = jnp.where(accept, (pos + 1) % m, pos)
+            # newest pair first: an accepted pair shifts the rest down one
+            # slot and the oldest falls off, so no slot is ever addressed by
+            # a per-lane index
+            def pushed(hist, row):
+                return jnp.where(
+                    accept, jnp.concatenate([row[None], hist[:-1]]), hist
+                )
+
+            s_hist = pushed(s.s_hist, s_vec)
+            y_hist = pushed(s.y_hist, y_vec)
+            rho = pushed(s.rho, 1.0 / jnp.where(accept, sy, 1.0))
             num_pairs = jnp.where(accept, s.num_pairs + 1, s.num_pairs)
 
             it = s.it + 1
@@ -292,7 +323,6 @@ def minimize_lbfgs(
                 y_hist=y_hist,
                 rho=rho,
                 num_pairs=num_pairs,
-                pos=pos,
                 reason=reason,
                 loss_hist=s.loss_hist.at[it].set(f_new),
                 gnorm_hist=s.gnorm_hist.at[it].set(gnorm_new),

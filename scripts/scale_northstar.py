@@ -42,11 +42,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 os.environ["JAX_PLATFORMS"] = "cpu"
-# 8 virtual device THREADS time-slice one physical core here, and XLA:CPU's
-# in-process all-reduce rendezvous hard-aborts at 40 s — a ~50M-entity
-# bucket's per-iteration interval blew it. 4M keeps the whole rendezvous
-# spread under ~10 s on a single core.
-os.environ.setdefault("PHOTON_RE_MAX_BUCKET_ENTITIES", "4000000")
 
 import jax  # noqa: E402
 

@@ -181,7 +181,7 @@ def test_sweep_level_resume_unit(tmp_path):
     full run's k-th sweep left off (states captured via sweep_callback)."""
     data = _game_data(seed=4)
     est = _estimator(grid=(1.0,), iters=3)
-    coords, _ = est._build_coordinates(data)
+    coords = est.build(data).coordinates
 
     captured = {}
 
@@ -201,7 +201,7 @@ def test_sweep_level_resume_unit(tmp_path):
     assert set(captured) == {0, 1, 2}
 
     est2 = _estimator(grid=(1.0,), iters=3)
-    coords2, _ = est2._build_coordinates(data)
+    coords2 = est2.build(data).coordinates
     resumed = run_coordinate_descent(
         coords2,
         ["fixed", "per-user"],

@@ -28,7 +28,24 @@ def tiny(monkeypatch, tmp_path):
     return workdir, sizes, data
 
 
-def test_train_score_serve_phases(tiny, capsys):
+@pytest.fixture()
+def own_arrays_only(monkeypatch):
+    """``assert_live_arrays_on`` looks at every live array of the process.
+    On a worker that ran another test file first, that file's meshed
+    coordinates may still be alive (jit caches keyed on the coordinate keep
+    them: ROADMAP C9), and the rehearsal then failed on arrays it never
+    made. Hold the phases to the arrays THEY make; ``chip_smoke.py`` itself,
+    a process of its own on the chip, keeps looking at all of them."""
+    left = jax.live_arrays()  # held here, so none of their ids is reused
+    theirs = {id(a) for a in left}
+    live_arrays = jax.live_arrays
+    monkeypatch.setattr(
+        jax, "live_arrays",
+        lambda *a, **k: [x for x in live_arrays(*a, **k) if id(x) not in theirs],
+    )
+
+
+def test_train_score_serve_phases(tiny, own_arrays_only, capsys):
     workdir, sizes, data = tiny
     devices = jax.devices()[:1]
     began = time.perf_counter()

@@ -254,7 +254,8 @@ def test_estimator_pool_matches_standalone_pool_rebuild():
         update_sequence=["fixed", "user", "item"],
         descent_iterations=1,
     )
-    coords, re_datasets = est._build_coordinates(data)
+    built = est.build(data)
+    coords, re_datasets = built.coordinates, built.re_datasets
     pool = est._build_shape_pool(data)
     for cid in ("user", "item"):
         rebuilt = build_random_effect_dataset(

@@ -392,37 +392,85 @@ def test_re_dense_fast_path_rejects_unsorted_full_rows():
             )
 
 
-def test_re_bucket_entity_cap_splits_and_preserves_coverage(monkeypatch):
-    """PHOTON_RE_MAX_BUCKET_ENTITIES splits oversized shape classes into
-    several same-shape buckets (bounds program size + the vmapped solve's
-    cross-device reduce interval) without losing or duplicating any
-    entity or sample."""
+@pytest.mark.parametrize("chunk", [64, 100, 299, 600])
+def test_chunked_bucket_solve_equals_the_whole_bucket(monkeypatch, chunk):
+    """A bucket solved as a loop over entity chunks inside the sweep
+    program gives every entity what the whole bucket at once gives it: a
+    lane's arithmetic never looks at the lanes beside it (elementwise
+    operations and reductions along its own axes; the while-loop batching
+    rule freezes a lane that has stopped), and the last chunk, moved back
+    to end on the last entity, solves the entities it shares with the chunk
+    before once more, to the same result. Chunk sizes that divide the singleton
+    bucket's entity count and that do not, down to one that leaves the
+    bucket whole."""
+    from photon_tpu.game import coordinate as coordinate_mod
+    from photon_tpu.game.coordinate import RandomEffectCoordinate
+
     rng = np.random.default_rng(41)
     n, users = 3_000, 900
     ids = ((rng.zipf(1.4, size=n) - 1) % users)
     ids[:users] = rng.permutation(users)
     x = rng.normal(size=(n, D_RE))
     data = GameData.build(
-        labels=rng.normal(size=n),
+        labels=(rng.uniform(size=n) < 0.4).astype(float),
         feature_shards={"per_user": CSRMatrix.from_dense(x)},
         id_tags={"userId": np.array([f"u{u:04d}" for u in ids])},
     )
-    cfg = _configs()["per-user"]
-    monkeypatch.delenv("PHOTON_RE_MAX_BUCKET_ENTITIES", raising=False)
-    ds_plain = build_random_effect_dataset(data, cfg)
-    monkeypatch.setenv("PHOTON_RE_MAX_BUCKET_ENTITIES", "100")
-    ds_cap = build_random_effect_dataset(data, cfg)
-    assert len(ds_cap.buckets) > len(ds_plain.buckets)
-    assert all(b.num_entities <= 100 for b in ds_cap.buckets)
-    # same entity set, each exactly once
-    all_ents = np.concatenate([b.entity_ids for b in ds_cap.buckets])
-    assert len(np.unique(all_ents)) == len(all_ents) == users
-    # same sample coverage in the flat score arrays
-    pos_cap = np.sort(np.concatenate([b.score_pos for b in ds_cap.buckets]))
-    pos_plain = np.sort(
-        np.concatenate([b.score_pos for b in ds_plain.buckets])
+    import dataclasses
+
+    cfg = _configs(TaskType.LOGISTIC_REGRESSION, re_l2=1.0)["per-user"]
+    # the deployment's solver settings (benchmarks/configs/glmix_ctr.json)
+    cfg = dataclasses.replace(
+        cfg,
+        optimization=dataclasses.replace(
+            cfg.optimization,
+            optimizer_config=OptimizerConfig(
+                max_iterations=5, ls_max_iterations=8
+            ),
+        ),
     )
-    np.testing.assert_array_equal(pos_cap, pos_plain)
+    ds = build_random_effect_dataset(data, cfg)
+    sizes = [b.num_entities for b in ds.buckets]
+    assert sizes[0] == 598 and sum(sizes) == users  # the one-row bucket
+    residual = jnp.asarray(rng.normal(size=n), jnp.float32)
+
+    def sweep():
+        # a coordinate of its own: the jitted programs are keyed on it
+        coord = RandomEffectCoordinate.build(data, ds, cfg)
+        return coord.train(residual, coord.initial_state())
+
+    whole_states, whole_infos = sweep()
+    # the budget at which ``chunk`` entities of the one-row bucket fit
+    opt = cfg.optimization.optimizer_config
+    monkeypatch.setattr(coordinate_mod, "_CHUNK_MULTIPLE", 1)
+    monkeypatch.setattr(
+        coordinate_mod, "RE_SOLVE_BYTES",
+        chunk * coordinate_mod.solve_entity_bytes(1, 8, opt),
+    )
+    assert coordinate_mod.solve_chunk_entities(598, 1, 8, opt) == min(chunk, 598)
+    states, infos = sweep()
+    # Not bitwise on this backend, and only here: XLA:CPU takes a chunk's
+    # lanes 8 or 16 at a time and finishes the rest in scalar code whose
+    # exp and log round the last place differently, and which lanes fall
+    # in that tail depends on the chunk's size. Read: 6 of 1520
+    # coefficients off by 1.2e-6 relative at chunk 64, none at 100, 299,
+    # 600. On the chip a chunk is whole tiles of 1024 lanes. What a solve
+    # COUNTS (iterations, evaluations, why it stopped) has to be equal.
+    for a, b in zip(whole_states, states):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-6
+        )
+    for ia, ib in zip(whole_infos, infos):
+        for name in ("iterations", "reason", "n_evals"):
+            np.testing.assert_array_equal(
+                np.asarray(getattr(ia, name)), np.asarray(getattr(ib, name)),
+                err_msg=name,
+            )
+        for name in ("value", "gradient", "loss_history"):
+            np.testing.assert_allclose(
+                np.asarray(getattr(ia, name)), np.asarray(getattr(ib, name)),
+                rtol=1e-5, atol=1e-5, err_msg=name,
+            )
 
 
 def test_passive_data_lower_bound_drops_scoring_rows():

@@ -203,6 +203,53 @@ def test_tron_logistic_converges():
     assert float(jnp.linalg.norm(g)) < 1e-3
 
 
+def _plateau(bump):
+    """A float32 objective of size 2.7e6 whose true gain over an iteration
+    is under the 0.25 its value resolves: a convex quadratic worth at most
+    0.18, under a constant. ``bump`` adds a last bit to every value off the
+    start point, as a rounded sum of 4 M terms does."""
+    big = jnp.float32(2_744_532.0)
+    scales = jnp.asarray([1.0, 4.0, 16.0, 64.0, 0.25, 0.0625, 2.0, 8.0], jnp.float32)
+    center = jnp.full((D,), 0.3, jnp.float32) / jnp.sqrt(scales)
+
+    def value_and_grad(x):
+        d = x - center
+        small = 0.5 * jnp.dot(scales * d, d)
+        moved = jnp.any(x != 0)
+        return big + small + jnp.where(moved, jnp.float32(bump), 0.0), scales * d
+
+    return value_and_grad
+
+
+@pytest.mark.parametrize("bump", [0.0, 0.25])
+def test_lbfgs_crosses_a_rounding_plateau(bump):
+    """Where the value cannot resolve an iteration's gain, the search goes
+    by the derivative and the solve runs on (optimize/linesearch.py): a
+    strict sufficient-decrease test fails every trial once a value reads a
+    last bit high, and ends the solve at iteration 0 by rounding."""
+    value_and_grad = _plateau(bump)
+    config = OptimizerConfig(max_iterations=10, tolerance=-1.0, ls_max_iterations=10)
+    res = minimize_lbfgs(value_and_grad, jnp.zeros((D,), jnp.float32), config)
+    assert int(res.reason) == ConvergenceReason.MAX_ITERATIONS
+    assert int(res.iterations) == 10
+    gnorm = np.asarray(res.grad_norm_history)
+    assert gnorm[-1] < 0.1 * gnorm[0]
+
+
+def test_line_search_still_fails_on_a_real_increase():
+    """Rounding's allowance is a few last bits of f0 and no more: along an
+    ascent direction every trial raises the value by far more, none is
+    accepted, and the search reports the failure."""
+    from photon_tpu.optimize.linesearch import wolfe_line_search
+
+    value_and_grad = _quadratic(jnp.ones((D,), jnp.float32))
+    x0 = jnp.zeros((D,), jnp.float32)
+    f0, g0 = value_and_grad(x0)
+    res = wolfe_line_search(value_and_grad, x0, g0, f0, g0, max_iterations=10)
+    assert not bool(res.success)
+    assert float(res.step) == 0.0
+
+
 def test_vmapped_lbfgs_batch_of_problems():
     # The random-effect pattern: many independent small solves under vmap.
     rng = np.random.default_rng(6)

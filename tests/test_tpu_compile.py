@@ -468,3 +468,68 @@ def test_cell_pass_temporaries_and_relayouts(cell_passes, which):
     assert under and all(
         p[-1] in ("photon.gather.fetch", "photon.gather.select") for p in under
     ), sorted(under)
+
+
+def test_cell_single_row_bucket_sweep_fits_its_budget(one_chip, tpu_branches):
+    """The per-user coordinate's sweep over the ``glmix_ctr.sweeps`` cell's
+    one-row bucket, [1 997 496, 1, 16] (the bucket the cell's structure seed
+    gives at 2^22 rows and 2^21 users, PERF.md PR 31): the solve runs as a
+    loop over entity chunks, and the program's temporaries stay under the
+    2 GB the cell allows the whole coordinate (before PR 31: 59 KB an
+    entity, 118 GB, and no chunk loop). Shapes only: no data is built."""
+    import json
+    import os
+
+    from photon_tpu.game import coordinate as coordinate_mod
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs", "glmix_ctr.json")) as f:
+        cell = json.load(f)
+    entities, rows, d = 1_997_496, 1, cell["random_effects"]["per_user"]["d"]
+    n = cell["features"]["n"]
+    opt = GLMProblemConfig(
+        task=TaskType.LOGISTIC_REGRESSION,
+        regularization=RegularizationContext(RegularizationType.L2),
+        optimizer_config=OptimizerConfig(
+            max_iterations=cell["solver"]["re_max_iterations"],
+            ls_max_iterations=cell["solver"]["re_ls_max_iterations"],
+        ),
+    )
+    coord = RandomEffectCoordinate(
+        config=RandomEffectCoordinateConfig(
+            random_effect_type="per_user", feature_shard="per_user",
+            optimization=opt, regularization_weights=(1.0,),
+            active_data_upper_bound=cell["random_effects"]["per_user"]["cap"],
+        ),
+        dataset=None, device_buckets=[],
+        problem_config=opt.with_regularization_weight(1.0),
+        num_samples=n, dtype=jnp.float32,
+    )
+    chunk = coordinate_mod.solve_chunk_entities(
+        entities, rows, d, opt.optimizer_config
+    )
+    assert chunk % 1024 == 0 and 4 <= -(-entities // chunk) <= 32
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    block = (
+        sds((entities, rows, d)), sds((entities, rows)), sds((entities, rows)),
+        sds((entities, rows)), sds((entities, rows), jnp.int32),
+    )
+    flat = (sds((entities, d)), sds((entities,), jnp.int32), sds((entities,), jnp.int32))
+    compiled = (
+        type(coord)
+        ._active_sweep_jit(True)
+        .lower(
+            coord, (block,), (flat,), sds((n,)), sds((n,)),
+            [sds((entities, d))], (0,), sds(()),
+        )
+        .compile()
+    )
+    m = _fits(compiled)
+    assert m.temp_size_in_bytes < 2 * (1 << 30), m.temp_size_in_bytes
+    text = compiled.as_text()
+    # the chunk loop sits under the bucket's scope, the solver under it
+    assert "photon.re.solve/while/body/" in text
+    assert "photon.re.chunk/vmap()/while/body/photon.lbfgs.linesearch" in text
