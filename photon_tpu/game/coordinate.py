@@ -49,6 +49,7 @@ from photon_tpu.ops.losses import POSITIVE_RESPONSE_THRESHOLD
 from photon_tpu.ops.normalization import NormalizationContext
 from photon_tpu.data.dataset import choose_sparse
 from photon_tpu.ops.objective import matvec
+from photon_tpu.optimize.common import one_solve_a_lane
 from photon_tpu.optimize.problem import GLMProblem, GLMProblemConfig
 from photon_tpu.types import Array, LabeledBatch, SparseBatch
 from photon_tpu.util import dispatch_count
@@ -591,10 +592,15 @@ class FixedEffectCoordinate(Coordinate):
         )
         return res.x, res
 
-    def _score_body(self, batch, norm_args, state: Array) -> Array:
+    def _score_body(
+        self, batch, norm_args, state: Array, product: Array | None = None
+    ) -> Array:
+        """The score at ``state``; over ``product`` (the feature product at
+        that point, ``GLMObjective.product``) where the caller holds it."""
         ctx = self._norm_ctx(norm_args)
-        eff = ctx.effective_coefficients(state)
-        s = matvec(batch, eff)
+        if product is None:
+            product = matvec(batch, ctx.effective_coefficients(state))
+        s = product
         if ctx.shifts is not None:
             s = s + ctx.margin_shift(state)
         return s
@@ -626,12 +632,17 @@ class FixedEffectCoordinate(Coordinate):
 
         with scope("photon.descent.residual"):
             residual = constrain_rows(total - score, self.mesh)
-        res = self._traced_problem(norm_args).solve(
+        res = self._traced_problem(norm_args).solve_keeping_product(
             batch, state, reg_weight, extra_offsets=residual
         )
         with scope("photon.descent.rescore"):
-            new_score = self._score_body(batch, norm_args, res.x)
+            # the solve's last exact evaluation made X·x one line earlier:
+            # the score takes that product and reads the block no more
+            new_score = self._score_body(
+                batch, norm_args, res.x, product=res.product
+            )
             new_total = constrain_rows(residual + new_score, self.mesh)
+        res = res._replace(product=None)  # an [n] array: not the row's to keep
         # health scalars fold into THIS program (coefficients and the
         # solve outputs are replicated under a mesh, so the reductions
         # stay collective-free); descent reads them back as the barrier
@@ -994,9 +1005,10 @@ class RandomEffectCoordinate(Coordinate):
                 )
                 return problem.solve(batch, w0_e, reg_weight)
 
-            return jax.vmap(solve_one)(
-                features, labels, offsets_eff, train_weights, w0
-            )
+            with one_solve_a_lane():
+                return jax.vmap(solve_one)(
+                    features, labels, offsets_eff, train_weights, w0
+                )
 
         def vmapped_solve(features, labels, offsets_eff, train_weights,
                           w0, reg_weight):

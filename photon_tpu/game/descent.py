@@ -262,6 +262,10 @@ def _poison_state_nan(state):
     return _poison_tree_jit(state)
 
 
+#: the per-solve counters of a health row (``obs/health.sweep_health``)
+_SOLVE_COUNTERS = ("iterations", "evaluations", "feature_passes")
+
+
 def _read_health(
     health_dev: Mapping[str, dict | None], barrier
 ) -> dict[str, dict]:
@@ -276,26 +280,22 @@ def _read_health(
     for cid in order:
         h = health_dev[cid]
         flat.extend((h["loss"], h["gnorm"], h["finite"]))
-        # the solves' counters, one pair per solve (obs/health.py); a
+        # the solves' counters, one of each per solve (obs/health.py); a
         # coordinate kind that folds none gives empty lists
-        flat.extend(h.get("iterations", ()))
-        flat.extend(h.get("evaluations", ()))
+        for name in _SOLVE_COUNTERS:
+            flat.extend(h.get(name, ()))
     # phl-ok: PHL002 THE per-sweep barrier read-back — health scalars ride the existing sync
     vals = fetch_scalars(flat, barrier=barrier).tolist()
     out: dict[str, dict] = {}
     at = 0
     for cid in order:
-        solves = len(health_dev[cid].get("iterations", ()))
         loss, gnorm, finite = vals[at : at + 3]
-        counts = [int(v) for v in vals[at + 3 : at + 3 + 2 * solves]]
-        at += 3 + 2 * solves
-        out[cid] = {
-            "loss": loss,
-            "gnorm": gnorm,
-            "finite": bool(finite),
-            "iterations": counts[:solves],
-            "evaluations": counts[solves:],
-        }
+        at += 3
+        out[cid] = {"loss": loss, "gnorm": gnorm, "finite": bool(finite)}
+        for name in _SOLVE_COUNTERS:
+            solves = len(health_dev[cid].get(name, ()))
+            out[cid][name] = [int(v) for v in vals[at : at + solves]]
+            at += solves
     return out
 
 

@@ -96,6 +96,7 @@ from photon_tpu.models.glm import model_for_task
 from photon_tpu.obs import causal
 from photon_tpu.obs import memory as obs_memory
 from photon_tpu.ops.normalization import NormalizationContext
+from photon_tpu.optimize.common import one_solve_a_lane
 from photon_tpu.optimize.problem import GLMProblem
 from photon_tpu.types import LabeledBatch
 from photon_tpu.util import dispatch_count, faults
@@ -820,9 +821,10 @@ class StreamingRandomEffectCoordinate(RandomEffectCoordinate):
             batch = LabeledBatch(features=f, labels=l, offsets=o, weights=w)
             return problem.solve(batch, w0_e, reg_weight)
 
-        res = jax.vmap(solve_one)(
-            features, labels, offsets_eff, train_weights, w0
-        )
+        with one_solve_a_lane():
+            res = jax.vmap(solve_one)(
+                features, labels, offsets_eff, train_weights, w0
+            )
         gsq = jnp.sum(jnp.square(res.gradient.astype(jnp.float32)), axis=-1)
         return res.x, res.value.astype(jnp.float32), gsq
 

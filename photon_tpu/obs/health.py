@@ -86,10 +86,12 @@ def sweep_health(state, info) -> dict:
     - ``gnorm``: global L2 norm over every final gradient leaf;
     - ``finite``: fused sentinel — loss AND gnorm AND every float state
       leaf finite. Any NaN/Inf anywhere in the new state flips it;
-    - ``iterations``, ``evaluations``: one entry per solve (an RE
-      coordinate has one per bucket), the optimizer iterations and the
-      objective evaluations of its slowest lane. They ride home with the
-      triple in the sweep's one read-back.
+    - ``iterations``, ``evaluations``, ``feature_passes``: one entry per
+      solve (an RE coordinate has one per bucket), the optimizer
+      iterations, the objective evaluations and the passes over the
+      feature block (``OptimizeResult.n_feature_passes``: what ran) of its
+      slowest lane. They ride home with the triple in the sweep's one
+      read-back.
 
     ``info`` is one OptimizeResult-like or a list of them (the RE
     multi-bucket case); ``state`` is the coordinate's new state pytree.
@@ -115,4 +117,7 @@ def sweep_health(state, info) -> dict:
         "finite": finite,
         "iterations": tuple(jnp.max(jnp.asarray(r.iterations)) for r in infos),
         "evaluations": tuple(jnp.max(jnp.asarray(r.n_evals)) for r in infos),
+        "feature_passes": tuple(
+            jnp.max(jnp.asarray(r.n_feature_passes)) for r in infos
+        ),
     }

@@ -193,12 +193,23 @@ class GLMObjective:
 
     # --- margins ----------------------------------------------------------
 
-    def margins(self, coef: Array, batch) -> Array:
+    def product(self, coef: Array, batch) -> Array:
+        """X·(coef .* factor): the part of the margins that reads the
+        feature block, and all of a score that does."""
+        with scope("photon.matvec"):
+            return _matvec(
+                batch, self.normalization.effective_coefficients(coef)
+            )
+
+    def margins(self, coef: Array, batch, product: Array | None = None) -> Array:
+        """The margins at ``coef``; over ``product`` (``self.product`` of
+        the same point) where the caller holds it."""
+        if product is None:
+            product = self.product(coef, batch)
         # offsets and shift ride in the matvec's scope: the compiler fuses
         # them onto the product, and a fusion is named by its last operation
         with scope("photon.matvec"):
-            eff = self.normalization.effective_coefficients(coef)
-            z = _matvec(batch, eff) + batch.offsets
+            z = product + batch.offsets
             if self.normalization.shifts is not None:
                 z = z + self.normalization.margin_shift(coef)
             return z
@@ -234,12 +245,15 @@ class GLMObjective:
         return self._value_grad_margins(coef, batch)[:2]
 
     def _value_grad_margins(
-        self, coef: Array, batch
+        self, coef: Array, batch, z: Array | None = None
     ) -> tuple[Array, Array, Array]:
         """(f, g, z) — single implementation shared by the black-box path
         and the directional oracle, so the two line-search modes can never
-        drift onto different objectives."""
-        z = self.margins(coef, batch)
+        drift onto different objectives. ``z``: the margins at ``coef``
+        where the caller holds them (one backward pass is then all that
+        reads the block)."""
+        if z is None:
+            z = self.margins(coef, batch)
         with scope("photon.loss"):
             losses, d1 = self.loss.loss_and_d1(z, batch.labels)
             value = jnp.sum(
@@ -274,6 +288,11 @@ class GLMObjective:
         def full(x: Array):
             return self._value_grad_margins(x, batch)
 
+        def full_product(x: Array):
+            p = self.product(x, batch)
+            z = self.margins(x, batch, product=p)
+            return (*self._value_grad_margins(x, batch, z), p)
+
         def dir_setup(carry_z: Array, x: Array, d: Array):
             z_d = matvec(batch, self.normalization.effective_coefficients(d))
             if self.normalization.shifts is not None:
@@ -307,7 +326,22 @@ class GLMObjective:
 
             return phi, accept
 
-        return DirectionalOracle(full=full, dir_setup=dir_setup)
+        return DirectionalOracle(
+            full=full,
+            dir_setup=dir_setup,
+            at_zero=functools.partial(self._at_zero, batch),
+            full_product=full_product,
+        )
+
+    def _at_zero(self, batch, x: Array):
+        """``_value_grad_margins`` at zero coefficients without a forward
+        pass, the oracles' ``at_zero``: X·0 is 0 and ``margin_shift(0)`` is
+        0, so the margins there are the offsets (in the shape and type
+        ``margins`` gives at ``x``); the loss on them and one backward pass
+        are all that is left."""
+        like = jax.eval_shape(self.margins, x, batch)
+        z = jnp.broadcast_to(batch.offsets, like.shape).astype(like.dtype)
+        return self._value_grad_margins(jnp.zeros_like(x), batch, z)
 
     def smooth_margin_oracle(self, batch) -> SmoothMarginOracle:
         """Value-only trial oracle for OWLQN (optimize/owlqn.py): each
@@ -332,6 +366,7 @@ class GLMObjective:
             full=lambda x: self._value_grad_margins(x, batch),
             value_margins=value_margins,
             grad_from_margins=grad_from_margins,
+            at_zero=functools.partial(self._at_zero, batch),
         )
 
     def hessian_operator(self, coef: Array, batch) -> Callable:

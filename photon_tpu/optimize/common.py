@@ -14,8 +14,10 @@ Because results are pytrees of fixed shape, the optimizers compose with
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import enum
+import threading
 from typing import NamedTuple
 
 import jax
@@ -64,6 +66,31 @@ class OptimizerConfig:
         return dataclasses.replace(self, max_iterations=15, tolerance=1e-5)
 
 
+_TRACING = threading.local()  # per thread: programs are traced in a pool too
+
+
+@contextlib.contextmanager
+def one_solve_a_lane():
+    """Entered, while tracing, around a ``vmap`` of solves (a random
+    effect's bucket: one entity a lane). A solve traced inside does not ask
+    whether ITS start point is the zero point: under ``vmap`` that ``cond``
+    is a select which runs both branches on every lane, and it picks a
+    zero lane's start values from another subgraph than a warm lane's,
+    which two programs over the same lanes need not round alike (the
+    streamed fit's chunk programs follow the whole-bucket program bit for
+    bit). A ``vmap`` without it is still right, through the select."""
+    was = getattr(_TRACING, "lanes", False)
+    _TRACING.lanes = True
+    try:
+        yield
+    finally:
+        _TRACING.lanes = was
+
+
+def solving_a_lane() -> bool:
+    return getattr(_TRACING, "lanes", False)
+
+
 class DirectionalOracle(NamedTuple):
     """Objective interface for margin-space line searches (minimize_lbfgs).
 
@@ -73,10 +100,19 @@ class DirectionalOracle(NamedTuple):
     cost once; ``phi(alpha) -> (f, dphi, aux)`` is the cheap scalar oracle
     for the Wolfe search, ``accept(alpha) -> (g, carry')`` produces the
     accepted point's gradient and next carry.
+    ``at_zero(x) -> (f, g, carry)`` — what ``full(zeros_like(x))`` gives,
+    from a model that knows its product with zero is zero: no forward
+    pass over the feature block. ``None``: the solve calls ``full``.
+    ``full_product(x) -> (f, g, carry, product)`` — ``full`` handing back
+    the feature product it made on the way, before anything was added to
+    it, for a caller that scores the point next
+    (``minimize_lbfgs(keep_product=True)``). ``None``: there is none.
     """
 
     full: object
     dir_setup: object
+    at_zero: object = None
+    full_product: object = None
 
 
 class SmoothMarginOracle(NamedTuple):
@@ -88,12 +124,16 @@ class SmoothMarginOracle(NamedTuple):
     is one forward pass; ``grad_from_margins(x, z) -> g`` turns the
     accepted trial's margins into the gradient with one backward pass —
     trials drop from 2 feature passes to 1, and the gradient is paid once
-    per iteration. ``full(x) -> (f, g, z)`` for init/box re-evaluations.
+    per iteration. ``full(x) -> (f, g, z)`` for init/box re-evaluations;
+    ``at_zero(x) -> (f, g, z)`` is ``full(zeros_like(x))`` without the
+    forward pass (see ``DirectionalOracle``), ``None`` where the model has
+    no such shortcut.
     """
 
     full: object
     value_margins: object
     grad_from_margins: object
+    at_zero: object = None
 
 
 class OptimizeResult(NamedTuple):
@@ -120,7 +160,14 @@ class OptimizeResult(NamedTuple):
     # n_evals (trial count, reference-comparable) no longer implies
     # 2 passes each; benches must use this for bytes/FLOP accounting.
     # 0 ⇒ not tracked (older paths): assume 2·n_evals + 2·n_hvp.
+    # L-BFGS and OWL-QN count what ran: 1 at a start from zero (the
+    # backward pass there), 3 at any other start, then every pass of the
+    # iterations and 2 for a last exact re-evaluation.
     n_feature_passes: Array | int = 0  # int32 scalar
+    # X·x̃ at ``x`` (x̃ the coefficients as the feature block meets them;
+    # offsets and margin shift NOT added), as the last exact evaluation
+    # made it. Only where the caller asked the solve to keep it.
+    product: Array | None = None
 
     @property
     def converged(self) -> Array:

@@ -30,6 +30,7 @@ from photon_tpu.optimize.common import (
     OptimizerConfig,
     convergence_check,
     project_to_box,
+    solving_a_lane,
 )
 from photon_tpu.optimize.linesearch import (
     wolfe_line_search,
@@ -137,12 +138,42 @@ def _two_loop_newest_first(
     return -lax.fori_loop(0, m, second_loop, gamma * q)
 
 
+def evaluate_start(eval_at, at_zero, x_init: Array, dtype):
+    """A solve's two boundary evaluations, L-BFGS's and OWL-QN's alike:
+    ``(f, g, carry)`` at ``x_init``, ``(f, g)`` at zero, and the feature
+    passes that ran, an int32 scalar.
+
+    ``at_zero`` (an oracle's; ``None`` for a black box) evaluates the zero
+    point with one backward pass, and where every value of ``x_init`` is
+    zero that evaluation is the start point's as well: 1 pass; 3 from any
+    other start. The test is made in the program, on the values. One solve
+    a lane (``common.one_solve_a_lane``) evaluates the start outright, as
+    the select would on every lane: 3 passes. Without ``at_zero`` both
+    points go through ``eval_at``: 4 passes."""
+    if at_zero is None:
+        f_zero, g_zero, _ = eval_at(jnp.zeros_like(x_init))
+        f0, g0, carry0 = eval_at(x_init)
+        return f0, g0, carry0, f_zero, g_zero, jnp.asarray(4, jnp.int32)
+    f_zero, g_zero, carry_zero = at_zero(x_init)
+    f_zero, g_zero = f_zero.astype(dtype), g_zero.astype(dtype)
+    if solving_a_lane():
+        f0, g0, carry0 = eval_at(x_init)
+        return f0, g0, carry0, f_zero, g_zero, jnp.asarray(3, jnp.int32)
+    moved = jnp.any(x_init != 0)
+    f0, g0, carry0 = lax.cond(
+        moved, lambda: eval_at(x_init), lambda: (f_zero, g_zero, carry_zero)
+    )
+    passes = jnp.where(moved, 3, 1).astype(jnp.int32)
+    return f0, g0, carry0, f_zero, g_zero, passes
+
+
 def minimize_lbfgs(
     value_and_grad: Callable[[Array], tuple[Array, Array]] | None,
     x0: Array,
     config: OptimizerConfig = OptimizerConfig(),
     *,
     oracle: DirectionalOracle | None = None,
+    keep_product: bool = False,
 ) -> OptimizeResult:
     """Minimize a smooth objective with L-BFGS.
 
@@ -155,6 +186,16 @@ def minimize_lbfgs(
     one forward (direction margins) + one backward (accepted gradient)
     feature pass. ``n_evals`` still counts line-search trials (the
     reference-comparable number); ``n_feature_passes`` counts real passes.
+
+    The boundary evaluations reuse what the solve holds. An oracle with
+    ``at_zero`` gives the zero point's value and gradient (the absolute
+    tolerances' scale) from one backward pass, and a start point whose
+    every value is zero IS that point: it is not evaluated again. That is
+    decided in the program from ``x0``'s values, so zeros handed to a
+    jitted caller as an argument take it too; under ``vmap`` both branches
+    run (``evaluate_start``). ``keep_product=True`` hands out the feature
+    product of the last exact re-evaluation (``OptimizeResult.product``)
+    where the oracle has ``full_product`` and the re-evaluation is made.
     """
     dtype = x0.dtype
     d = x0.shape[-1]
@@ -182,12 +223,12 @@ def minimize_lbfgs(
         return f.astype(dtype), g.astype(dtype), carry
 
     # Absolute tolerances from the zero-coefficient state (Optimizer.scala:181).
-    f_zero, g_zero, _ = eval_at(jnp.zeros_like(x0))
+    x_init = project_to_box(x0, config.lower_bounds, config.upper_bounds)
+    f0, g0, carry0, f_zero, g_zero, start_passes = evaluate_start(
+        eval_at, oracle.at_zero, x_init, dtype
+    )
     loss_abs_tol = jnp.abs(f_zero) * config.tolerance
     grad_abs_tol = jnp.linalg.norm(g_zero) * config.tolerance
-
-    x_init = project_to_box(x0, config.lower_bounds, config.upper_bounds)
-    f0, g0, carry0 = eval_at(x_init)
 
     init = _LBFGSState(
         it=jnp.zeros((), jnp.int32),
@@ -203,7 +244,7 @@ def minimize_lbfgs(
         loss_hist=jnp.full((t + 1,), f0, dtype),
         gnorm_hist=jnp.full((t + 1,), jnp.linalg.norm(g0), dtype),
         n_evals=jnp.asarray(2, jnp.int32),  # zero-state + initial point
-        n_passes=jnp.asarray(4, jnp.int32),  # 2 full evals x 2 passes
+        n_passes=start_passes,
         carry=carry0,
     )
 
@@ -333,7 +374,7 @@ def minimize_lbfgs(
 
     s = lax.while_loop(cond, body, init)
 
-    f_final, g_final = s.f, s.g
+    f_final, g_final, product = s.f, s.g, None
     n_evals, n_passes = s.n_evals, s.n_passes
     if oracle.dir_setup is not None and not has_box:
         # (the box path re-evaluates at the projected point every
@@ -349,7 +390,11 @@ def minimize_lbfgs(
         # periodic lax.cond refresh degrades to select under vmap and
         # would charge every per-entity lane the full evaluation every
         # iteration.
-        f_final, g_final, _ = eval_at(s.x)
+        if keep_product and oracle.full_product is not None:
+            f_final, g_final, _, product = oracle.full_product(s.x)
+            f_final, g_final = f_final.astype(dtype), g_final.astype(dtype)
+        else:
+            f_final, g_final, _ = eval_at(s.x)
         n_evals = n_evals + 1
         n_passes = n_passes + 2
 
@@ -374,4 +419,5 @@ def minimize_lbfgs(
         n_evals=n_evals,
         n_hvp=jnp.zeros((), jnp.int32),
         n_feature_passes=n_passes,
+        product=product,
     )

@@ -27,7 +27,11 @@ from photon_tpu.optimize.common import (
     convergence_check,
     project_to_box,
 )
-from photon_tpu.optimize.lbfgs import _CURVATURE_EPS, two_loop_direction
+from photon_tpu.optimize.lbfgs import (
+    _CURVATURE_EPS,
+    evaluate_start,
+    two_loop_direction,
+)
 from photon_tpu.types import Array
 
 
@@ -123,9 +127,10 @@ def _owlqn_machinery(
         if has_box:
             x0 = project_to_box(x0, config.lower_bounds, config.upper_bounds)
         # Absolute tolerances off the zero state (Optimizer.scala:181).
-        f_zero, g_zero, _ = eval_smooth(jnp.zeros_like(x0))
+        f0s, g0, carry0, f_zero, g_zero, start_passes = evaluate_start(
+            eval_smooth, oracle.at_zero, x0, dtype
+        )
         pg_zero = pseudo_gradient(jnp.zeros_like(x0), g_zero, l1)
-        f0s, g0, carry0 = eval_smooth(x0)
         f0 = full_value(f0s, x0)
         return _OWLQNState(
             it=jnp.zeros((), jnp.int32),
@@ -143,7 +148,7 @@ def _owlqn_machinery(
                 (t + 1,), jnp.linalg.norm(pseudo_gradient(x0, g0, l1)), dtype
             ),
             n_evals=jnp.asarray(2, jnp.int32),  # zero-state + initial point
-            n_passes=jnp.asarray(4, jnp.int32),
+            n_passes=start_passes,
             loss_abs_tol=jnp.abs(f_zero) * config.tolerance,
             grad_abs_tol=jnp.linalg.norm(pg_zero) * config.tolerance,
             carry=carry0,

@@ -194,6 +194,33 @@ class GLMProblem:
         work is added. Per-iteration counters (``n_evals``, line-search
         trials) live in the returned OptimizeResult; eager callers feed
         them to the registry via :func:`record_optimize_metrics`."""
+        return self._spanned_solve(
+            batch, w0, reg_weight, extra_offsets=extra_offsets
+        )
+
+    def solve_keeping_product(
+        self,
+        batch: LabeledBatch,
+        w0: Array,
+        reg_weight=None,
+        *,
+        extra_offsets: Array | None = None,
+    ) -> OptimizeResult:
+        """``solve`` for a caller that scores the solution next: the
+        result's ``product`` is ``objective.product(result.x, batch)`` as
+        the solve's last exact evaluation made it, so the score needs no
+        pass of its own. ``None`` where the optimizer ends on no such
+        evaluation (OWL-QN, TRON, box constraints, the black-box line
+        search): the caller then makes the product itself."""
+        return self._spanned_solve(
+            batch,
+            w0,
+            reg_weight,
+            extra_offsets=extra_offsets,
+            keep_product=True,
+        )
+
+    def _spanned_solve(self, batch, w0, reg_weight, **kwargs) -> OptimizeResult:
         with obs.span(
             "optimize.solve",
             cat="solve",
@@ -201,9 +228,7 @@ class GLMProblem:
             task=self.config.task.name,
         ):
             obs.counter("optimize.solves")
-            return self._solve(
-                batch, w0, reg_weight, extra_offsets=extra_offsets
-            )
+            return self._solve(batch, w0, reg_weight, **kwargs)
 
     def _solve(
         self,
@@ -212,6 +237,7 @@ class GLMProblem:
         reg_weight=None,
         *,
         extra_offsets: Array | None = None,
+        keep_product: bool = False,
     ) -> OptimizeResult:
         if extra_offsets is not None:
             batch = batch._replace(offsets=batch.offsets + extra_offsets)
@@ -272,7 +298,11 @@ class GLMProblem:
         if full_ls:
             return minimize_lbfgs(vg, w0, cfg)
         return minimize_lbfgs(
-            None, w0, cfg, oracle=objective.directional_oracle(batch)
+            None,
+            w0,
+            cfg,
+            oracle=objective.directional_oracle(batch),
+            keep_product=keep_product,
         )
 
     # --- variances --------------------------------------------------------

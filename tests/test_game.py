@@ -749,3 +749,71 @@ def test_fixed_effect_bf16_feature_storage():
         assert w.dtype == jnp.float32
         out[bf16] = np.asarray(w)
     np.testing.assert_allclose(out[True], out[False], rtol=0.05, atol=0.02)
+
+
+@pytest.mark.parametrize("representation", ["DENSE", "SPARSE"])
+def test_fixed_effect_sweep_rescores_from_its_solve_and_counts_its_passes(
+    representation,
+):
+    """A fixed-effect sweep takes its new score from the product its solve's
+    last exact evaluation made: the same bits as ``coordinate.score`` of the
+    new state. Its health row counts the passes over the feature block that
+    RAN: from the zero states 1 (the zero point's backward pass) + 2 an
+    iteration + 2 (the last exact re-evaluation), and 2 more from any other
+    start (the start point's own evaluation): 23 and 25 at 10 iterations."""
+    from photon_tpu.game.config import FeatureRepresentation
+    from photon_tpu.game.descent import run_coordinate_descent
+
+    data, *_ = _make_game_data(task="logistic")
+    opt = GLMProblemConfig(
+        task=TaskType.LOGISTIC_REGRESSION,
+        # no tolerance stops the fixed effect: every solve runs 10 iterations
+        optimizer_config=OptimizerConfig(max_iterations=10, tolerance=-1.0),
+    )
+    configs = _configs(task=TaskType.LOGISTIC_REGRESSION)
+    configs["fixed"] = FixedEffectCoordinateConfig(
+        feature_shard="global",
+        optimization=opt,
+        regularization_weights=(0.1,),
+        representation=FeatureRepresentation[representation],
+    )
+    built = GameEstimator(
+        task=TaskType.LOGISTIC_REGRESSION,
+        coordinate_configs=configs,
+        update_sequence=["fixed", "per-user"],
+        descent_iterations=2,
+        dtype=jnp.float32,
+    ).build(data)
+    cd = run_coordinate_descent(
+        built.coordinates,
+        built.update_sequence,
+        built.descent_iterations,
+        initial_states=built.initial_states(),
+    )
+    rows = [row["health"]["fixed"] for row in cd.tracker if "health" in row]
+    assert [r["iterations"] for r in rows] == [[10], [10]]
+    assert [r["feature_passes"] for r in rows] == [[23], [25]]
+    # the random effect's buckets count theirs too, one entry a bucket
+    per_user = [row["health"]["per-user"] for row in cd.tracker if "health" in row]
+    for r in per_user:
+        assert len(r["feature_passes"]) == len(r["iterations"]) > 0
+        assert all(p >= 2 * i + 1 for p, i in zip(r["feature_passes"], r["iterations"]))
+
+    fixed = built.coordinates["fixed"]
+    n = data.labels.shape[0]
+    for state in (fixed.initial_state(), cd.states["fixed"]):
+        total = jnp.asarray(
+            np.random.default_rng(5).normal(size=n).astype(np.float32)
+        )
+        score = fixed.score(state)
+        total = total + score
+        new_state, new_score, new_total, info, _ = fixed.sweep_step(
+            total, score, state, donate=False
+        )
+        np.testing.assert_array_equal(
+            np.asarray(new_score), np.asarray(fixed.score(new_state))
+        )
+        np.testing.assert_array_equal(
+            np.asarray(new_total), np.asarray((total - score) + new_score)
+        )
+        assert info.product is None  # the row keeps no [n] array

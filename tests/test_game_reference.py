@@ -162,6 +162,24 @@ def test_step_counts_the_fixed_effect_s_passes_from_the_barrier(fitted):
     assert len(per_step) == 1 and per_step[0] > 0
 
 
+def test_fixed_effect_solves_count_the_passes_that_ran(fitted):
+    """The passes over the feature block a fixed-effect solve RAN, beside the
+    ones ``work_game`` says it needed: from the zero states the zero point's
+    backward pass, 2 an iteration and the last exact re-evaluation's 2; from
+    sweep 1's point the start's own evaluation on top. The rescoring reads
+    the block no more (it takes the re-evaluation's product)."""
+    from benchmarks.lib import work_game
+
+    infos = fitted["state"].fe_infos
+    assert len(infos) == 2
+    for i, info in enumerate(infos):
+        it = int(info.iterations)
+        ran = int(info.n_feature_passes)
+        assert ran == 2 * it + (3 if i == 0 else 5)
+        assert ran - work_game.lbfgs_passes(it, from_zero=i == 0) == (2 if i == 0 else 3)
+        assert info.product is None
+
+
 def test_fit_runs_on_the_built_handle(fitted, runner):
     """``GameEstimator.fit`` is ``build`` + the same descent: its model holds
     the coefficients the driven sweeps ended on, to the bit."""

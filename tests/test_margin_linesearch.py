@@ -39,9 +39,10 @@ def _batch(rng, n, d, poisson=False):
     )
 
 
+@pytest.mark.parametrize("from_zero", [True, False])
 @pytest.mark.parametrize("poisson", [False, True])
 @pytest.mark.parametrize("normalized", [False, True])
-def test_oracle_matches_blackbox(poisson, normalized):
+def test_oracle_matches_blackbox(poisson, normalized, from_zero):
     rng = np.random.default_rng(0)
     n, d = 400, 24
     batch = _batch(rng, n, d, poisson=poisson)
@@ -59,6 +60,8 @@ def test_oracle_matches_blackbox(poisson, normalized):
     obj = GLMObjective(loss=loss, l2_weight=0.7, normalization=norm)
     cfg = OptimizerConfig(max_iterations=60, tolerance=1e-8)
     w0 = jnp.zeros((d,), jnp.float32)
+    if not from_zero:
+        w0 = w0.at[1].set(0.05)
 
     res_full = minimize_lbfgs(
         lambda w: obj.value_and_gradient(w, batch), w0, cfg
@@ -72,10 +75,13 @@ def test_oracle_matches_blackbox(poisson, normalized):
     np.testing.assert_allclose(
         np.asarray(res_m.x), np.asarray(res_full.x), rtol=5e-3, atol=5e-4
     )
-    # the point of the oracle: feature passes bounded by 2/iteration + init
-    # + one final exact re-evaluation (drift bound), independent of
-    # line-search trial count
-    assert int(res_m.n_feature_passes) == 4 + 2 * int(res_m.iterations) + 2
+    # the point of the oracle: feature passes bounded by 2/iteration + the
+    # start + one final exact re-evaluation (drift bound), independent of
+    # line-search trial count. The start is counted as it ran: the zero
+    # point's backward pass alone where the solve starts there, and the
+    # start point's two passes on top where it does not
+    start = 1 if from_zero else 3
+    assert int(res_m.n_feature_passes) == start + 2 * int(res_m.iterations) + 2
     assert int(res_full.n_feature_passes) == 2 * int(res_full.n_evals)
 
 
@@ -148,7 +154,8 @@ def test_oracle_with_box_constraints():
     assert float(res.value) == pytest.approx(float(res_full.value), rel=1e-4)
 
 
-def test_owlqn_value_only_trials_match_blackbox():
+@pytest.mark.parametrize("from_zero", [True, False])
+def test_owlqn_value_only_trials_match_blackbox(from_zero):
     """OWLQN's SmoothMarginOracle (value-only trials, gradient from carried
     margins) reproduces the black-box solve, including the sparsity
     pattern, and tracks passes = trials + 1 per iteration."""
@@ -160,6 +167,8 @@ def test_owlqn_value_only_trials_match_blackbox():
     obj = GLMObjective(loss=LogisticLoss, l2_weight=0.05, l1_weight=0.1)
     cfg = OptimizerConfig(max_iterations=50)
     w0 = jnp.zeros((d,), jnp.float32)
+    if not from_zero:
+        w0 = w0.at[1].set(0.05)
 
     res_full = minimize_owlqn(
         lambda w: obj.value_and_gradient(w, batch), w0, 0.1, cfg
@@ -176,8 +185,10 @@ def test_owlqn_value_only_trials_match_blackbox():
     np.testing.assert_array_equal(
         np.asarray(res_m.x) == 0.0, np.asarray(res_full.x) == 0.0
     )
-    # value-only trials: passes strictly below the black-box 2-per-trial
-    assert int(res_m.n_feature_passes) == 4 + int(res_m.n_evals) - 2 + int(
+    # value-only trials: passes strictly below the black-box 2-per-trial;
+    # the start counted as it ran (1 from zero, 3 from elsewhere)
+    start = 1 if from_zero else 3
+    assert int(res_m.n_feature_passes) == start + int(res_m.n_evals) - 2 + int(
         res_m.iterations
     )
     assert int(res_full.n_feature_passes) == 4 + 2 * (

@@ -117,9 +117,9 @@ def _config(name: str) -> dict:
     return {**config, **config["rehearse"]}
 
 
-def _sparse_segment_program(monkeypatch):
-    """``sparse_poisson``'s ``SegmentedOWLQN`` segment, window layout forced
-    on, as the TPU takes it: prefix rmatvec, chunked gather."""
+def _sparse_segmented(monkeypatch):
+    """``sparse_poisson``'s ``SegmentedOWLQN`` over its batch, window layout
+    forced on, as the TPU takes it: prefix rmatvec, chunked gather."""
     from photon_tpu.ops.losses import loss_for_task
     from photon_tpu.ops.objective import GLMObjective
     from photon_tpu.ops.sparse_windows import maybe_build_windows
@@ -162,7 +162,14 @@ def _sparse_segment_program(monkeypatch):
         oracle_factory=objective.smooth_margin_oracle,
         segment_iters=solver["segment_iters"],
     )
-    state = jax.eval_shape(seg._init_f, jnp.zeros((d,), jnp.float32), batch)
+    return seg, batch, jax.ShapeDtypeStruct((d,), jnp.float32)
+
+
+def _sparse_segment_program(monkeypatch):
+    """Its segment program, and the shapes of the data it reads."""
+    seg, batch, w0 = _sparse_segmented(monkeypatch)
+    windows = batch.windows
+    state = jax.eval_shape(seg._init_f, w0, batch)
     data_shapes = {
         _shape(batch.indices), _shape(batch.values), _shape(windows.rows),
         _shape(windows.vals), _shape(windows.bounds),
@@ -363,3 +370,141 @@ ENTRY %main (a: f32[8], b: (s32[], f32[8])) -> f32[8] {
     stripped = hlo.strip_metadata(text)
     assert "metadata" not in stripped
     assert "multiply(%reduce-window.1, %a)" in stripped
+
+
+# --- the passes a solve's boundary evaluations may hold ---------------------
+#
+# Compiled for the CPU with the passes cut into segments, a pass over the
+# feature block is a ``while`` under ``photon.matvec`` (forward) or
+# ``photon.rmatvec`` (backward), and its name stack says where it sits: in a
+# branch of the start's ``cond``, in the optimizer's iteration loop, or
+# in neither.
+
+_PLACE_RE = re.compile(r"/(cond/branch_\d+_fun|while/body)/")
+
+
+def _passes_by_place(compiled) -> dict[tuple[str, str], list[str]]:
+    """``{(place, scope): [names, in text order]}`` of the program's pass
+    loops: place is ``branch_0``/``branch_1`` of the start's ``cond``,
+    ``iterations`` (the optimizer's loop) or ``top``; scope is the pass's
+    ``photon.matvec`` or ``photon.rmatvec``."""
+    out: dict[tuple[str, str], list[str]] = {}
+    for ins in hlo.parse_instructions(compiled).values():
+        # the loop's OWN name stack: a scope join also hands the optimizer's
+        # loop the scope of what it encloses (on a TPU, ``photon.matvec``)
+        path = hlo.scope_path(ins.op_name)
+        if ins.opcode != "while" or not path or path[-1] not in (
+            "photon.matvec", "photon.rmatvec"
+        ):
+            continue
+        # what encloses the pass: the name stack ahead of its own scope
+        ahead = ins.op_name.split("/photon.")[0] + "/"
+        m = _PLACE_RE.search(ahead)
+        place = "top"
+        if m is not None:
+            place = (
+                "iterations" if m.group(1) == "while/body"
+                else m.group(1)[len("cond/"):-len("_fun")]
+            )
+        out.setdefault((place, path[-1]), []).append(ins.name)
+    return out
+
+
+def test_init_program_reads_no_block_forward_from_a_zero_start(
+    monkeypatch, fresh_compiles
+):
+    """``jit_init_f`` (a solve's start, the benchmark runner's first call):
+    outside the start's ``cond`` there is ONE pass, the zero point's
+    backward one; the branch a zero start takes holds none, the other
+    branch the start point's two."""
+    import photon_tpu.ops.gather as gather_mod
+
+    monkeypatch.setattr(gather_mod, "_SEG_BYTES", 1 << 20)
+    seg, batch, w0 = _sparse_segmented(monkeypatch)
+    passes = _passes_by_place(seg._init_f.lower(w0, batch).compile())
+    assert {k: len(v) for k, v in passes.items()} == {
+        ("top", "photon.rmatvec"): 1,
+        ("branch_1", "photon.matvec"): 1,
+        ("branch_1", "photon.rmatvec"): 1,
+    }, passes
+
+
+def test_fe_sweep_program_reads_the_block_once_after_its_loop(
+    monkeypatch, fresh_compiles
+):
+    """``jit_fe_sweep``: ahead of the iteration loop the zero point's
+    backward pass and nothing forward outside the branch a non-zero start
+    takes; in the loop a forward and a backward pass; after it ONE forward
+    pass, the last exact re-evaluation's, whose product the rescoring
+    takes (it made a second one), and that evaluation's backward pass."""
+    import photon_tpu.ops.gather as gather_mod
+    from photon_tpu.game.config import FixedEffectCoordinateConfig
+    from photon_tpu.game.coordinate import FixedEffectCoordinate
+    from photon_tpu.game.data import CSRMatrix, GameData
+    from photon_tpu.optimize.common import OptimizerConfig
+    from photon_tpu.optimize.problem import (
+        GLMProblemConfig,
+        RegularizationContext,
+        RegularizationType,
+    )
+    from photon_tpu.game.config import FeatureRepresentation
+    from photon_tpu.types import TaskType
+
+    monkeypatch.setattr(gather_mod, "_SEG_BYTES", 1 << 20)
+    monkeypatch.setenv("PHOTON_SPARSE_WINDOWS", "1")
+    monkeypatch.setenv("PHOTON_SPARSE_RMATVEC", "prefix")
+    monkeypatch.setenv("PHOTON_SPARSE_GATHER", "chunked")
+    feat = _config("sparse_poisson")["features"]
+    n, d, k = feat["n"], feat["d"], feat["nnz_per_row"]
+    rng = np.random.default_rng(0)
+    data = GameData.build(
+        labels=(rng.uniform(size=n) > 0.5).astype(np.float64),
+        feature_shards={
+            "global": CSRMatrix(
+                indptr=np.arange(n + 1, dtype=np.int64) * k,
+                indices=rng.integers(0, d, size=n * k).astype(np.int32),
+                values=rng.normal(size=n * k),
+                num_cols=d,
+            )
+        },
+        id_tags={},
+    )
+    coord = FixedEffectCoordinate.build(
+        data,
+        FixedEffectCoordinateConfig(
+            feature_shard="global",
+            optimization=GLMProblemConfig(
+                task=TaskType.LOGISTIC_REGRESSION,
+                regularization=RegularizationContext(RegularizationType.L2),
+                optimizer_config=OptimizerConfig(max_iterations=10),
+            ),
+            regularization_weights=(1.0,),
+            representation=FeatureRepresentation.SPARSE,
+        ),
+    )
+    assert coord.batch.windows is not None
+    compiled = coord._sweep_lowered(False).compile()
+    passes = _passes_by_place(compiled)
+    assert {k: len(v) for k, v in passes.items()} == {
+        ("top", "photon.matvec"): 1,
+        ("top", "photon.rmatvec"): 2,
+        ("branch_1", "photon.matvec"): 1,
+        ("branch_1", "photon.rmatvec"): 1,
+        ("iterations", "photon.matvec"): 1,
+        ("iterations", "photon.rmatvec"): 1,
+    }, passes
+    # text order is the schedule's: the one forward pass outside branch and
+    # loop comes after the iteration loop, as does one of the backward two
+    instrs = hlo.parse_instructions(compiled)
+    order = list(instrs)
+    (forward,) = passes[("top", "photon.matvec")]
+    (loop,) = [
+        ins.name
+        for ins in instrs.values()
+        if ins.opcode == "while"
+        and ins.computation == instrs[forward].computation
+        and "photon." not in (ins.op_name or "")
+    ]
+    assert order.index(forward) > order.index(loop)
+    before, after = sorted(order.index(p) for p in passes[("top", "photon.rmatvec")])
+    assert before < order.index(loop) < after
