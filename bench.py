@@ -1001,7 +1001,7 @@ def config_sparse_poisson(peak_flops, scale):
             "instance_len": int(length),
             "window": int(windows.window),
             "padding_waste": round(1.0 - n * k / (w_inst * length), 4),
-            "impl": os.environ.get("PHOTON_SPARSE_RMATVEC", "auto"),
+            "impl": "prefix",
         }
     _log(
         f"[bench] config3 host gen {gen_s:.1f}s window build "

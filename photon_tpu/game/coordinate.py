@@ -53,6 +53,7 @@ from photon_tpu.optimize.common import one_solve_a_lane
 from photon_tpu.optimize.problem import GLMProblem, GLMProblemConfig
 from photon_tpu.types import Array, LabeledBatch, SparseBatch
 from photon_tpu.util import dispatch_count
+from photon_tpu.util.target import donation_enabled
 
 
 def _fetch_global(x) -> np.ndarray:
@@ -117,31 +118,6 @@ def solve_chunk_entities(
     return max(_CHUNK_MULTIPLE, fit // _CHUNK_MULTIPLE * _CHUNK_MULTIPLE)
 
 
-def sweep_donation_enabled() -> bool:
-    """Whether the fused sweep step donates its total/score/state buffers.
-
-    On XLA:CPU (jaxlib 0.4.37) donated fused-sweep buffers intermittently
-    corrupt the allocator heap — reproduced as ``double free or
-    corruption`` / ``corrupted size vs. prev_size`` aborts at teardown
-    and NaN scores mid-run (~1 in 10 runs of tests/test_mf.py + the
-    fused-sweep suite; never without donation). Donation is therefore
-    enabled only off-CPU, where it is the designed steady-state memory
-    win (the [N] temporaries and coefficient blocks at north-star scale).
-    ``PHOTON_SWEEP_DONATION=0/1`` overrides for A/B and triage.
-
-    Called lazily (first sweep step), never at import — reading the
-    default backend initializes it.
-    """
-    import os
-
-    env = os.environ.get("PHOTON_SWEEP_DONATION", "").strip()
-    if env in ("0", "1"):
-        return env == "1"
-    import jax
-
-    return jax.default_backend() != "cpu"
-
-
 def _make_sweep_jits(body, static_argnums, donate_argnums, name):
     """The fused sweep step compiles as a (donating, non-donating) pair;
     ``Coordinate._active_sweep_jit`` picks per backend. One construction
@@ -198,7 +174,7 @@ class Coordinate:
         ``donate`` pins the buffer-donation choice for the whole descent
         run (descent decides ONCE and threads it through, so the copy
         discipline and the actual donation can never diverge mid-run);
-        ``None`` falls back to ``sweep_donation_enabled()``.
+        ``None`` falls back to ``donation_enabled()``.
 
         → ``(new_state, new_score, new_total, info, health)``, where
         ``health`` is the per-coordinate loss/gnorm/isfinite triple of
@@ -234,7 +210,7 @@ class Coordinate:
     @classmethod
     def _active_sweep_jit(cls, donate=None):
         if donate is None:
-            donate = sweep_donation_enabled()
+            donate = donation_enabled()
         return cls._sweep_jit if donate else cls._sweep_jit_nodonate
 
     # -- AOT precompile support (descent.precompile_coordinates) --------
@@ -285,7 +261,7 @@ class Coordinate:
         caller's to schedule."""
         out = []
         if include_sweep:
-            d = bool(donate) if donate is not None else sweep_donation_enabled()
+            d = bool(donate) if donate is not None else donation_enabled()
             out.append((("sweep", d), "sweep", self._sweep_lowered(d)))
         if include_score:
             out.append((("score",), "score", self._score_lowered()))
@@ -459,7 +435,7 @@ class FixedEffectCoordinate(Coordinate):
             # device_put straight from host numpy so no single device ever
             # holds the whole [N, D] block. Column windows shard EXPLICITLY
             # on the instance axis (shard_batch drops them — GSPMD cannot
-            # partition the scan/Pallas variants); the objective then runs
+            # partition the windowed pass's loop); the objective then runs
             # the shard_map reduction in parallel/sparse.py.
             windows = getattr(batch, "windows", None)
             batch = shard_batch(batch, mesh)
@@ -626,7 +602,7 @@ class FixedEffectCoordinate(Coordinate):
         ``_sweep_jit`` (total/score/state DONATED — the [N] temporaries
         and the coefficient block reuse their input buffers every
         steady-state step; the solve's history buffers remain its own) and
-        ``_sweep_jit_nodonate`` (XLA:CPU — see sweep_donation_enabled)."""
+        ``_sweep_jit_nodonate`` (XLA:CPU — see util/target.donation_enabled)."""
         TRACE_COUNTERS["fe_sweep"] += 1
         from photon_tpu.parallel.mesh import constrain_rows
 
@@ -689,7 +665,7 @@ class FixedEffectCoordinate(Coordinate):
             state,
             self._reg_scalar(self.problem.config.regularization_weight),
         )
-        d = bool(donate) if donate is not None else sweep_donation_enabled()
+        d = bool(donate) if donate is not None else donation_enabled()
         out = self._aot_call(("sweep", d), *args)
         if out is not None:
             return out
@@ -1201,7 +1177,7 @@ class RandomEffectCoordinate(Coordinate):
         Compiled as ``_sweep_jit`` (total/score/state DONATED — the [N]
         temporaries and each bucket's coefficient block reuse their input
         buffers) and ``_sweep_jit_nodonate`` (XLA:CPU — see
-        sweep_donation_enabled). The residual's zero-sentinel pad is built
+        donation_enabled). The residual's zero-sentinel pad is built
         once, not per bucket."""
         TRACE_COUNTERS["re_sweep"] += 1
         from photon_tpu.parallel.mesh import constrain_rows
@@ -1349,7 +1325,7 @@ class RandomEffectCoordinate(Coordinate):
                    donate=None):
         dispatch_count.record(1)
         reg_w = self._reg_scalar(self.problem_config.regularization_weight)
-        d = bool(donate) if donate is not None else sweep_donation_enabled()
+        d = bool(donate) if donate is not None else donation_enabled()
         out = self._aot_call(
             ("sweep", d), self._train_args(), self._score_args(), total,
             score, state, reg_w,
@@ -1621,7 +1597,7 @@ class MatrixFactorizationCoordinate(Coordinate):
         L-BFGS over both factor tables plus rescore and total update in one
         program; ``_sweep_jit`` donates ``total``/``score``/``(U, V)``,
         ``_sweep_jit_nodonate`` is the XLA:CPU variant (see
-        sweep_donation_enabled)."""
+        donation_enabled)."""
         TRACE_COUNTERS["mf_sweep"] += 1
         row_idx, col_idx, _, weights, _ = data
         residual = total - score
@@ -1698,7 +1674,7 @@ class MatrixFactorizationCoordinate(Coordinate):
             state,
             self._reg_scalar(self.l2_weight),
         )
-        d = bool(donate) if donate is not None else sweep_donation_enabled()
+        d = bool(donate) if donate is not None else donation_enabled()
         out = self._aot_call(("sweep", d), *args)
         if out is not None:
             return out

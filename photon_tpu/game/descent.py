@@ -28,11 +28,12 @@ from typing import Callable, Mapping, Sequence
 import jax
 
 from photon_tpu import obs
-from photon_tpu.game.coordinate import Coordinate, sweep_donation_enabled
+from photon_tpu.game.coordinate import Coordinate
 from photon_tpu.obs.health import DivergenceError, resolve_policy
 from photon_tpu.util import compile_watch, dispatch_count, faults
 from photon_tpu.util.force import fetch_scalars, force
 from photon_tpu.util.sanitize import sanctioned_transfers, transfer_sanitizer
+from photon_tpu.util.target import donation_enabled
 
 logger = logging.getLogger(__name__)
 
@@ -416,8 +417,8 @@ def run_coordinate_descent(
 
     # donation active ⇒ every structure that must outlive a sweep needs
     # its own buffers (copies below); donation off (XLA:CPU — see
-    # coordinate.sweep_donation_enabled) ⇒ the copies are skipped
-    donating = fused and sweep_donation_enabled()
+    # util/target.donation_enabled) ⇒ the copies are skipped
+    donating = fused and donation_enabled()
     states = {}
     for cid, coord in coordinates.items():
         if initial_states is not None and cid in initial_states:

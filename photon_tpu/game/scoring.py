@@ -14,7 +14,7 @@ replaces both halves:
   host per chunk from the model's memoized vocab index, coefficients
   gathered on device — no per-call dict rebuild, no numpy einsum), the
   matrix-factorization factor dot — plus offsets, with the batch buffers
-  donated (off-CPU; see :func:`score_donation_enabled`). Batches are
+  donated (off-CPU; see ``util/target.donation_enabled``). Batches are
   padded to a SMALL FIXED SET of shapes — a constant row count and
   power-of-two ELL widths, the shape-budget philosophy of ``game/data``
   applied to inference — so steady-state scoring triggers zero retraces
@@ -89,6 +89,7 @@ from photon_tpu.game.model import (
 from photon_tpu.util import compile_watch, faults
 from photon_tpu.util.retry import RetryPolicy, is_transient, retry_call
 from photon_tpu.util.sanitize import sanctioned_transfers, transfer_sanitizer
+from photon_tpu.util.target import donation_enabled
 
 logger = logging.getLogger(__name__)
 
@@ -191,22 +192,6 @@ class UnsupportedModelLayout(ValueError):
     gather limit). Drivers catch exactly this to fall back to the
     monolithic host path — a plain ``ValueError`` (bad batch-rows /
     partition / env knob values) must NOT silently demote the run."""
-
-
-def score_donation_enabled() -> bool:
-    """Whether the fused score program donates its batch buffers.
-
-    Same backend gate (and the same reason) as
-    ``coordinate.sweep_donation_enabled``: on XLA:CPU (jaxlib 0.4.37)
-    donated buffers intermittently corrupt the allocator heap, so
-    donation is on only off-CPU, where reusing the [B, K] feature blocks
-    is the steady-state memory win. ``PHOTON_SCORE_DONATION=0/1``
-    overrides for A/B and triage. Called lazily — reading the default
-    backend initializes it."""
-    env = os.environ.get("PHOTON_SCORE_DONATION", "").strip()
-    if env in ("0", "1"):
-        return env == "1"
-    return jax.default_backend() != "cpu"
 
 
 # ---------------------------------------------------------------------------
@@ -402,7 +387,7 @@ class GameScorer:
             else (dense_cols_max or DEFAULT_DENSE_COLS_MAX)
         )
         self._donate = (
-            bool(donate) if donate is not None else score_donation_enabled()
+            bool(donate) if donate is not None else donation_enabled()
         )
 
         self._fixed: list[_FixedSpec] = []

@@ -74,7 +74,6 @@ from photon_tpu.game.coordinate import (
     FixedEffectCoordinate,
     RandomEffectCoordinate,
     _make_sweep_jits,
-    sweep_donation_enabled,
 )
 from photon_tpu.game.config import (
     FixedEffectCoordinateConfig,
@@ -101,6 +100,7 @@ from photon_tpu.optimize.problem import GLMProblem
 from photon_tpu.types import LabeledBatch
 from photon_tpu.util import dispatch_count, faults
 from photon_tpu.util.sanitize import sanctioned_transfers
+from photon_tpu.util.target import donation_enabled
 
 logger = logging.getLogger(__name__)
 
@@ -574,7 +574,7 @@ class StreamingFixedEffectCoordinate(FixedEffectCoordinate):
             state_dev = jax.device_put(
                 jnp.asarray(np.asarray(state), dtype=self.dtype)
             )
-        d = sweep_donation_enabled()
+        d = donation_enabled()
         # class-attribute access: the UNBOUND jit pair (self rides as the
         # explicit static arg, like the materialized sweep pair)
         exe = (
@@ -635,7 +635,7 @@ class StreamingFixedEffectCoordinate(FixedEffectCoordinate):
     ) -> list:
         out = []
         if include_score:
-            d = bool(donate) if donate is not None else sweep_donation_enabled()
+            d = bool(donate) if donate is not None else donation_enabled()
             feat_dtype = (
                 jnp.bfloat16 if self.config.bf16_features else self.dtype
             )
@@ -851,7 +851,7 @@ class StreamingRandomEffectCoordinate(RandomEffectCoordinate):
     def _chunk_exes(self, donate=None):
         # class-attribute access: the UNBOUND jit pairs (self rides as the
         # explicit static arg, like the materialized sweep pair)
-        d = bool(donate) if donate is not None else sweep_donation_enabled()
+        d = bool(donate) if donate is not None else donation_enabled()
         cls = type(self)
         solve = cls._solve_chunk_jit if d else cls._solve_chunk_jit_nodonate
         score = cls._score_chunk_jit if d else cls._score_chunk_jit_nodonate

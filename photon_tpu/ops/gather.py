@@ -40,13 +40,13 @@ segment from ``_SEG_BYTES``, the table's itemsize and the tiling rule of
 the sliced axis; where it says one segment there is no loop at all (the
 per-entity solves under ``vmap``, the scorer's batches).
 
-Selection: ``PHOTON_SPARSE_GATHER`` = auto (default) | chunked | plain.
-AUTO routes to the row fetch on TPU backends, plain elsewhere (CPU's
-native gather is faster than the 128x traffic blow-up).
+Which gather a program gets is a fact about its platform
+(:func:`fetches_rows`): the row fetch in a program for a TPU, the plain
+``table[idx]`` elsewhere (CPU's native gather is faster than the 128x
+traffic blow-up).
 """
 from __future__ import annotations
 
-import os
 from typing import Callable, NamedTuple, Sequence
 
 import jax
@@ -54,20 +54,19 @@ import jax.numpy as jnp
 
 from photon_tpu.obs.scopes import scope
 from photon_tpu.types import Array
+from photon_tpu.util import target
 
 __all__ = [
     "SegmentPlan",
     "chunked_take",
     "fetch_select",
     "fetch_select_dot",
-    "gather_strategy",
+    "fetches_rows",
     "lane_rows",
     "map_segments",
     "segment_plan",
     "take_1d",
 ]
-
-_ENV = "PHOTON_SPARSE_GATHER"
 
 #: bytes of fetched rows (slots × 128 lanes × itemsize) in one segment, from
 #: the curve on the chip (PERF.md §6, PR 30): at 2²⁶ the v5e's compiler keeps
@@ -145,15 +144,13 @@ def fetch_select_dot(t2: Array, idx: Array, weights: Array) -> Array:
     benchmark's sparse cell, PERF.md §6, PR 30). The barrier holds that
     order: without it the compiler reduces across the lanes first. The K
     products of a row are the same; the order they are added in moves the
-    last bits. A bf16 table's rows are widened before the products."""
+    last bits."""
     k, r = idx.shape
     lane_iota = jax.lax.broadcasted_iota(jnp.int32, (1, 1, 128), 2)
     with scope("photon.gather"):
         with scope("photon.gather.fetch"):
             rows = t2[idx.reshape(-1) >> 7].reshape(k, r, 128)
         with scope("photon.gather.select"):
-            if rows.dtype == jnp.bfloat16:
-                rows = rows.astype(jnp.float32)
             sel = (idx & 127)[..., None] == lane_iota
             by_lane = jnp.sum(
                 jnp.where(sel, rows, 0) * weights[..., None], axis=0
@@ -220,35 +217,16 @@ def chunked_take(table: Array, idx: Array) -> Array:
     return out.reshape(idx.shape)
 
 
-def gather_strategy(table: Array) -> str:
-    """``"chunked"`` or ``"plain"`` for a gather from ``table``.
-
-    The ``PHOTON_SPARSE_GATHER`` knob and the AUTO platform choice are
-    resolved at TRACE time: already-compiled programs keep the strategy
-    they were traced with after an env change (set the env before the
-    first call, or bust the jit cache to re-route). AUTO prefers the
-    platform of the device the TABLE actually lives on (eager calls);
-    under a jit trace the operand carries no committed device, so the
-    default backend — which is what the program will compile for — is
-    the right key."""
-    impl = os.environ.get(_ENV, "auto").strip().lower()
-    if impl == "auto":
-        platform = None
-        try:
-            devices = table.devices()
-            if devices:
-                platform = next(iter(devices)).platform
-        except Exception:
-            platform = None  # tracer or uncommitted: fall back
-        if platform is None:
-            platform = jax.default_backend()
-        impl = "chunked" if platform == "tpu" else "plain"
-    return impl
+def fetches_rows() -> bool:
+    """Whether a 1-D gather in the program being traced is the 128-lane row
+    fetch: in a program for a TPU, whose 1-element gather is serialized."""
+    return target.platform() == "tpu"
 
 
 def take_1d(table: Array, idx: Array) -> Array:
-    """Strategy-dispatched 1-D gather (:func:`gather_strategy`)."""
-    if gather_strategy(table) == "chunked":
+    """``table[idx]``: :func:`chunked_take` where :func:`fetches_rows`, the
+    plain gather elsewhere."""
+    if fetches_rows():
         return chunked_take(table, idx)
     with scope("photon.gather"):
         return table[idx]

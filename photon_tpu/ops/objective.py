@@ -26,7 +26,6 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import os
 from typing import Callable
 
 import jax
@@ -60,13 +59,6 @@ def matvec(batch, v: Array) -> Array:
 
 def _matvec(batch, v: Array) -> Array:
     if isinstance(batch, SparseBatch):
-        # PHOTON_SPARSE_BF16_TABLE=1 stores the gathered coefficient
-        # table bf16: the fetched rows are 128·itemsize B per useful
-        # element, so halving the table halves them; products accumulate
-        # in f32. Opt-in (trace-time binding, like the gather strategy
-        # knob); a bf16 table is a different result.
-        if os.environ.get("PHOTON_SPARSE_BF16_TABLE", "0") == "1":
-            v = v.astype(jnp.bfloat16)
         return _ell_matvec(v, batch.indices, batch.values)
     x = batch.features
     if x.dtype == jnp.bfloat16:
@@ -93,7 +85,7 @@ def _ell_matvec(table: Array, indices: Array, values: Array) -> Array:
     never leave fast memory."""
     from photon_tpu.ops import gather
 
-    if indices.ndim == 2 and gather.gather_strategy(table) == "chunked":
+    if indices.ndim == 2 and gather.fetches_rows():
         plan = gather.segment_plan(
             *indices.shape, table.dtype.itemsize, 128
         )
@@ -103,36 +95,27 @@ def _ell_matvec(table: Array, indices: Array, values: Array) -> Array:
             return gather.map_segments(
                 rows_block, (indices.T, values.T), plan, axis=1
             )
-    tv = gather.take_1d(table, indices)
-    if table.dtype == jnp.bfloat16:  # products still accumulate in f32
-        tv = tv.astype(jnp.float32)
-    return jnp.sum(tv * values, axis=-1)
+    return jnp.sum(gather.take_1d(table, indices) * values, axis=-1)
 
 
 def _use_windows(batch, per_row: Array) -> bool:
     """Single routing decision for every windowed reduction (gradient AND
-    variance paths): a column-window layout is present, the reduction is a
-    plain 1-D row weighting, and ``PHOTON_SPARSE_RMATVEC=segment`` has not
-    forced the flat scatter path for A/B measurement."""
-    impl = os.environ.get("PHOTON_SPARSE_RMATVEC", "auto").strip().lower()
-    return (
-        getattr(batch, "windows", None) is not None
-        and per_row.ndim == 1
-        and impl != "segment"
-    )
+    variance paths): a column-window layout is present and the reduction is
+    a plain 1-D row weighting."""
+    return getattr(batch, "windows", None) is not None and per_row.ndim == 1
 
 
 def _windowed_rmatvec_dispatch(windows, per_row: Array, dim: int, mesh):
     """One routing decision for every windowed Xᵀ· reduction (gradient AND
     variance paths): instance-sharded shard_map under a mesh, the
-    single-chip kernel otherwise."""
+    single-chip pass otherwise."""
     if mesh is not None:
         from photon_tpu.parallel.sparse import sharded_windowed_rmatvec
 
         return sharded_windowed_rmatvec(windows, per_row, dim, mesh)
-    from photon_tpu.ops.sparse_windows import windowed_rmatvec
+    from photon_tpu.ops.sparse_windows import rmatvec_windows_prefix
 
-    return windowed_rmatvec(windows, per_row, dim)
+    return rmatvec_windows_prefix(windows, per_row, dim)
 
 
 def rmatvec(batch, per_row: Array, dim: int, mesh=None) -> Array:
@@ -142,11 +125,10 @@ def rmatvec(batch, per_row: Array, dim: int, mesh=None) -> Array:
     Sparse ELL: flat scatter-add over the N·K (index, value·r) pairs. Under
     pjit with rows sharded, each shard scatters into its own [dim] partial
     and XLA inserts the psum — same collective the dense Xᵀr gets. When the
-    batch carries a column-window layout (single-chip high-dim shards), the
+    batch carries a column-window layout (built on a TPU at high dim), the
     scatter is rerouted through ops/sparse_windows — XLA:TPU's serialized
     scatter lowering is minutes/eval at 10⁶-segment scale; the windowed
-    one-hot MXU kernel is milliseconds. ``PHOTON_SPARSE_RMATVEC=segment``
-    forces the plain path for A/B measurement.
+    prefix-sum pass is dense work.
     """
     with scope("photon.rmatvec"):
         return _rmatvec(batch, per_row, dim, mesh)

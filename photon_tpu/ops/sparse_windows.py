@@ -11,7 +11,7 @@ accumulates into a per-executor dense array in JVM memory,
 ValueAndGradientAggregator.scala:133-152; the TPU equivalent of that
 "local dense accumulate" is exactly this module.)
 
-The fix is a build-time layout + an MXU trick:
+The fix is a build-time layout that turns the scatter into dense work:
 
 - **Build** (host, once — indices are static across every objective
   evaluation of a solve): sort the (row, col, val) triples by column and
@@ -19,46 +19,35 @@ The fix is a build-time layout + an MXU trick:
   window to a common length L. Windows whose load exceeds L **spill** into
   multiple instances mapped to the same output range — essential under
   real feature skew (an intercept column alone holds N entries).
-- **Scatter → one-hot matmul**: within an instance, Xᵀr restricted to its
-  w columns is ``contribᵀ · onehot(local_cols)`` — a [1,L]×[L,w] matmul.
-  The Pallas kernel generates the one-hot **in VMEM** (never in HBM) and
-  feeds the MXU, so HBM traffic is just the (row, lcol, val) stream. A
-  pure-XLA ``lax.scan`` fallback computes the identical algebra for
-  CPU/debug, and a flat pre-sorted ``segment_sum`` variant exists for
-  comparison (padding uses local col w−1 so flat indices stay sorted).
+- **Scatter → prefix sums**: within an instance the local columns are
+  non-decreasing (column sort), so per-column sums are differences of the
+  contribution cumsum at build-time-static boundaries (``bounds``) — a
+  fully dense path with no scatter and no custom kernel
+  (:func:`rmatvec_windows_prefix`, the one rmatvec over a layout).
 - **The gather side is the floor**: contrib = vals · r[rows] is a
   1-element gather from a [N] vector, which XLA:TPU serializes; it goes
   through ops/gather's row fetch and lane select and is 75 % of a
   backward pass (PERF.md §5). ``_over_instances`` runs it in the segment
   loop of the pass: a segment is a block of whole instances whose fetched
   rows fit fast memory (``gather.segment_plan``: 32 instances of 4096
-  slots), the loop's body carries the variant's consumer (the prefix
-  variant's centring, cumsum and bounds reads, [I, L] → [I, w]), and the
-  build pads the instance count to whole segments, so [W_inst, L] is
-  read as it lies and no pass pads, slices or stacks a value per slot.
+  slots), the loop's body carries the consumer (the centring, cumsum and
+  bounds reads, [I, L] → [I, w]), and the build pads the instance count
+  to whole segments, so [W_inst, L] is read as it lies and no pass pads,
+  slices or stacks a value per slot.
 
 Instance partials combine with one [W_inst, w] → [W, w] sorted
 segment-sum (thousands of rows, not millions — off the cliff).
 
-Sharded batches (parallel/mesh.shard_batch) intentionally drop the
-windows: under plain GSPMD row-sharding the scan/Pallas variants do not
-partition, and the per-shard scatter is back on the segment_sum path.
-The multi-chip windowed path lives in ``parallel/sparse.py`` instead —
+Without a layout (the CPU, where ``maybe_build_windows`` builds none, and
+row-sharded GSPMD batches: parallel/mesh.shard_batch drops the windows,
+whose loop does not partition) Xᵀr is the ``segment_sum`` of
+``ops/objective._rmatvec``, which is also the reference the tests compare
+with. The multi-chip windowed path lives in ``parallel/sparse.py`` —
 window instances sharded explicitly over the mesh with ``shard_map``
-(column-range partials + one psum), reusing this module's kernels
-per shard.
-
-- **Prefix-sum variant**: within an instance the local columns are
-  non-decreasing (column sort), so per-column sums are differences of the
-  contribution cumsum at build-time-static boundaries (``bounds``) — a
-  fully dense gather-only path with no scatter and no custom kernel.
-
-Selection: ``PHOTON_SPARSE_RMATVEC`` = auto (default) | prefix | pallas |
-onehot | flat | segment. AUTO → prefix on TPU, onehot elsewhere.
+(column-range partials + one psum), running this module's pass per shard.
 """
 from __future__ import annotations
 
-import os
 from typing import NamedTuple
 
 import jax
@@ -68,8 +57,7 @@ import numpy as np
 from photon_tpu import obs
 from photon_tpu.obs.scopes import scope
 from photon_tpu.types import Array
-
-_ENV = "PHOTON_SPARSE_RMATVEC"
+from photon_tpu.util import target
 
 #: the TPU sublane rule: the second-to-last dim of a block is a multiple of 8
 _SUBLANES = 8
@@ -81,14 +69,14 @@ class ColumnWindows(NamedTuple):
     rows/lcols/vals: [W_inst, L]; ``inst2win``: [W_inst] window id per
     instance (non-decreasing); ``iota``: [w] = arange(window) — carried as
     an array so the window width rides a static *shape* through jit (an int
-    leaf would be traced away) and doubles as the one-hot compare operand.
+    leaf would be traced away).
     ``bounds``: [W_inst, w+1] exclusive prefix counts per local column
     (bounds[i, c] = #slots in instance i with lcol < c) — static segment
-    boundaries for the prefix-sum rmatvec; ``None`` on layouts built before
-    the field existed. Padding slots: row 0, local col w−1, value 0.
-    W_inst is padded at build time (inert instances) to a multiple of 8, so
-    the Pallas block shape (8, L) satisfies the TPU sublane rule, and of
-    the instances per segment of the backward pass (``instance_multiple``).
+    boundaries for the prefix-sum rmatvec. Padding slots: row 0, local col
+    w−1, value 0. W_inst is padded at build time (inert instances) to a
+    multiple of 8 (the TPU sublane rule for a block of whole instances) and
+    of the instances per segment of the backward pass
+    (``instance_multiple``).
     """
 
     rows: Array
@@ -96,7 +84,7 @@ class ColumnWindows(NamedTuple):
     vals: Array
     inst2win: Array
     iota: Array
-    bounds: Array | None = None
+    bounds: Array
 
     @property
     def window(self) -> int:
@@ -124,12 +112,6 @@ def _native_histogram(arr_idx, arr_val, num_features):
     (native/window_builder.cpp) — O(nnz + d) vs numpy's comparison argsort.
     Returns (col_counts, nnz) or None when the fast path does not apply
     (non-f32 values, library unavailable)."""
-    if os.environ.get("PHOTON_NATIVE_WINDOWS", "1").strip().lower() in (
-        "0",
-        "off",
-        "never",
-    ):
-        return None
     if arr_val.dtype != np.float32 or arr_idx.size == 0:
         return None
     from photon_tpu.data.native_index import _load_native_lib
@@ -202,8 +184,8 @@ def build_column_windows(
 
     ``instance_cap`` bounds L so one hot column (intercept!) spills across
     instances instead of inflating every window's padding. L is rounded up
-    to a multiple of ``chunk`` (the kernel's VMEM one-hot chunk) or to 8
-    for small layouts. ``host=True`` keeps the result as numpy — for mesh
+    to a multiple of ``chunk`` (1024: whole 8 × 128 tiles) or to 8 for
+    small layouts. ``host=True`` keeps the result as numpy — for mesh
     placement, where materializing the whole stream on one device first
     would be the exact single-device footprint the sharding avoids.
     """
@@ -243,9 +225,9 @@ def _build_column_windows(
             counts = np.bincount(flat_col // window, minlength=num_windows)
 
     # Round the spill cap itself to the instance length so FULL spill
-    # instances carry zero padding — mid-stream padding (local col w−1
-    # between two instances of the same window) would break the sorted
-    # invariant rmatvec_windows_flat promises to XLA.
+    # instances carry zero padding: a window's stream stays sorted by
+    # column across its instances, with padding (local col w−1) only at
+    # the end of its last one.
     cap = int(min(counts.max() if nnz else 1, instance_cap))
     if cap > chunk:
         cap = -(-cap // chunk) * chunk
@@ -255,10 +237,10 @@ def _build_column_windows(
     n_inst = np.maximum(1, -(-counts // cap))
     w_inst = int(n_inst.sum())
     # Round the instance count with inert instances (vals 0 / lcol w−1 /
-    # last window id) to a multiple of 8, so the Pallas kernel's (8, L)
-    # block shape meets the TPU sublane-divisibility rule for any layout,
-    # and of the backward pass's instances per segment, so that its loop
-    # runs over whole segments and no pass pads or slices the streams.
+    # last window id) to a multiple of 8 (a block of instances meets the
+    # TPU sublane-divisibility rule for any layout) and of the backward
+    # pass's instances per segment, so that its loop runs over whole
+    # segments and no pass pads or slices the streams.
     w_inst_pad = (-w_inst) % instance_multiple(
         w_inst, length, arr_val.dtype.itemsize
     )
@@ -335,7 +317,7 @@ def _instance_bounds(lcols2: np.ndarray, window: int) -> np.ndarray:
 
 
 # ---------------------------------------------------------------------------
-# rmatvec implementations (identical algebra, different lowering)
+# the rmatvec over a layout
 # ---------------------------------------------------------------------------
 
 
@@ -361,21 +343,21 @@ def _over_instances(
     value 0, contributing nothing) — and ``blocks`` the same instances of
     each [W_inst, ·] array in ``streams``.
 
-    This gather, not the scatter, is the floor of every windowed rmatvec
-    variant, and what it costs is where its fetched rows land (ops/gather's
-    module docstring). So where the layout's fetched rows pass one segment
+    This gather, not the scatter, is the floor of the windowed rmatvec,
+    and what it costs is where its fetched rows land (ops/gather's module
+    docstring). So where the layout's fetched rows pass one segment
     the backward pass runs the segment loop here: a segment is a block of
     whole instances (``segment_plan``; the build pads the instance count to
     a multiple of it), its body fetches ``r[rows]``, selects, multiplies by
     ``vals`` and hands the [instances, L] block to ``consumer``, and the
     loop stacks what the consumer returns. One segment, or the plain
-    gather: ``consumer`` gets the whole layout at once, as before."""
+    gather: ``consumer`` gets the whole layout at once."""
     from photon_tpu.ops import gather
 
     plan = gather.segment_plan(
         *windows.rows.shape, per_row.dtype.itemsize, _SUBLANES
     )
-    if plan.steps == 1 or gather.gather_strategy(per_row) != "chunked":
+    if plan.steps == 1 or not gather.fetches_rows():
         contrib = windows.vals * gather.take_1d(per_row, windows.rows)
         return consumer(contrib, *streams)
     t2 = gather.lane_rows(per_row)
@@ -388,49 +370,6 @@ def _over_instances(
     )
 
 
-def _contrib(windows: ColumnWindows, per_row: Array) -> Array:
-    """[W_inst, L] contributions, one per slot, for the variants that
-    consume them whole (flat, pallas)."""
-    return _over_instances(windows, per_row, lambda contrib: contrib)
-
-
-def rmatvec_windows_flat(
-    windows: ColumnWindows, per_row: Array, dim: int
-) -> Array:
-    """Pre-sorted flat segment_sum: padding local col w−1 keeps global
-    indices non-decreasing, so XLA sees ``indices_are_sorted``."""
-    w = windows.window
-    gcols = (windows.lcols + windows.inst2win[:, None] * w).reshape(-1)
-    num_windows = max(1, -(-dim // w))
-    out = jax.ops.segment_sum(
-        _contrib(windows, per_row).reshape(-1),
-        gcols,
-        num_segments=num_windows * w,
-        indices_are_sorted=True,
-    )
-    return out[:dim]
-
-
-def rmatvec_windows_onehot(
-    windows: ColumnWindows, per_row: Array, dim: int
-) -> Array:
-    """Pure-XLA one-hot matmul, scanned one instance at a time (the scan
-    keeps the [L, w] one-hot a fused per-step intermediate instead of a
-    materialized [W_inst, L, w] monster)."""
-    iota = windows.iota
-
-    def body(_, xs):
-        rows, lcols, vals = xs
-        cb = vals * per_row[rows]
-        onehot = (lcols[:, None] == iota[None, :]).astype(cb.dtype)
-        return None, cb @ onehot
-
-    _, out_inst = jax.lax.scan(
-        body, None, (windows.rows, windows.lcols, windows.vals)
-    )
-    return _combine(out_inst, windows, dim)
-
-
 def rmatvec_windows_prefix(
     windows: ColumnWindows, per_row: Array, dim: int
 ) -> Array:
@@ -441,8 +380,6 @@ def rmatvec_windows_prefix(
     lowering-proof TPU path. The algebra (``_prefix_partials``) runs per
     block of instances inside the gather's segment loop, so what is stacked
     is [W_inst, w], not the [W_inst, L] contributions."""
-    if windows.bounds is None:
-        return rmatvec_windows_onehot(windows, per_row, dim)
     out_inst = _over_instances(
         windows, per_row, _prefix_partials, windows.bounds
     )
@@ -470,130 +407,10 @@ def _prefix_partials(contrib: Array, bounds: Array) -> Array:
         return g[:, 1:] - g[:, :-1] + mu * counts
 
 
-#: instances per Pallas grid step — the TPU sublane rule requires the
-#: second-to-last block dim be a multiple of 8 (block (1, L) fails to lower)
-_PALLAS_BLK = _SUBLANES
-
-
-def _pallas_kernel_factory(length: int, w: int, chunk: int):
-    from jax.experimental import pallas as pl
-
-    steps = max(1, length // chunk)
-
-    def kernel(contrib_ref, lcols_ref, out_ref):
-        for i in range(_PALLAS_BLK):
-
-            def body(j, acc, i=i):  # bind: fori_loop runs within this i
-                cb = contrib_ref[i, pl.ds(j * chunk, chunk)].astype(
-                    jnp.float32
-                )
-                lc = lcols_ref[i, pl.ds(j * chunk, chunk)]
-                onehot = (
-                    lc[:, None]
-                    == jax.lax.broadcasted_iota(jnp.int32, (chunk, w), 1)
-                ).astype(jnp.float32)
-                return acc + jnp.dot(
-                    cb[None, :], onehot, preferred_element_type=jnp.float32
-                )
-
-            acc = jax.lax.fori_loop(
-                0, steps, body, jnp.zeros((1, w), jnp.float32)
-            )
-            out_ref[i, :] = acc[0]
-
-    return kernel
-
-
-def rmatvec_windows_pallas(
-    windows: ColumnWindows,
-    per_row: Array,
-    dim: int,
-    *,
-    interpret: bool = False,
-) -> Array:
-    """Pallas kernel: one grid step per instance; the one-hot lives only in
-    VMEM and the multiply-accumulate runs on the MXU. HBM traffic is the
-    (lcol, contrib) stream — the layout's point."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    w_inst, length = windows.rows.shape
-    w = windows.window
-    # The (blk=8, L) block residency is 8× the old (1, L) blocks: two
-    # [8, L] 4-byte operands must fit VMEM alongside the [chunk, w] one-hot.
-    # Past ~2^17 slots/instance (≈8 MB of operands) a real-TPU launch would
-    # die in Mosaic with a VMEM error; fail loudly instead of silently
-    # measuring a different implementation (interpret mode has no VMEM
-    # limit and proceeds).
-    if length * _PALLAS_BLK > (1 << 20) and not interpret:
-        raise ValueError(
-            f"pallas rmatvec: instance length {length} × {_PALLAS_BLK} "
-            "sublanes exceeds the VMEM block budget; lower "
-            "PHOTON_SPARSE_WINDOW_CAP or select "
-            "PHOTON_SPARSE_RMATVEC=prefix"
-        )
-    # chunk must DIVIDE the instance length or the fori_loop drops the tail
-    # (build rounds length to a multiple of its chunk arg, which need not be
-    # this kernel's 1024 default) — pick the largest aligned divisor.
-    chunk = length
-    if length > 1024:
-        for c in (1024, 512, 256, 128, 64, 32, 16, 8):
-            if length % c == 0:
-                chunk = c
-                break
-        else:
-            # No aligned divisor (custom build chunk not a multiple of 8).
-            # chunk=length would put a (length, w) one-hot in VMEM — fine
-            # for modest lengths, a Mosaic VMEM blowup for big ones. Say
-            # so: a caller who selected this kernel must not silently
-            # measure another implementation.
-            if length > 4096:
-                raise ValueError(
-                    f"pallas rmatvec: instance length {length} has no "
-                    "divisor that is a multiple of 8; build the layout "
-                    "with a chunk that is, or select "
-                    "PHOTON_SPARSE_RMATVEC=prefix"
-                )
-    # f32 accumulation: the MXU path is TPU-only, where x64 is unsupported
-    contrib = _contrib(windows, per_row).astype(jnp.float32)
-    lcols = windows.lcols
-    blk = _PALLAS_BLK
-    pad = (-w_inst) % blk
-    if pad:  # layouts from before the build-time 8-padding
-        contrib = jnp.pad(contrib, ((0, pad), (0, 0)))
-        lcols = jnp.pad(lcols, ((0, pad), (0, 0)), constant_values=w - 1)
-
-    out_inst = pl.pallas_call(
-        _pallas_kernel_factory(length, w, chunk),
-        out_shape=jax.ShapeDtypeStruct((w_inst + pad, w), jnp.float32),
-        grid=((w_inst + pad) // blk,),
-        in_specs=[
-            pl.BlockSpec(
-                (blk, length), lambda i: (i, 0), memory_space=pltpu.VMEM
-            ),
-            pl.BlockSpec(
-                (blk, length), lambda i: (i, 0), memory_space=pltpu.VMEM
-            ),
-        ],
-        out_specs=pl.BlockSpec(
-            (blk, w), lambda i: (i, 0), memory_space=pltpu.VMEM
-        ),
-        interpret=interpret,
-    )(contrib, lcols)
-    return _combine(out_inst[:w_inst], windows, dim)
-
-
-def _env_int(name: str, default: int, *, lo: int, hi: int) -> int:
-    raw = os.environ.get(name, "").strip()
-    if not raw:
-        return default
-    try:
-        v = int(raw)
-    except ValueError as e:
-        raise ValueError(f"{name}={raw!r} is not an integer") from e
-    if not lo <= v <= hi:
-        raise ValueError(f"{name}={v} outside [{lo}, {hi}]")
-    return v
+def windows_pay(num_features: int) -> bool:
+    """The layout is worth its host-side sort and ~1.5× extra device memory
+    only where the scatter cliff exists, a TPU, and at high dim."""
+    return target.platform() == "tpu" and num_features >= 1024
 
 
 def maybe_build_windows(
@@ -603,60 +420,16 @@ def maybe_build_windows(
     *,
     host: bool = False,
 ):
-    """Policy gate for the layout build: windows are worth their host-side
-    sort + ~1.5× extra device memory only on TPU (where the scatter cliff
-    exists) at high dim. ``PHOTON_SPARSE_WINDOWS`` = auto (default) | 1 | 0.
-    Pass ``host=True`` when the result will be mesh-sharded
+    """The layout where :func:`windows_pay`, else ``None``. Pass
+    ``host=True`` when the result will be mesh-sharded
     (parallel/sparse.shard_windows) so the stream never lands whole on one
     device."""
-    flag = os.environ.get("PHOTON_SPARSE_WINDOWS", "auto").strip().lower()
-    if flag in ("0", "off", "never"):
-        return None
     if jax.process_count() > 1:
         # multi-controller placement of the instance-sharded layout needs a
         # make_array_from_callback path (parallel/sparse.shard_windows uses
         # single-controller device_put); until that exists the sharded ELL
         # segment_sum path is the multi-host story
         return None
-    if flag in ("1", "on", "always") or (
-        jax.default_backend() == "tpu" and num_features >= 1024
-    ):
-        # tuning knobs (kernel-shape tradeoff: wider windows → fewer grid
-        # steps but more one-hot compares; see PERF.md). Deliberately NOT
-        # named PHOTON_SPARSE_WINDOW: one dropped character from the on/off
-        # flag PHOTON_SPARSE_WINDOWS must not silently become a width of 1.
-        window = _env_int("PHOTON_SPARSE_WINDOW_WIDTH", 128, lo=8, hi=8192)
-        cap = _env_int("PHOTON_SPARSE_WINDOW_CAP", 4096, lo=64, hi=1 << 20)
-        return build_column_windows(
-            indices,
-            values,
-            num_features,
-            window=window,
-            instance_cap=cap,
-            host=host,
-        )
-    return None
-
-
-def windowed_rmatvec(
-    windows: ColumnWindows, per_row: Array, dim: int
-) -> Array:
-    """Implementation dispatch (trace-time; see module docstring)."""
-    impl = os.environ.get(_ENV, "auto").strip().lower()
-    if impl == "auto":
-        if jax.default_backend() == "tpu":
-            # r4 on-chip measurement (PERF.md): prefix-sum beats the
-            # one-hot kernels and every segment_sum lowering at config-3
-            # scale; layouts without bounds fall back inside prefix.
-            impl = "prefix"
-        else:
-            impl = "onehot"
-    if impl == "prefix":
-        return rmatvec_windows_prefix(windows, per_row, dim)
-    if impl == "pallas":
-        return rmatvec_windows_pallas(windows, per_row, dim)
-    if impl == "onehot":
-        return rmatvec_windows_onehot(windows, per_row, dim)
-    if impl == "flat":
-        return rmatvec_windows_flat(windows, per_row, dim)
-    raise ValueError(f"unknown {_ENV}={impl!r}")
+    if not windows_pay(num_features):
+        return None
+    return build_column_windows(indices, values, num_features, host=host)

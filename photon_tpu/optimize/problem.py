@@ -15,7 +15,6 @@ from __future__ import annotations
 
 import dataclasses
 import enum
-import os
 from typing import Callable
 
 import jax
@@ -210,8 +209,8 @@ class GLMProblem:
         result's ``product`` is ``objective.product(result.x, batch)`` as
         the solve's last exact evaluation made it, so the score needs no
         pass of its own. ``None`` where the optimizer ends on no such
-        evaluation (OWL-QN, TRON, box constraints, the black-box line
-        search): the caller then makes the product itself."""
+        evaluation (OWL-QN, TRON, box constraints): the caller then makes
+        the product itself."""
         return self._spanned_solve(
             batch,
             w0,
@@ -251,13 +250,7 @@ class GLMProblem:
             RegularizationType.L1,
             RegularizationType.ELASTIC_NET,
         )
-        full_ls = (
-            os.environ.get("PHOTON_GLM_LINESEARCH", "margin").strip().lower()
-            == "full"
-        )
         if has_l1 or opt == OptimizerType.OWLQN:
-            if full_ls:
-                return minimize_owlqn(vg, w0, objective.l1_weight, cfg)
             # value-only backtracking trials (1 feature pass each) with the
             # accepted gradient from carried margins
             return minimize_owlqn(
@@ -290,13 +283,10 @@ class GLMProblem:
                 hvp_factory=lambda w: objective.hessian_operator(w, batch),
             )
         # LBFGS and LBFGSB (box bounds live in the OptimizerConfig). The
-        # margin-space line search is the default — trials cost O(N)
-        # elementwise instead of two feature passes (biggest win inside the
-        # vmapped per-entity solves, where one straggler lane's trials used
-        # to cost every lane a feature pass). PHOTON_GLM_LINESEARCH=full
-        # forces the black-box search for A/B.
-        if full_ls:
-            return minimize_lbfgs(vg, w0, cfg)
+        # line search is in margin space — trials cost O(N) elementwise
+        # instead of two feature passes (biggest win inside the vmapped
+        # per-entity solves, where one straggler lane's trials would cost
+        # every lane a feature pass).
         return minimize_lbfgs(
             None,
             w0,

@@ -1,11 +1,11 @@
-"""Multi-chip windowed sparse Xᵀr: instance-sharded one-hot reduction.
+"""Multi-chip windowed sparse Xᵀr: instance-sharded prefix-sum reduction.
 
 Completes the column-window story (ops/sparse_windows.py) for the mesh
-case. Under plain GSPMD the windowed variants do not partition: the scan
-carries sequential semantics and a Pallas grid is opaque to the SPMD
-partitioner, so ``parallel/mesh.shard_batch`` intentionally drops windows
-and the sharded ELL path falls back to per-shard segment_sum — correct,
-but back on XLA:TPU's serialized-scatter lowering, now per chip.
+case. Under plain GSPMD the windowed pass does not partition (its segment
+loop carries sequential semantics), so ``parallel/mesh.shard_batch``
+intentionally drops windows and the sharded ELL path falls back to
+per-shard segment_sum — correct, but back on XLA:TPU's
+serialized-scatter lowering, now per chip.
 
 This module shards the layout EXPLICITLY instead, with ``shard_map``:
 
@@ -16,15 +16,15 @@ This module shards the layout EXPLICITLY instead, with ``shard_map``:
 - the residual vector ``per_row`` is passed replicated — it is O(N) small
   (4 MB at n=2²⁰) next to the O(N·K) pair stream, the classic
   replicate-the-vector SpMV distribution;
-- each device runs the SAME single-chip kernel (Pallas on TPU, scan
-  elsewhere) over its instances into a full [dim] partial that is zero
+- each device runs the SAME single-chip pass (``rmatvec_windows_prefix``)
+  over its instances into a full [dim] partial that is zero
   outside its column ranges, and one ``psum`` over the mesh axes adds the
   disjoint partials — the reference's treeAggregate for the sparse
   gradient (ValueAndGradientAggregator.scala:244-247), ridden over ICI.
 
 Padding instances added for shard divisibility carry value 0 / local col
-w−1 / window id W−1, preserving both the algebra and the sorted-order
-invariant of the flat variant.
+w−1 / window id W−1 (and bounds that count every slot at w−1), preserving
+both the algebra and the sorted order of ``inst2win``.
 """
 from __future__ import annotations
 
@@ -36,7 +36,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from photon_tpu.ops.sparse_windows import (
     ColumnWindows,
     instance_multiple,
-    windowed_rmatvec,
+    rmatvec_windows_prefix,
 )
 from photon_tpu.types import Array
 
@@ -65,13 +65,10 @@ def pad_windows_for_mesh(
         widths = [(0, pad)] + [(0, 0)] * (x.ndim - 1)
         return np.pad(np.asarray(x), widths, constant_values=fill)
 
-    bounds = windows.bounds
-    if bounds is not None:
-        # an all-padding instance has every slot at lcol w−1: exclusive
-        # prefix counts are 0 for c ≤ w−1 and `length` at c = w
-        pad_rows = np.zeros((pad, w + 1), dtype=np.int32)
-        pad_rows[:, -1] = length
-        bounds = np.concatenate([np.asarray(bounds), pad_rows])
+    # an all-padding instance has every slot at lcol w−1: exclusive
+    # prefix counts are 0 for c ≤ w−1 and `length` at c = w
+    pad_bounds = np.zeros((pad, w + 1), dtype=np.int32)
+    pad_bounds[:, -1] = length
 
     return ColumnWindows(
         rows=pad_leaf(windows.rows, 0),
@@ -79,7 +76,7 @@ def pad_windows_for_mesh(
         vals=pad_leaf(windows.vals, 0),
         inst2win=pad_leaf(windows.inst2win, num_windows - 1),
         iota=windows.iota,
-        bounds=bounds,
+        bounds=np.concatenate([np.asarray(windows.bounds), pad_bounds]),
     )
 
 
@@ -114,11 +111,7 @@ def shard_windows(
         vals=put(windows.vals, inst_mat),
         inst2win=put(windows.inst2win, inst_sharded),
         iota=put(windows.iota, NamedSharding(mesh, P())),
-        bounds=(
-            None
-            if windows.bounds is None
-            else put(windows.bounds, inst_mat)
-        ),
+        bounds=put(windows.bounds, inst_mat),
     )
 
 
@@ -126,11 +119,11 @@ def sharded_windowed_rmatvec(
     windows: ColumnWindows, per_row: Array, dim: int, mesh: Mesh
 ) -> Array:
     """Xᵀ·per_row over instance-sharded windows: per-shard single-chip
-    kernel + one psum of disjoint column-range partials."""
+    pass + one psum of disjoint column-range partials."""
     axes = tuple(mesh.axis_names)
 
     def local(wins: ColumnWindows, r: Array) -> Array:
-        partial = windowed_rmatvec(wins, r, dim)
+        partial = rmatvec_windows_prefix(wins, r, dim)
         return jax.lax.psum(partial, axes)
 
     return shard_map(
@@ -143,9 +136,7 @@ def sharded_windowed_rmatvec(
                 vals=P(axes, None),
                 inst2win=P(axes),
                 iota=P(),
-                bounds=(
-                    None if windows.bounds is None else P(axes, None)
-                ),
+                bounds=P(axes, None),
             ),
             P(),  # replicated residual vector
         ),

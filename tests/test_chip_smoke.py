@@ -16,8 +16,13 @@ import chip_smoke
 @pytest.fixture()
 def tiny(monkeypatch, tmp_path):
     """A deployment of 2048 rows / 64 users / 16 items on disk, with the
-    TPU-only layout branch forced (``jax.default_backend()`` is cpu)."""
-    monkeypatch.setenv("PHOTON_SPARSE_WINDOWS", "1")
+    chip's window layout: ``training_phase`` builds and RUNS in one call, on
+    the CPU, so the layout's policy predicate is patched and no
+    ``compiling_for`` is entered (it would turn donation on too)."""
+    monkeypatch.setattr(
+        "photon_tpu.ops.sparse_windows.windows_pay",
+        lambda num_features: num_features >= 1024,
+    )
     monkeypatch.setattr(chip_smoke, "SERVE_REQUESTS", 3)
     monkeypatch.setattr(chip_smoke, "SERVE_ROWS_PER_REQ", 8)
     monkeypatch.setattr(chip_smoke, "SCORE_BATCH_ROWS", 64)

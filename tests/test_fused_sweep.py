@@ -165,13 +165,13 @@ def test_fused_descent_matches_unfused_bit_exact():
 
 
 def test_fused_sweep_donation_mode_and_no_warnings():
-    """Where donation is active (off-CPU; see sweep_donation_enabled —
+    """Where donation is active (off-CPU; see util/target.donation_enabled —
     XLA:CPU donation corrupts the heap in jaxlib 0.4.37) it must be REAL
     (inputs consumed — the steady state reuses buffers instead of
     allocating) and CLEAN (no 'donated buffer was not usable'
     copy-fallback warnings). Where it is gated off, inputs must survive
     untouched."""
-    from photon_tpu.game.coordinate import sweep_donation_enabled
+    from photon_tpu.util.target import donation_enabled
 
     coords = _build_coordinates()
     fe = coords["fixed"]
@@ -187,7 +187,7 @@ def test_fused_sweep_donation_mode_and_no_warnings():
     bad = [str(w.message) for w in rec if "donat" in str(w.message).lower()]
     assert bad == [], f"donation fell back to copies: {bad}"
     inputs = (("total", total), ("score", score), ("state", state))
-    if sweep_donation_enabled():
+    if donation_enabled():
         for name, donated in inputs:
             assert donated.is_deleted(), f"{name} buffer was not consumed"
     else:
@@ -209,7 +209,7 @@ def test_caller_snapshots_survive_donation(monkeypatch):
     executes for real, with no actual CPU donation."""
     import photon_tpu.game.descent as descent_mod
 
-    monkeypatch.setattr(descent_mod, "sweep_donation_enabled", lambda: True)
+    monkeypatch.setattr(descent_mod, "donation_enabled", lambda: True)
     for cls in (FixedEffectCoordinate, RandomEffectCoordinate):
         monkeypatch.setattr(cls, "_sweep_jit", cls._sweep_jit_nodonate)
     coords = _build_coordinates()
@@ -249,7 +249,7 @@ def test_sweep_callback_snapshots_are_donation_stable(monkeypatch):
     CPU runners where donation is disabled."""
     import photon_tpu.game.descent as descent_mod
 
-    monkeypatch.setattr(descent_mod, "sweep_donation_enabled", lambda: True)
+    monkeypatch.setattr(descent_mod, "donation_enabled", lambda: True)
     for cls in (FixedEffectCoordinate, RandomEffectCoordinate):
         monkeypatch.setattr(cls, "_sweep_jit", cls._sweep_jit_nodonate)
     coords = _build_coordinates()

@@ -28,6 +28,7 @@ from photon_tpu.ops.sparse_windows import (
     rmatvec_windows_prefix,
 )
 from photon_tpu.types import SparseBatch
+from photon_tpu.util import target
 
 
 @pytest.mark.parametrize(
@@ -234,20 +235,21 @@ def test_segmented_take_nonfinite_isolation(small_segments):
         assert np.isinf(got).sum() == 5 and np.isnan(got).sum() == 5
 
 
-def test_take_1d_env_dispatch(monkeypatch):
+def test_take_1d_platform_dispatch():
     rng = np.random.default_rng(2)
     t = jnp.asarray(rng.standard_normal(500).astype(np.float32))
     ix = jnp.asarray(rng.integers(0, 500, size=(99,)).astype(np.int32))
     outs = {}
-    for impl in ("plain", "chunked", "auto"):
-        monkeypatch.setenv("PHOTON_SPARSE_GATHER", impl)
-        outs[impl] = np.asarray(take_1d(t, ix))
-    assert np.array_equal(outs["plain"], outs["chunked"])
-    assert np.array_equal(outs["plain"], outs["auto"])
+    for platform in ("cpu", "tpu"):
+        with target.compiling_for(platform):
+            outs[platform] = np.asarray(take_1d(t, ix))
+    assert np.array_equal(outs["cpu"], outs["tpu"])
+    assert np.array_equal(outs["cpu"], np.asarray(chunked_take(t, ix)))
 
 
-def test_production_routes_match_plain(monkeypatch):
-    """ELL matvec and windowed prefix rmatvec: chunked == plain exactly."""
+def test_production_routes_match_plain():
+    """ELL matvec and windowed prefix rmatvec: the row fetch == the plain
+    gather exactly."""
     rng = np.random.default_rng(3)
     n, d, k = 256, 2048, 12
     idx = rng.integers(0, d, size=(n, k)).astype(np.int32)
@@ -265,11 +267,11 @@ def test_production_routes_match_plain(monkeypatch):
     r = jnp.asarray(rng.standard_normal(n).astype(np.float32))
 
     results = {}
-    for impl in ("plain", "chunked"):
-        monkeypatch.setenv("PHOTON_SPARSE_GATHER", impl)
-        results[impl] = (
-            np.asarray(matvec(batch, v)),
-            np.asarray(rmatvec_windows_prefix(w, r, d)),
-        )
-    assert np.array_equal(results["plain"][0], results["chunked"][0])
-    assert np.array_equal(results["plain"][1], results["chunked"][1])
+    for platform in ("cpu", "tpu"):
+        with target.compiling_for(platform):
+            results[platform] = (
+                np.asarray(matvec(batch, v)),
+                np.asarray(rmatvec_windows_prefix(w, r, d)),
+            )
+    assert np.array_equal(results["cpu"][0], results["tpu"][0])
+    assert np.array_equal(results["cpu"][1], results["tpu"][1])

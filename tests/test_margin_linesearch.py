@@ -222,13 +222,12 @@ def test_owlqn_oracle_with_box_constraints():
     assert float(res.value) == pytest.approx(float(ref.value), rel=1e-4)
 
 
-def test_oracle_sparse_batch_with_windows(monkeypatch):
+def test_oracle_sparse_batch_with_windows():
     """Sparse FE solve: oracle margins via ELL gather, accepted gradient
     via the windowed backward."""
     from photon_tpu.ops.sparse_windows import build_column_windows
     from photon_tpu.types import SparseBatch
 
-    monkeypatch.setenv("PHOTON_SPARSE_RMATVEC", "onehot")
     rng = np.random.default_rng(3)
     n, k, d = 300, 5, 256
     idx = rng.integers(1, d, size=(n, k)).astype(np.int32)

@@ -178,12 +178,14 @@ def _sparse_case(seed, n, k, d):
 
 @pytest.fixture
 def row_fetch_in_small_segments(monkeypatch):
-    """The TPU's gather on the CPU, a segment = 64 KiB of fetched rows."""
+    """The TPU's gather on the CPU (one sparse pass, no donated program), a
+    segment = 64 KiB of fetched rows."""
     import photon_tpu.ops.gather as gather_mod
+    from photon_tpu.util import target
 
-    monkeypatch.setenv("PHOTON_SPARSE_GATHER", "chunked")
     monkeypatch.setattr(gather_mod, "_SEG_BYTES", 1 << 16)
-    return gather_mod
+    with target.compiling_for("tpu"):
+        yield gather_mod
 
 
 @pytest.mark.parametrize(
@@ -244,15 +246,15 @@ def test_segmented_ell_matvec_nonfinite_entry_reaches_only_its_rows(
     np.testing.assert_allclose(got[~bad], clean, rtol=2e-6, atol=2e-6)
 
 
-def test_one_segment_matvec_under_vmap_lowers_as_before(monkeypatch):
+def test_one_segment_matvec_under_vmap_lowers_as_before():
     """Per-entity solves call matvec under vmap on blocks of one segment:
     their program is the loop-free one of before the segment loop (the
     row fetch and lane select over the whole block), operation for
     operation."""
     from photon_tpu.ops.objective import matvec
     from photon_tpu.types import SparseBatch
+    from photon_tpu.util import target
 
-    monkeypatch.setenv("PHOTON_SPARSE_GATHER", "chunked")
     e, n, k, d = 6, 40, 5, 300
 
     def before(v, idx, val):  # ops/gather.chunked_take as PR 29 had it
@@ -279,9 +281,10 @@ def test_one_segment_matvec_under_vmap_lowers_as_before(monkeypatch):
         jax.ShapeDtypeStruct((e, n, k), jnp.int32),
         jax.ShapeDtypeStruct((e, n, k), jnp.float32),
     )
-    texts = [
-        jax.jit(jax.vmap(f)).lower(*args).as_text().replace(name, "f")
-        for f, name in ((before, "before"), (now, "now"))
-    ]
+    with target.compiling_for("tpu"):
+        texts = [
+            jax.jit(jax.vmap(f)).lower(*args).as_text().replace(name, "f")
+            for f, name in ((before, "before"), (now, "now"))
+        ]
     assert "while" not in texts[1]
     assert texts[0] == texts[1]

@@ -4,9 +4,9 @@ instruction that reads the data sits under one, and a scope changes nothing
 but metadata.
 
 The programs are compiled here for the CPU at the cells' ``rehearse``
-shapes, with the TPU-only branches steered by the knobs the program already
-has (``PHOTON_SPARSE_WINDOWS``, ``PHOTON_SPARSE_RMATVEC``,
-``PHOTON_SPARSE_GATHER``). The persistent compile cache is off around them:
+shapes, traced under ``target.compiling_for("tpu")`` (the ``for_tpu``
+fixture) so that they take the TPU's branches, and never run. The
+persistent compile cache is off around them:
 metadata is not part of its key, so a program served from it would carry
 the scopes of whichever tree wrote the entry.
 """
@@ -24,6 +24,7 @@ import jax.numpy as jnp
 from photon_tpu.analysis import hlo
 from photon_tpu.obs import scopes
 from photon_tpu.obs.scopes import SCOPES
+from photon_tpu.util import target
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 PACKAGE = os.path.join(ROOT, "photon_tpu")
@@ -111,33 +112,38 @@ def fresh_compiles():
     compilation_cache.reset_cache()
 
 
+@pytest.fixture()
+def for_tpu():
+    """Trace as for a TPU: window layout, prefix rmatvec, row fetch. The
+    tests that take it compile and inspect; none runs a program."""
+    with target.compiling_for("tpu"):
+        yield
+
+
 def _config(name: str) -> dict:
     with open(os.path.join(ROOT, "benchmarks", "configs", f"{name}.json")) as f:
         config = json.load(f)
     return {**config, **config["rehearse"]}
 
 
-def _sparse_segmented(monkeypatch):
-    """``sparse_poisson``'s ``SegmentedOWLQN`` over its batch, window layout
-    forced on, as the TPU takes it: prefix rmatvec, chunked gather."""
+def _sparse_segmented():
+    """``sparse_poisson``'s ``SegmentedOWLQN`` over its batch, as the TPU
+    takes it (``for_tpu``): window layout, prefix rmatvec, row fetch."""
     from photon_tpu.ops.losses import loss_for_task
     from photon_tpu.ops.objective import GLMObjective
-    from photon_tpu.ops.sparse_windows import maybe_build_windows
+    from photon_tpu.ops.sparse_windows import build_column_windows
     from photon_tpu.optimize.common import OptimizerConfig
     from photon_tpu.optimize.owlqn import SegmentedOWLQN
     from photon_tpu.types import SparseBatch, TaskType
 
-    monkeypatch.setenv("PHOTON_SPARSE_WINDOWS", "1")
-    monkeypatch.setenv("PHOTON_SPARSE_RMATVEC", "prefix")
-    monkeypatch.setenv("PHOTON_SPARSE_GATHER", "chunked")
     config = _config("sparse_poisson")
     feat, solver = config["features"], config["solver"]
     n, d, k = feat["n"], feat["d"], feat["nnz_per_row"]
     rng = np.random.default_rng(0)
     idx = rng.integers(0, d, size=(n, k), dtype=np.int32)
     vals = rng.normal(size=(n, k)).astype(np.float32)
-    windows = maybe_build_windows(idx, vals, d)
-    assert windows is not None and windows.bounds is not None
+    # built outright: the rehearsal's d is under the policy's 1024
+    windows = build_column_windows(idx, vals, d)
     batch = SparseBatch(
         indices=jnp.asarray(idx),
         values=jnp.asarray(vals),
@@ -165,9 +171,9 @@ def _sparse_segmented(monkeypatch):
     return seg, batch, jax.ShapeDtypeStruct((d,), jnp.float32)
 
 
-def _sparse_segment_program(monkeypatch):
+def _sparse_segment_program():
     """Its segment program, and the shapes of the data it reads."""
-    seg, batch, w0 = _sparse_segmented(monkeypatch)
+    seg, batch, w0 = _sparse_segmented()
     windows = batch.windows
     state = jax.eval_shape(seg._init_f, w0, batch)
     data_shapes = {
@@ -177,7 +183,7 @@ def _sparse_segment_program(monkeypatch):
     return seg._segment_f.lower(state, batch).compile(), data_shapes
 
 
-def _dense_tron_program(monkeypatch):
+def _dense_tron_program():
     """``linear_tron``'s whole TRON solve under one jit, as the benchmark's
     runner builds it."""
     from photon_tpu.optimize.common import OptimizerConfig
@@ -243,9 +249,9 @@ def _scoped_modules():
 
 
 @pytest.mark.parametrize("cell", sorted(PROGRAMS))
-def test_cell_program_carries_its_scopes(cell, monkeypatch, fresh_compiles):
+def test_cell_program_carries_its_scopes(cell, for_tpu, fresh_compiles):
     build, expected = PROGRAMS[cell]
-    compiled, data_shapes = build(monkeypatch)
+    compiled, data_shapes = build()
     scope_of = hlo.instruction_scopes(compiled)
     assert expected <= set(scope_of.values()), (
         f"missing: {sorted(expected - set(scope_of.values()))}"
@@ -278,7 +284,9 @@ def test_cell_program_carries_its_scopes(cell, monkeypatch, fresh_compiles):
     assert by_scope[hlo.UNSCOPED] == len(seconds) - len(scope_of)
 
 
-def test_segment_loop_names_the_gathers_two_halves(monkeypatch, fresh_compiles):
+def test_segment_loop_names_the_gathers_two_halves(
+    monkeypatch, for_tpu, fresh_compiles
+):
     """The segment program with its passes cut into several segments (the
     rehearsal shapes at a 2^20 B segment): under ``photon.gather`` a scope
     join finds ``photon.gather.fetch`` and ``photon.gather.select`` in both
@@ -290,7 +298,7 @@ def test_segment_loop_names_the_gathers_two_halves(monkeypatch, fresh_compiles):
     assert gather_mod.segment_plan(
         config["n"], config["nnz_per_row"], 4, 128
     ).steps >= 3
-    compiled, _ = _sparse_segment_program(monkeypatch)
+    compiled, _ = _sparse_segment_program()
     assert " while(" in compiled.as_text()
     paths = set(hlo.instruction_scope_paths(compiled).values())
     under = {p for p in paths if "photon.gather" in p}
@@ -308,14 +316,16 @@ def test_segment_loop_names_the_gathers_two_halves(monkeypatch, fresh_compiles):
 
 
 @pytest.mark.parametrize("cell", sorted(PROGRAMS))
-def test_scopes_change_nothing_but_metadata(cell, monkeypatch, fresh_compiles):
+def test_scopes_change_nothing_but_metadata(
+    cell, monkeypatch, for_tpu, fresh_compiles
+):
     build, _ = PROGRAMS[cell]
-    scoped = build(monkeypatch)[0].as_text()
+    scoped = build()[0].as_text()
     for module in _scoped_modules():
         monkeypatch.setattr(
             module, "scope", lambda name: contextlib.nullcontext()
         )
-    bare = build(monkeypatch)[0].as_text()
+    bare = build()[0].as_text()
     assert "photon." in scoped and "photon." not in bare
     assert hlo.strip_metadata(scoped) == hlo.strip_metadata(bare)
     assert "metadata=" not in hlo.strip_metadata(scoped)
@@ -411,7 +421,7 @@ def _passes_by_place(compiled) -> dict[tuple[str, str], list[str]]:
 
 
 def test_init_program_reads_no_block_forward_from_a_zero_start(
-    monkeypatch, fresh_compiles
+    monkeypatch, for_tpu, fresh_compiles
 ):
     """``jit_init_f`` (a solve's start, the benchmark runner's first call):
     outside the start's ``cond`` there is ONE pass, the zero point's
@@ -420,7 +430,7 @@ def test_init_program_reads_no_block_forward_from_a_zero_start(
     import photon_tpu.ops.gather as gather_mod
 
     monkeypatch.setattr(gather_mod, "_SEG_BYTES", 1 << 20)
-    seg, batch, w0 = _sparse_segmented(monkeypatch)
+    seg, batch, w0 = _sparse_segmented()
     passes = _passes_by_place(seg._init_f.lower(w0, batch).compile())
     assert {k: len(v) for k, v in passes.items()} == {
         ("top", "photon.rmatvec"): 1,
@@ -430,7 +440,7 @@ def test_init_program_reads_no_block_forward_from_a_zero_start(
 
 
 def test_fe_sweep_program_reads_the_block_once_after_its_loop(
-    monkeypatch, fresh_compiles
+    monkeypatch, for_tpu, fresh_compiles
 ):
     """``jit_fe_sweep``: ahead of the iteration loop the zero point's
     backward pass and nothing forward outside the branch a non-zero start
@@ -451,9 +461,10 @@ def test_fe_sweep_program_reads_the_block_once_after_its_loop(
     from photon_tpu.types import TaskType
 
     monkeypatch.setattr(gather_mod, "_SEG_BYTES", 1 << 20)
-    monkeypatch.setenv("PHOTON_SPARSE_WINDOWS", "1")
-    monkeypatch.setenv("PHOTON_SPARSE_RMATVEC", "prefix")
-    monkeypatch.setenv("PHOTON_SPARSE_GATHER", "chunked")
+    # the rehearsal's d is under the policy's 1024
+    monkeypatch.setattr(
+        "photon_tpu.ops.sparse_windows.windows_pay", lambda num_features: True
+    )
     feat = _config("sparse_poisson")["features"]
     n, d, k = feat["n"], feat["d"], feat["nnz_per_row"]
     rng = np.random.default_rng(0)

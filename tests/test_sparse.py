@@ -262,34 +262,3 @@ def test_fixed_effect_coordinate_sparse_matches_dense():
         rtol=1e-7,
         atol=1e-9,
     )
-
-
-def test_bf16_table_gather_knob_matches_f32_within_tolerance(monkeypatch):
-    """PHOTON_SPARSE_BF16_TABLE=1 gathers the coefficient table in
-    bfloat16 (halves the dominant row-fetch stream on TPU); the margin
-    must match the f32 path within bf16 rounding of the coefficients."""
-    import jax.numpy as jnp
-    import numpy as np
-
-    from photon_tpu.ops.objective import matvec
-    from photon_tpu.types import SparseBatch
-
-    rng = np.random.default_rng(9)
-    n, d, k = 512, 4096, 12
-    batch = SparseBatch(
-        indices=jnp.asarray(rng.integers(0, d, size=(n, k)), jnp.int32),
-        values=jnp.asarray(rng.normal(size=(n, k)), jnp.float32),
-        labels=jnp.zeros((n,), jnp.float32),
-        offsets=jnp.zeros((n,), jnp.float32),
-        weights=jnp.ones((n,), jnp.float32),
-        windows=None,
-    )
-    w = jnp.asarray(rng.normal(size=d), jnp.float32)
-    monkeypatch.delenv("PHOTON_SPARSE_BF16_TABLE", raising=False)
-    z32 = np.asarray(matvec(batch, w))
-    monkeypatch.setenv("PHOTON_SPARSE_BF16_TABLE", "1")
-    z16 = np.asarray(matvec(batch, w))
-    # bf16 has 8 mantissa bits: per-coefficient relative error <= 2^-8,
-    # summed over k terms of O(1) products
-    assert np.max(np.abs(z16 - z32)) < k * np.max(np.abs(z32)) * 2**-7
-    assert not np.array_equal(z16, z32)  # the knob actually routed bf16

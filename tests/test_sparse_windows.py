@@ -1,5 +1,5 @@
-"""Column-windowed sparse rmatvec: layout build + all lowerings agree with
-the flat segment_sum reference (ops/sparse_windows.py).
+"""Column-windowed sparse rmatvec: layout build + the prefix-sum pass agree
+with the flat scatter-add reference (ops/sparse_windows.py).
 
 The windowed layout exists to reroute the high-dim backward scatter around
 XLA:TPU's serialized scatter lowering; numerics must be identical (up to
@@ -17,11 +17,9 @@ from photon_tpu.ops.sparse_windows import (
     ColumnWindows,
     build_column_windows,
     maybe_build_windows,
-    rmatvec_windows_flat,
-    rmatvec_windows_onehot,
-    rmatvec_windows_pallas,
     rmatvec_windows_prefix,
 )
+from photon_tpu.util import target
 
 
 def _reference_rmatvec(idx, val, r, d):
@@ -54,22 +52,13 @@ def test_all_impls_match_reference(hot_column, d):
     )
     expect = _reference_rmatvec(idx, val, r, d)
 
-    r_j = jnp.asarray(r)
-    got_flat = np.asarray(rmatvec_windows_flat(windows, r_j, d))
-    got_onehot = np.asarray(rmatvec_windows_onehot(windows, r_j, d))
-    got_pallas = np.asarray(
-        rmatvec_windows_pallas(windows, r_j, d, interpret=True)
-    )
-    got_prefix = np.asarray(rmatvec_windows_prefix(windows, r_j, d))
-    np.testing.assert_allclose(got_flat, expect, rtol=2e-4, atol=1e-4)
-    np.testing.assert_allclose(got_onehot, expect, rtol=2e-4, atol=1e-4)
-    np.testing.assert_allclose(got_pallas, expect, rtol=2e-4, atol=1e-4)
-    np.testing.assert_allclose(got_prefix, expect, rtol=2e-4, atol=1e-4)
+    got = np.asarray(rmatvec_windows_prefix(windows, jnp.asarray(r), d))
+    np.testing.assert_allclose(got, expect, rtol=2e-4, atol=1e-4)
 
 
 def test_build_pads_instances_to_multiple_of_8():
-    """The Pallas (8, L) block shape requires W_inst % 8 == 0; inert
-    padding instances must not change the algebra."""
+    """A block of whole instances meets the TPU sublane rule only where
+    W_inst % 8 == 0; inert padding instances must not change the algebra."""
     rng = np.random.default_rng(3)
     idx, val = _random_ell(rng, 100, 3, 40)
     windows = build_column_windows(idx, val, 40, window=16, instance_cap=64)
@@ -122,58 +111,10 @@ def test_prefix_drift_bounded_on_biased_contributions():
     np.testing.assert_allclose(got, expect, rtol=5e-5, atol=1e-3)
 
 
-def test_prefix_falls_back_without_bounds():
-    """Layouts predating the bounds field route prefix → onehot."""
-    rng = np.random.default_rng(5)
-    idx, val = _random_ell(rng, 64, 3, 32)
-    windows = build_column_windows(idx, val, 32, window=16)
-    legacy = windows._replace(bounds=None)
-    r = jnp.asarray(rng.standard_normal(64).astype(np.float32))
-    np.testing.assert_allclose(
-        np.asarray(rmatvec_windows_prefix(legacy, r, 32)),
-        _reference_rmatvec(idx, val, np.asarray(r), 32),
-        rtol=2e-4,
-        atol=1e-4,
-    )
-
-
-def test_pallas_chunk_divides_nondefault_length():
-    """Regression: an instance length from a non-default build chunk (e.g.
-    1536 = 3·512) must not drop tail slots in the kernel's fori_loop."""
-    rng = np.random.default_rng(9)
-    n, k, d = 3000, 2, 8  # one window, load ~6000 → spill at cap 1536
-    idx = np.zeros((n, k), dtype=np.int32)
-    val = np.ones((n, k), dtype=np.float32)
-    windows = build_column_windows(
-        idx, val, d, window=8, instance_cap=1536, chunk=512
-    )
-    assert windows.rows.shape[1] == 1536
-    r = jnp.ones((n,), jnp.float32)
-    got = np.asarray(
-        rmatvec_windows_pallas(windows, r, d, interpret=True)
-    )
-    assert got[0] == pytest.approx(n * k)
-
-
-def test_pallas_refuses_undivisible_long_instance():
-    """An instance length over 4096 with no 8-aligned divisor used to
-    return the one-hot scan's result under the kernel's name."""
-    n, k, d = 5001, 1, 8
-    windows = build_column_windows(
-        np.zeros((n, k), dtype=np.int32), np.ones((n, k), dtype=np.float32),
-        d, window=8, instance_cap=5001, chunk=4099,
-    )
-    assert windows.rows.shape[1] == 2 * 4099
-    with pytest.raises(ValueError, match="no divisor"):
-        rmatvec_windows_pallas(
-            windows, jnp.ones((n,), jnp.float32), d, interpret=True
-        )
-
-
 def test_flat_sorted_invariant_with_misaligned_cap():
     """Regression: a spill cap that is not a multiple of the length rounding
-    must not leave mid-stream padding that breaks the non-decreasing global
-    column order rmatvec_windows_flat promises XLA."""
+    must not leave mid-stream padding: a window's stream stays in
+    non-decreasing global column order across its instances."""
     rng = np.random.default_rng(11)
     n, k, d = 500, 3, 64
     idx, val = _random_ell(rng, n, k, d, hot_column=True, zero_slots=False)
@@ -184,7 +125,7 @@ def test_flat_sorted_invariant_with_misaligned_cap():
     gcols = np.asarray(windows.lcols) + np.asarray(windows.inst2win)[:, None] * w
     assert np.all(np.diff(gcols.reshape(-1)) >= 0), "flat order not sorted"
     r = jnp.asarray(rng.standard_normal(n).astype(np.float32))
-    got = np.asarray(rmatvec_windows_flat(windows, r, d))
+    got = np.asarray(rmatvec_windows_prefix(windows, r, d))
     np.testing.assert_allclose(
         got, _reference_rmatvec(idx, val, np.asarray(r), d),
         rtol=2e-4, atol=1e-4,
@@ -214,7 +155,9 @@ def test_native_builder_matches_numpy(monkeypatch):
     n, k, d = 700, 6, 500
     idx, val = _random_ell(rng, n, k, d, hot_column=True)
     w_native = build_column_windows(idx, val, d, window=64, instance_cap=256)
-    monkeypatch.setenv("PHOTON_NATIVE_WINDOWS", "0")
+    monkeypatch.setattr(
+        "photon_tpu.data.native_index._load_native_lib", lambda: None
+    )
     w_numpy = build_column_windows(idx, val, d, window=64, instance_cap=256)
     for a, b in zip(w_native, w_numpy):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
@@ -249,12 +192,12 @@ def test_explicit_zero_slots_dropped():
     windows = build_column_windows(idx, val, 16, window=16)
     assert float(jnp.sum((windows.vals != 0).astype(jnp.int32))) == 64.0
     r = jnp.ones((64,), jnp.float32)
-    got = np.asarray(rmatvec_windows_flat(windows, r, 16))
+    got = np.asarray(rmatvec_windows_prefix(windows, r, 16))
     expect = np.bincount(idx[:, 0], minlength=16).astype(np.float32)
     np.testing.assert_allclose(got, expect)
 
 
-def test_objective_gradient_with_windows_matches_plain(monkeypatch):
+def test_objective_gradient_with_windows_matches_plain():
     """GLMObjective routed through the windowed path reproduces the plain
     ELL segment_sum gradient bit-for-bit-ish."""
     from photon_tpu.ops.losses import LogisticLoss
@@ -280,17 +223,14 @@ def test_objective_gradient_with_windows_matches_plain(monkeypatch):
     obj = GLMObjective(loss=LogisticLoss, l2_weight=0.5)
     v0, g0 = obj.value_and_gradient(jnp.asarray(w), batch(None))
     windows = build_column_windows(idx, val, d, window=32)
-    for impl in ("onehot", "prefix"):  # prefix = the TPU AUTO default
-        monkeypatch.setenv("PHOTON_SPARSE_RMATVEC", impl)
-        v1, g1 = obj.value_and_gradient(jnp.asarray(w), batch(windows))
-        assert float(v0) == pytest.approx(float(v1), rel=1e-6), impl
-        np.testing.assert_allclose(
-            np.asarray(g0), np.asarray(g1), rtol=1e-5, atol=1e-6,
-            err_msg=impl,
-        )
+    v1, g1 = obj.value_and_gradient(jnp.asarray(w), batch(windows))
+    assert float(v0) == pytest.approx(float(v1), rel=1e-6)
+    np.testing.assert_allclose(
+        np.asarray(g0), np.asarray(g1), rtol=1e-5, atol=1e-6
+    )
 
 
-def test_hessian_diagonal_with_windows_matches_plain(monkeypatch):
+def test_hessian_diagonal_with_windows_matches_plain():
     """Variance path: windowed Σ d2·x² (incl. the shift binomial expansion)
     must match the plain segment_sum lowering."""
     from photon_tpu.ops.losses import LogisticLoss
@@ -326,16 +266,14 @@ def test_hessian_diagonal_with_windows_matches_plain(monkeypatch):
     obj = GLMObjective(loss=LogisticLoss, l2_weight=0.3, normalization=norm)
     d0 = obj.hessian_diagonal(jnp.asarray(w), batch(None))
     windows = build_column_windows(idx, val, d, window=32)
-    for impl in ("onehot", "prefix"):  # prefix: worst case for cumsum
-        monkeypatch.setenv("PHOTON_SPARSE_RMATVEC", impl)
-        d1 = obj.hessian_diagonal(jnp.asarray(w), batch(windows))
-        np.testing.assert_allclose(
-            np.asarray(d0), np.asarray(d1), rtol=1e-4, atol=1e-5,
-            err_msg=impl,
-        )
+    # all-positive d2: the worst case for a difference of cumsums
+    d1 = obj.hessian_diagonal(jnp.asarray(w), batch(windows))
+    np.testing.assert_allclose(
+        np.asarray(d0), np.asarray(d1), rtol=1e-4, atol=1e-5
+    )
 
 
-def test_bf16_sparse_values_end_to_end(monkeypatch):
+def test_bf16_sparse_values_end_to_end():
     """bf16-stored sparse values (config.bf16_features on a sparse shard)
     train close to the f32 path; windows preserve the bf16 storage."""
     from photon_tpu.game.config import (
@@ -361,8 +299,6 @@ def test_bf16_sparse_values_end_to_end(monkeypatch):
     )
     labels = (rng.uniform(size=n) > 0.5).astype(np.float64)
     data = GameData.build(labels=labels, feature_shards={"g": shard})
-    monkeypatch.setenv("PHOTON_SPARSE_WINDOWS", "1")
-    monkeypatch.setenv("PHOTON_SPARSE_RMATVEC", "onehot")
 
     def train(bf16):
         cfg = FixedEffectCoordinateConfig(
@@ -377,7 +313,9 @@ def test_bf16_sparse_values_end_to_end(monkeypatch):
             ),
             regularization_weights=(1.0,),
         )
-        coord = FixedEffectCoordinate.build(data, cfg)
+        # the chip's layout, built here; the fit below runs outside the block
+        with target.compiling_for("tpu"):
+            coord = FixedEffectCoordinate.build(data, cfg)
         if bf16:
             assert coord.batch.values.dtype == jnp.bfloat16
             assert coord.batch.windows is not None
@@ -391,23 +329,23 @@ def test_bf16_sparse_values_end_to_end(monkeypatch):
     assert np.linalg.norm(w16 - w32) / max(np.linalg.norm(w32), 1e-9) < 0.05
 
 
-def test_maybe_build_windows_policy(monkeypatch):
+def test_maybe_build_windows_policy():
     rng = np.random.default_rng(3)
     idx, val = _random_ell(rng, 32, 4, 4096)
-    # CPU backend + auto → no windows
-    monkeypatch.setenv("PHOTON_SPARSE_WINDOWS", "auto")
+    # a program for the CPU → no windows
     assert maybe_build_windows(idx, val, 4096) is None or (
         jax.default_backend() == "tpu"
     )
-    # forced on → built regardless of backend
-    monkeypatch.setenv("PHOTON_SPARSE_WINDOWS", "1")
-    w = maybe_build_windows(idx, val, 4096)
-    assert isinstance(w, ColumnWindows)
-    # host=True keeps leaves in numpy (for mesh placement)
-    wh = maybe_build_windows(idx, val, 4096, host=True)
-    assert isinstance(wh.rows, np.ndarray)
-    monkeypatch.setenv("PHOTON_SPARSE_WINDOWS", "0")
-    assert maybe_build_windows(idx, val, 4096) is None
+    with target.compiling_for("tpu"):
+        w = maybe_build_windows(idx, val, 4096)
+        assert isinstance(w, ColumnWindows)
+        # the defaults of build_column_windows, and nothing else
+        assert w.window == 128 and w.instance_len <= 4096
+        # host=True keeps leaves in numpy (for mesh placement)
+        wh = maybe_build_windows(idx, val, 4096, host=True)
+        assert isinstance(wh.rows, np.ndarray)
+        with target.compiling_for("cpu"):
+            assert maybe_build_windows(idx, val, 4096) is None
 
 
 def test_sharded_windowed_rmatvec_matches_reference():
@@ -444,8 +382,10 @@ def test_sharded_windowed_rmatvec_matches_reference():
 
 def test_mesh_estimator_sparse_windows_parity(monkeypatch):
     """Full production path: GameEstimator with a mesh + high-dim sparse FE
-    and forced windows (instance-sharded shard_map backward) must train the
-    same coefficients as the single-device run without windows."""
+    and the chip's windows (instance-sharded shard_map backward) must train
+    the same coefficients as the single-device run without windows. Build
+    and fit are one call, and the fit RUNS here, so the test patches the
+    layout's one policy predicate and enters no ``compiling_for``."""
     from photon_tpu.game.config import FixedEffectCoordinateConfig
     from photon_tpu.game.data import CSRMatrix, GameData
     from photon_tpu.game.estimator import GameEstimator
@@ -471,8 +411,11 @@ def test_mesh_estimator_sparse_windows_parity(monkeypatch):
     labels = (rng.uniform(size=n) > 0.5).astype(np.float64)
     data = GameData.build(labels=labels, feature_shards={"g": shard})
 
-    def fit(mesh, env):
-        monkeypatch.setenv("PHOTON_SPARSE_WINDOWS", env)
+    def fit(mesh, windows):
+        monkeypatch.setattr(
+            "photon_tpu.ops.sparse_windows.windows_pay",
+            lambda num_features: windows and num_features >= 1024,
+        )
         est = GameEstimator(
             task=TaskType.LOGISTIC_REGRESSION,
             coordinate_configs={
@@ -503,9 +446,9 @@ def test_mesh_estimator_sparse_windows_parity(monkeypatch):
             for r in results
         ]
 
-    w_plain = fit(None, "0")
+    w_plain = fit(None, False)
     mesh = make_mesh(num_data=len(jax.devices()) // 2, num_entity=2)
-    w_mesh = fit(mesh, "1")
+    w_mesh = fit(mesh, True)
     assert len(w_plain) == len(w_mesh) == 2
     for wp, wm in zip(w_plain, w_mesh):
         np.testing.assert_allclose(wm, wp, rtol=5e-4, atol=5e-5)
@@ -523,7 +466,7 @@ def test_windows_survive_jit_closure():
     @jax.jit
     def f(windows, r):
         calls["n"] += 1
-        return rmatvec_windows_onehot(windows, r, 128)
+        return rmatvec_windows_prefix(windows, r, 128)
 
     r1 = jnp.asarray(rng.standard_normal(64).astype(np.float32))
     r2 = jnp.asarray(rng.standard_normal(64).astype(np.float32))
@@ -537,16 +480,16 @@ def test_windows_survive_jit_closure():
 
 @pytest.fixture
 def row_fetch(monkeypatch):
-    """The TPU's gather on the CPU; returns a setter for the segment size."""
+    """The TPU's gather on the CPU (the tests below run one sparse pass, no
+    donated program); yields a setter for the segment size."""
     import photon_tpu.ops.gather as gather_mod
-
-    monkeypatch.setenv("PHOTON_SPARSE_GATHER", "chunked")
 
     def set_segment(slots):
         monkeypatch.setattr(gather_mod, "_SEG_BYTES", slots * 512)
         return gather_mod
 
-    return set_segment
+    with target.compiling_for("tpu"):
+        yield set_segment
 
 
 def _layout(seed, n=1100, k=5, d=300, **build):
@@ -564,9 +507,10 @@ def _layout(seed, n=1100, k=5, d=300, **build):
 def test_segmented_contrib_bit_identical(row_fetch, per, expect_tail):
     """A layout built for one segment size, run at another: three or more
     segments, an instance count that is not a multiple of the instances
-    per segment, and every slot's vals . r[rows] bit-equal to the plain
+    per segment, and every slot's vals . r[rows] (what the loop hands its
+    consumer; here the consumer hands it back) bit-equal to the plain
     lookup's."""
-    from photon_tpu.ops.sparse_windows import _contrib
+    from photon_tpu.ops.sparse_windows import _over_instances
 
     idx, val, r, windows = _layout(11)
     w_inst, length = windows.rows.shape
@@ -574,28 +518,21 @@ def test_segmented_contrib_bit_identical(row_fetch, per, expect_tail):
     plan = gather_mod.segment_plan(w_inst, length, 4, 8)
     assert plan.per == per and plan.steps >= 3
     assert bool(plan.tail) == expect_tail, (w_inst, plan)
-    got = np.asarray(jax.jit(_contrib)(windows, jnp.asarray(r)))
+    contrib = jax.jit(lambda w, r_: _over_instances(w, r_, lambda c: c))
+    got = np.asarray(contrib(windows, jnp.asarray(r)))
     expect = np.asarray(windows.vals) * r[np.asarray(windows.rows)]
     assert np.array_equal(got, expect)
 
 
 @pytest.mark.parametrize("per", [8, 16, 24])
-@pytest.mark.parametrize("impl", ["prefix", "flat", "pallas"])
-def test_segmented_rmatvec_matches_reference(row_fetch, impl, per):
+def test_segmented_rmatvec_matches_reference(row_fetch, per):
     idx, val, r, windows = _layout(12)
     d = 300
     w_inst, length = windows.rows.shape
     assert row_fetch(per * length).segment_plan(
         w_inst, length, 4, 8
     ).steps >= 3
-    fn = {
-        "prefix": rmatvec_windows_prefix,
-        "flat": rmatvec_windows_flat,
-        "pallas": lambda w, r_, d_: rmatvec_windows_pallas(
-            w, r_, d_, interpret=True
-        ),
-    }[impl]
-    got = np.asarray(fn(windows, jnp.asarray(r), d))
+    got = np.asarray(rmatvec_windows_prefix(windows, jnp.asarray(r), d))
     np.testing.assert_allclose(
         got, _reference_rmatvec(idx, val, r, d), rtol=2e-4, atol=1e-4
     )

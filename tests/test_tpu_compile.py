@@ -7,11 +7,11 @@ the machine with the chip: misaligned Pallas blocks, programs over the
 16 GB of device memory, unsupported dtypes. Nothing runs, so these tests
 say nothing about results or times.
 
-``jax.default_backend()`` still reads ``cpu`` in such a compile, so each
-test steers the TPU-only branches with the knobs the program already has
-(``PHOTON_SPARSE_WINDOWS``, ``PHOTON_SPARSE_RMATVEC``,
-``PHOTON_SPARSE_GATHER``, ``PHOTON_SWEEP_DONATION``,
-``PHOTON_SCORE_DONATION``).
+``jax.default_backend()`` still reads ``cpu`` in such a compile, so the
+whole module runs under one ``target.compiling_for("tpu")``: the layout is
+built, the gathers fetch rows and the sweeps donate, as on the chip.
+Nothing here RUNS a sweep or score program (XLA:CPU corrupts donated
+buffers); a test that does belongs in another file.
 
 The topology is described inside a module-scoped fixture and nowhere
 else: only one process may load the TPU library, pytest-xdist workers
@@ -46,6 +46,7 @@ from photon_tpu.optimize.problem import (
     RegularizationType,
 )
 from photon_tpu.types import TaskType
+from photon_tpu.util import target
 
 #: the smoke's widths and scale (chip_smoke.py; bench ``game_ctr_scale``)
 ROWS = chip_smoke.ROWS
@@ -77,9 +78,10 @@ def one_chip(topo):
 
 @pytest.fixture(scope="module", autouse=True)
 def _as_on_the_chip():
-    """f32 like a run on the chip (conftest turns x64 on for the CPU
-    suite), and no persistent cache: a compile for a described chip is
-    written to it but cannot be read back without a chip."""
+    """Programs for a TPU (module docstring), f32 like a run on the chip
+    (conftest turns x64 on for the CPU suite), and no persistent cache: a
+    compile for a described chip is written to it but cannot be read back
+    without a chip."""
     from jax.experimental.compilation_cache import compilation_cache
 
     x64 = jax.config.jax_enable_x64
@@ -87,7 +89,8 @@ def _as_on_the_chip():
     jax.config.update("jax_enable_x64", False)
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield
+    with target.compiling_for("tpu"):
+        yield
     jax.config.update("jax_enable_x64", x64)
     jax.config.update("jax_enable_compilation_cache", cache)
     compilation_cache.reset_cache()
@@ -156,41 +159,26 @@ def _fits(compiled):
 
 @pytest.fixture(scope="module")
 def fe_coordinate(deployment):
-    """Built with the window layout forced on: the TPU branch of
-    ``maybe_build_windows``."""
-    mp = pytest.MonkeyPatch()
-    mp.setenv("PHOTON_SPARSE_WINDOWS", "1")
-    try:
-        coord = FixedEffectCoordinate.build(
-            deployment,
-            FixedEffectCoordinateConfig(
-                feature_shard="global",
-                optimization=_opt(10),
-                regularization_weights=(1.0,),
-            ),
-        )
-    finally:
-        mp.undo()
+    """With the window layout: the TPU branch of ``maybe_build_windows``."""
+    coord = FixedEffectCoordinate.build(
+        deployment,
+        FixedEffectCoordinateConfig(
+            feature_shard="global",
+            optimization=_opt(10),
+            regularization_weights=(1.0,),
+        ),
+    )
     assert coord.batch.windows is not None
     return coord
 
 
-@pytest.fixture()
-def tpu_branches(monkeypatch):
-    """What ``jax.default_backend() == 'tpu'`` selects on the chip."""
-    monkeypatch.setenv("PHOTON_SPARSE_RMATVEC", "prefix")
-    monkeypatch.setenv("PHOTON_SPARSE_GATHER", "chunked")
-    monkeypatch.setenv("PHOTON_SWEEP_DONATION", "1")
-    monkeypatch.setenv("PHOTON_SCORE_DONATION", "1")
-
-
-def test_fe_sweep_compiles_for_v5e(fe_coordinate, one_chip, tpu_branches):
+def test_fe_sweep_compiles_for_v5e(fe_coordinate, one_chip):
     coord = fe_coordinate
     n = coord.batch.labels.shape[0]
     row = jax.ShapeDtypeStruct((n,), coord.dtype, sharding=one_chip)
     compiled = (
         type(coord)
-        ._active_sweep_jit(True)
+        ._active_sweep_jit(None)  # the platform's: donating, as on the chip
         .lower(
             coord,
             _on(coord.batch, one_chip),
@@ -207,9 +195,11 @@ def test_fe_sweep_compiles_for_v5e(fe_coordinate, one_chip, tpu_branches):
     assert m.alias_size_in_bytes >= 2 * n * 4
     assert coord.batch.windows.instance_len == 4096
     assert coord.batch.windows.window == 128
+    text = compiled.as_text()
+    assert "photon.rmatvec.prefix" in text and "photon.gather.fetch" in text
 
 
-def test_re_bucket_sweep_compiles_for_v5e(deployment, one_chip, tpu_branches):
+def test_re_bucket_sweep_compiles_for_v5e(deployment, one_chip):
     """The fused RE sweep over ONE bucket of the per-user coordinate: the
     cap-sized one ([E, 128, 16], ~1.5 s). The whole coordinate is the same
     program over seven buckets and compiles in ~90 s here, nearly all of
@@ -252,7 +242,7 @@ def test_re_bucket_sweep_compiles_for_v5e(deployment, one_chip, tpu_branches):
     _fits(compiled)
 
 
-def test_scorer_batch_program_compiles_for_v5e(one_chip, tpu_branches):
+def test_scorer_batch_program_compiles_for_v5e(one_chip):
     """The fused score program of a GLMix model at the smoke's widths:
     FE d = 2^17 through a 32-wide ELL block (24 nnz snapped to its
     power-of-two level), per-user and per-item tables of d = 16."""
@@ -323,26 +313,12 @@ def _rmatvec_compiled(fn, fe_coordinate, one_chip):
     )
 
 
-def test_prefix_rmatvec_compiles_for_v5e(
-    fe_coordinate, one_chip, tpu_branches
-):
+def test_prefix_rmatvec_compiles_for_v5e(fe_coordinate, one_chip):
     _fits(
         _rmatvec_compiled(
             sparse_windows.rmatvec_windows_prefix, fe_coordinate, one_chip
         )
     )
-
-
-def test_pallas_rmatvec_compiles_for_v5e(
-    fe_coordinate, one_chip, tpu_branches
-):
-    """Instance length 4096 x window 128, through Mosaic: the kernel is
-    in the program (``tpu_custom_call``), not a give-way."""
-    compiled = _rmatvec_compiled(
-        sparse_windows.rmatvec_windows_pallas, fe_coordinate, one_chip
-    )
-    _fits(compiled)
-    assert "tpu_custom_call" in compiled.as_text()
 
 
 # --- the sparse cell's two passes, from shapes alone ------------------------
@@ -361,38 +337,33 @@ def cell_passes(one_chip):
     from photon_tpu.ops.objective import matvec
     from photon_tpu.types import SparseBatch
 
-    mp = pytest.MonkeyPatch()
-    mp.setenv("PHOTON_SPARSE_GATHER", "chunked")
-    try:
-        sds = lambda shape, dt=jnp.float32: jax.ShapeDtypeStruct(  # noqa: E731
-            shape, dt, sharding=one_chip
-        )
-        n, k, d = CELL_N, CELL_K, CELL_D
-        batch = SparseBatch(
-            indices=sds((n, k), jnp.int32), values=sds((n, k)),
-            labels=sds((n,)), offsets=sds((n,)), weights=sds((n,)),
-            windows=None,
-        )
-        forward = jax.jit(matvec).lower(batch, sds((d,))).compile()
-        w_inst = CELL_INSTANCES
-        w_inst += (-w_inst) % sparse_windows.instance_multiple(
-            w_inst, CELL_LENGTH, 4
-        )
-        windows = sparse_windows.ColumnWindows(
-            rows=sds((w_inst, CELL_LENGTH), jnp.int32),
-            lcols=sds((w_inst, CELL_LENGTH), jnp.int32),
-            vals=sds((w_inst, CELL_LENGTH)),
-            inst2win=sds((w_inst,), jnp.int32),
-            iota=sds((CELL_WINDOW,), jnp.int32),
-            bounds=sds((w_inst, CELL_WINDOW + 1), jnp.int32),
-        )
-        backward = (
-            jax.jit(sparse_windows.rmatvec_windows_prefix, static_argnums=2)
-            .lower(windows, sds((n,)), d)
-            .compile()
-        )
-    finally:
-        mp.undo()
+    sds = lambda shape, dt=jnp.float32: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=one_chip
+    )
+    n, k, d = CELL_N, CELL_K, CELL_D
+    batch = SparseBatch(
+        indices=sds((n, k), jnp.int32), values=sds((n, k)),
+        labels=sds((n,)), offsets=sds((n,)), weights=sds((n,)),
+        windows=None,
+    )
+    forward = jax.jit(matvec).lower(batch, sds((d,))).compile()
+    w_inst = CELL_INSTANCES
+    w_inst += (-w_inst) % sparse_windows.instance_multiple(
+        w_inst, CELL_LENGTH, 4
+    )
+    windows = sparse_windows.ColumnWindows(
+        rows=sds((w_inst, CELL_LENGTH), jnp.int32),
+        lcols=sds((w_inst, CELL_LENGTH), jnp.int32),
+        vals=sds((w_inst, CELL_LENGTH)),
+        inst2win=sds((w_inst,), jnp.int32),
+        iota=sds((CELL_WINDOW,), jnp.int32),
+        bounds=sds((w_inst, CELL_WINDOW + 1), jnp.int32),
+    )
+    backward = (
+        jax.jit(sparse_windows.rmatvec_windows_prefix, static_argnums=2)
+        .lower(windows, sds((n,)), d)
+        .compile()
+    )
     return {
         "forward": (forward, n * k),
         "backward": (backward, w_inst * CELL_LENGTH),
@@ -470,7 +441,7 @@ def test_cell_pass_temporaries_and_relayouts(cell_passes, which):
     ), sorted(under)
 
 
-def test_cell_single_row_bucket_sweep_fits_its_budget(one_chip, tpu_branches):
+def test_cell_single_row_bucket_sweep_fits_its_budget(one_chip):
     """The per-user coordinate's sweep over the ``glmix_ctr.sweeps`` cell's
     one-row bucket, [1 997 496, 1, 16] (the bucket the cell's structure seed
     gives at 2^22 rows and 2^21 users, PERF.md PR 31): the solve runs as a
