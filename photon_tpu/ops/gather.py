@@ -40,6 +40,21 @@ segment from ``_SEG_BYTES``, the table's itemsize and the tiling rule of
 the sliced axis; where it says one segment there is no loop at all (the
 per-entity solves under ``vmap``, the scorer's batches).
 
+**Two block sizes in the one loop** (:func:`map_segment_groups`; PERF.md
+§6, PR 35). The backward consumer's prefix sums want the window instances
+on the 128 lanes, and a segment that fast memory sizes holds 32: on
+[32, 4096] the compiler scans ACROSS the lanes (0.090 s a pass), on
+[128, 4096] it lays the instances minor and the scan is plain vector adds
+(0.0245 s). So the fetch and the select stay on segments, a buffer carried
+through the loop collects what ``group`` consecutive segments emit (2 MB,
+in fast memory), and the consumer runs on it once a group, under a
+``lax.cond`` in the same loop: a backward pass 0.7312 → 0.6710 s. It is
+ONE loop because the other forms lose the table: a loop of consumer blocks
+around the loop of segments makes the table an HBM operand of the fetch
+(compiled text, PR 35), and a 128-instance segment puts the fetched block
+in HBM. Blocks of 256 and 512 instances read 0.6723 and 0.6802 s: the
+least group that fills the lanes is the one to take.
+
 Which gather a program gets is a fact about its platform
 (:func:`fetches_rows`): the row fetch in a program for a TPU, the plain
 ``table[idx]`` elsewhere (CPU's native gather is faster than the 128x
@@ -63,6 +78,7 @@ __all__ = [
     "fetch_select_dot",
     "fetches_rows",
     "lane_rows",
+    "map_segment_groups",
     "map_segments",
     "segment_plan",
     "take_1d",
@@ -189,6 +205,91 @@ def map_segments(
         lo = segs * per
         rest = body(
             *(jax.lax.slice_in_dim(s, lo, lo + tail, axis=axis) for s in streams)
+        )
+        out = jnp.concatenate([out, rest])
+    return out
+
+
+def map_segment_groups(
+    body: Callable[..., Array],
+    consumer: Callable[..., Array],
+    streams: Sequence[Array],
+    consumer_streams: Sequence[Array],
+    plan: SegmentPlan,
+    group: int,
+) -> Array:
+    """``consumer(body(*blocks), *consumer_blocks)`` over the leading axis,
+    with the two halves on blocks of their own size: ``body`` (the fetch
+    and select) on every segment of ``plan``, ``consumer`` once per
+    ``group`` consecutive segments, on their ``group * plan.per`` units
+    together. Both hand back arrays whose leading axis is the unit axis,
+    and every unit's result depends on that unit alone.
+
+    ONE loop over the segments does it, not a loop in a loop (which takes
+    the table out of fast memory: the module docstring): it carries a
+    [group * per, ...] buffer of what ``body`` emitted and the result,
+    writes its segment at ``(i % group) * per`` and runs ``consumer`` on
+    the buffer under a ``lax.cond`` on every ``group``-th step. The units
+    past the last whole group (none where the layout was padded to whole
+    groups) go through :func:`map_segments`, a segment to a consumer
+    block; so does everything where ``group`` is 1."""
+    n_body = len(streams)
+
+    def both(*blocks):
+        return consumer(body(*blocks[:n_body]), *blocks[n_body:])
+
+    everything = (*streams, *consumer_streams)
+    segs, per, tail = plan
+    if group == 1 or segs < group:
+        return map_segments(both, everything, plan, 0)
+    groups = segs // group
+    block = group * per
+    done = groups * block
+
+    def segment(i, carry):
+        buf, out = carry
+        seg = body(
+            *(jax.lax.dynamic_slice_in_dim(s, i * per, per, 0) for s in streams)
+        )
+        at = jax.lax.rem(i, group)
+        buf = jax.lax.dynamic_update_slice_in_dim(buf, seg, at * per, 0)
+
+        def consume(out):
+            lo = (i - at) * per
+            res = consumer(
+                buf,
+                *(
+                    jax.lax.dynamic_slice_in_dim(s, lo, block, 0)
+                    for s in consumer_streams
+                ),
+            )
+            return jax.lax.dynamic_update_slice_in_dim(out, res, lo, 0)
+
+        return buf, jax.lax.cond(at == group - 1, consume, lambda o: o, out)
+
+    def units(x, k):  # the shape of k units of x
+        return jax.ShapeDtypeStruct((k,) + x.shape[1:], x.dtype)
+
+    buf = units(jax.eval_shape(body, *(units(s, per) for s in streams)), block)
+    out = units(
+        jax.eval_shape(
+            consumer, buf, *(units(s, block) for s in consumer_streams)
+        ),
+        done,
+    )
+    _, out = jax.lax.fori_loop(
+        0,
+        groups * group,
+        segment,
+        (jnp.zeros(buf.shape, buf.dtype), jnp.zeros(out.shape, out.dtype)),
+    )
+    left = segs * per + tail - done
+    if left:
+        rest = map_segments(
+            both,
+            [jax.lax.slice_in_dim(s, done, done + left) for s in everything],
+            SegmentPlan(left // per, per, left % per),
+            0,
         )
         out = jnp.concatenate([out, rest])
     return out

@@ -26,13 +26,18 @@ The fix is a build-time layout that turns the scatter into dense work:
   (:func:`rmatvec_windows_prefix`, the one rmatvec over a layout).
 - **The gather side is the floor**: contrib = vals · r[rows] is a
   1-element gather from a [N] vector, which XLA:TPU serializes; it goes
-  through ops/gather's row fetch and lane select and is 75 % of a
+  through ops/gather's row fetch and lane select and is 81 % of a
   backward pass (PERF.md §5). ``_over_instances`` runs it in the segment
-  loop of the pass: a segment is a block of whole instances whose fetched
-  rows fit fast memory (``gather.segment_plan``: 32 instances of 4096
-  slots), the loop's body carries the consumer (the centring, cumsum and
-  bounds reads, [I, L] → [I, w]), and the build pads the instance count
-  to whole segments, so [W_inst, L] is read as it lies and no pass pads,
+  loop of the pass, on two block sizes (``_backward_cut``). A SEGMENT is a
+  block of whole instances whose fetched rows fit fast memory
+  (``gather.segment_plan``: 32 instances of 4096 slots); its body fetches,
+  selects and multiplies by ``vals``. The CONSUMER (the centring, cumsum
+  and bounds reads, [I, L] → [I, w]) runs once per 4 segments, on their
+  128 instances together: with a lane's worth of instances in its block
+  the cumsum scans with the instances on the lanes (0.0245 s a pass at the
+  benchmark's sparse cell; 0.090 s on a segment's 32, where it scans across
+  them: PERF.md §6, PR 35). The build pads the instance count to whole
+  consumer blocks, so [W_inst, L] is read as it lies and no pass pads,
   slices or stacks a value per slot.
 
 Instance partials combine with one [W_inst, w] → [W, w] sorted
@@ -59,7 +64,9 @@ from photon_tpu.obs.scopes import scope
 from photon_tpu.types import Array
 from photon_tpu.util import target
 
-#: the TPU sublane rule: the second-to-last dim of a block is a multiple of 8
+#: the TPU tiling rules: the last dim of a block lies on 128 lanes, the
+#: second-to-last is a multiple of 8 sublanes
+_LANES = 128
 _SUBLANES = 8
 
 
@@ -75,7 +82,7 @@ class ColumnWindows(NamedTuple):
     boundaries for the prefix-sum rmatvec. Padding slots: row 0, local col
     w−1, value 0. W_inst is padded at build time (inert instances) to a
     multiple of 8 (the TPU sublane rule for a block of whole instances) and
-    of the instances per segment of the backward pass
+    of the instances per consumer block of the backward pass
     (``instance_multiple``).
     """
 
@@ -95,16 +102,32 @@ class ColumnWindows(NamedTuple):
         return self.rows.shape[1]
 
 
-def instance_multiple(w_inst: int, length: int, itemsize: int) -> int:
-    """What a layout's instance count is padded to a multiple of: the
-    instances in a segment of the backward pass (ops/gather.segment_plan
-    for a per-row table of ``itemsize``-byte entries), or 8 where the pass
-    is one segment."""
+def _backward_cut(w_inst: int, length: int, itemsize: int):
+    """How the backward pass cuts a layout of ``w_inst`` instances of
+    ``length`` slots over a per-row table of ``itemsize``-byte entries:
+    (ops/gather's ``SegmentPlan`` of whole instances, segments to a block of
+    the consumer). The fetch's block is sized by fast memory (the plan); the
+    consumer's by the lanes: the least whole number of segments that puts a
+    lane's worth of instances (128) in its block, so that the prefix sums
+    scan with the instances on the lanes. One segment to a block where a
+    segment holds that many already, where the pass is one segment, and
+    where the layout has no 128 instances."""
     from photon_tpu.ops.gather import segment_plan
 
-    w_inst += (-w_inst) % _SUBLANES
     plan = segment_plan(w_inst, length, itemsize, _SUBLANES)
-    return _SUBLANES if plan.steps == 1 else plan.per
+    if plan.steps == 1 or w_inst < _LANES:
+        return plan, 1
+    return plan, -(-_LANES // plan.per)
+
+
+def instance_multiple(w_inst: int, length: int, itemsize: int) -> int:
+    """What a layout's instance count is padded to a multiple of: the
+    instances in a consumer block of the backward pass (``_backward_cut``:
+    whole segments of its loop), or 8 where the pass is one segment."""
+    plan, group = _backward_cut(
+        w_inst + (-w_inst) % _SUBLANES, length, itemsize
+    )
+    return _SUBLANES if plan.steps == 1 else plan.per * group
 
 
 def _native_histogram(arr_idx, arr_val, num_features):
@@ -189,10 +212,19 @@ def build_column_windows(
     placement, where materializing the whole stream on one device first
     would be the exact single-device footprint the sharding avoids.
     """
-    with obs.span("windows.build", cat="build", num_features=num_features):
-        return _build_column_windows(
+    with obs.span(
+        "windows.build", cat="build", num_features=num_features
+    ) as sp:
+        windows = _build_column_windows(
             indices, values, num_features, window, instance_cap, chunk, host
         )
+        # what a TPU's backward pass makes of these shapes: the steps of its
+        # loop, and the instances `photon.rmatvec.prefix` sees at once
+        plan, group = _backward_cut(
+            *windows.rows.shape, windows.vals.dtype.itemsize
+        )
+        sp.set(segments=plan.steps, consumer_block=plan.per * group)
+        return windows
 
 
 def _build_column_windows(
@@ -239,8 +271,8 @@ def _build_column_windows(
     # Round the instance count with inert instances (vals 0 / lcol w−1 /
     # last window id) to a multiple of 8 (a block of instances meets the
     # TPU sublane-divisibility rule for any layout) and of the backward
-    # pass's instances per segment, so that its loop runs over whole
-    # segments and no pass pads or slices the streams.
+    # pass's instances per consumer block, so that its loop runs over whole
+    # blocks of whole segments and no pass pads or slices the streams.
     w_inst_pad = (-w_inst) % instance_multiple(
         w_inst, length, arr_val.dtype.itemsize
     )
@@ -345,28 +377,35 @@ def _over_instances(
 
     This gather, not the scatter, is the floor of the windowed rmatvec,
     and what it costs is where its fetched rows land (ops/gather's module
-    docstring). So where the layout's fetched rows pass one segment
-    the backward pass runs the segment loop here: a segment is a block of
-    whole instances (``segment_plan``; the build pads the instance count to
-    a multiple of it), its body fetches ``r[rows]``, selects, multiplies by
-    ``vals`` and hands the [instances, L] block to ``consumer``, and the
-    loop stacks what the consumer returns. One segment, or the plain
-    gather: ``consumer`` gets the whole layout at once."""
+    docstring). So where the layout's fetched rows pass one segment the
+    backward pass runs the segment loop here, on the two block sizes of
+    ``_backward_cut``. A segment is a block of whole instances sized by
+    fast memory (``segment_plan``); its body fetches ``r[rows]``, selects
+    and multiplies by ``vals``. ``consumer`` gets the [instances, L]
+    contributions of ``group`` consecutive segments at once, a block sized
+    by the lanes (at least 128 instances), and the loop stacks what it
+    returns (``gather.map_segment_groups``; the build pads the instance
+    count to whole consumer blocks). With ``group`` 1 the consumer sits in
+    the segment's body. One segment, or the plain gather: ``consumer`` gets
+    the whole layout at once."""
     from photon_tpu.ops import gather
 
-    plan = gather.segment_plan(
-        *windows.rows.shape, per_row.dtype.itemsize, _SUBLANES
-    )
+    plan, group = _backward_cut(*windows.rows.shape, per_row.dtype.itemsize)
     if plan.steps == 1 or not gather.fetches_rows():
         contrib = windows.vals * gather.take_1d(per_row, windows.rows)
         return consumer(contrib, *streams)
     t2 = gather.lane_rows(per_row)
 
-    def instances_block(rows, vals, *blocks):
-        return consumer(vals * gather.fetch_select(t2, rows), *blocks)
+    def contributions(rows, vals):
+        return vals * gather.fetch_select(t2, rows)
 
-    return gather.map_segments(
-        instances_block, (windows.rows, windows.vals, *streams), plan, axis=0
+    return gather.map_segment_groups(
+        contributions,
+        consumer,
+        (windows.rows, windows.vals),
+        streams,
+        plan,
+        group,
     )
 
 
@@ -378,8 +417,8 @@ def rmatvec_windows_prefix(
     contribution prefix sum at build-time-static boundaries — a cumsum plus
     a [W_inst, w+1] gather. Fully dense, no scatter, no custom kernel: the
     lowering-proof TPU path. The algebra (``_prefix_partials``) runs per
-    block of instances inside the gather's segment loop, so what is stacked
-    is [W_inst, w], not the [W_inst, L] contributions."""
+    block of at least 128 instances inside the gather's segment loop, so
+    what is stacked is [W_inst, w], not the [W_inst, L] contributions."""
     out_inst = _over_instances(
         windows, per_row, _prefix_partials, windows.bounds
     )

@@ -46,8 +46,8 @@ def pad_windows_for_mesh(
 ) -> ColumnWindows:
     """Pad the instance axis with inert instances (vals 0, lcol w−1, last
     window id) to ``num_shards`` equal runs, each a multiple of the
-    instances per segment its shard's backward pass loops over (the
-    build's own padding rule, per shard)."""
+    instances per consumer block of its shard's backward pass (whole
+    segments of its loop: the build's own padding rule, per shard)."""
     w_inst, length = windows.rows.shape
     per_shard = -(-w_inst // num_shards)
     per_shard += (-per_shard) % instance_multiple(

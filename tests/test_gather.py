@@ -95,10 +95,14 @@ def test_segment_plan_scales_with_table_itemsize():
 #: cell forward ([2^22, 56] ELL) and backward (58 384 instances of 4096
 #: slots, padded to 58 400), chip_smoke's FE coordinate (game_ctr_scale:
 #: 2^18 rows x 25 slots; 2056 instances padded to 2080), one slot, a
-#: float64 table, and a per-entity block that is one segment
+#: float64 table, and a per-entity block that is one segment; since PR 35
+#: the two cells' layouts are padded to whole consumer blocks of 4 segments
+#: (58 496 and 25 216 instances: 457 and 197 blocks)
 _PLANS = {
     "cell_forward": ((1 << 22, 56, 4, 128), (1820, 2304, 1024)),
     "cell_backward": ((58400, 4096, 4, 8), (1825, 32, 0)),
+    "cell_backward_blocks": ((58496, 4096, 4, 8), (1828, 32, 0)),
+    "glmix_backward_blocks": ((25216, 4096, 4, 8), (788, 32, 0)),
     "game_ctr_forward": ((1 << 18, 25, 4, 128), (51, 5120, 1024)),
     "game_ctr_backward": ((2080, 4096, 4, 8), (65, 32, 0)),
     "one_slot": ((1, 1, 4, 1024), (1, 1, 0)),
@@ -182,6 +186,74 @@ def test_map_segments_slices_every_stream_along_axis(small_segments):
     assert (plan.segments, plan.per, plan.tail) == (4, 8, 3)
     out = map_segments(lambda x, y: jnp.sum(x + y, axis=0), (a, b), plan, 1)
     assert np.array_equal(np.asarray(out), np.asarray(jnp.sum(3.0 * a, 0)))
+
+
+@pytest.mark.parametrize(
+    "units,per,group",
+    [
+        (96, 8, 4),  # whole groups: 3 consumer blocks of 32 units
+        (32, 8, 4),  # exactly one
+        (104, 8, 4),  # a segment left over after 3 groups
+        (125, 8, 4),  # three segments and a ragged end left over
+        (29, 8, 4),  # not one whole group: every segment its own block
+        (96, 8, 1),  # a group of one: the one-level loop
+        (88, 8, 16),  # a group larger than the pass
+    ],
+)
+def test_map_segment_groups_is_the_consumer_of_the_body(units, per, group):
+    """The two-level loop: the body on segments of ``per`` units, the
+    consumer on ``group`` of them together; every unit's result is the
+    whole-array ``consumer(body(x), s)``'s, bit for bit (both halves are
+    per unit and exact here), whatever is left over after the last group."""
+    from photon_tpu.ops.gather import SegmentPlan, map_segment_groups
+
+    rng = np.random.default_rng(units + group)
+    x = jnp.asarray(rng.integers(-8, 8, size=(units, 6)).astype(np.float32))
+    y = jnp.asarray(rng.integers(-8, 8, size=(units, 6)).astype(np.float32))
+    s = jnp.asarray(rng.integers(0, 6, size=(units, 3)).astype(np.int32))
+    body = lambda a, b: a * b + 1.0  # noqa: E731
+    consumer = lambda c, ix: jnp.take_along_axis(  # noqa: E731
+        jnp.cumsum(c, axis=1), ix, axis=1
+    )
+    plan = SegmentPlan(units // per, per, units % per)
+    got = jax.jit(
+        lambda x, y, s: map_segment_groups(
+            body, consumer, (x, y), (s,), plan, group
+        )
+    )(x, y, s)
+    assert np.array_equal(np.asarray(got), np.asarray(consumer(body(x, y), s)))
+
+
+def test_map_segment_groups_runs_the_consumer_once_a_group():
+    """One loop, not a loop in a loop: the segments' ``while`` holds the
+    consumer under one ``cond``, and the consumer sees ``group * per``
+    units; a group of one is :func:`map_segments`' program."""
+    from photon_tpu.ops.gather import SegmentPlan, map_segment_groups
+
+    seen = []
+
+    def consumer(c):
+        seen.append(c.shape)
+        return jnp.cumsum(c, axis=1)
+
+    x = jnp.ones((96, 6), jnp.float32)
+    plan = SegmentPlan(12, 8, 0)
+    two = jax.make_jaxpr(
+        lambda x: map_segment_groups(jnp.sin, consumer, (x,), (), plan, 4)
+    )(x)
+    assert seen == [(32, 6)] * len(seen) and seen
+    text = str(two)
+    assert text.count("scan[") + text.count("while[") == 1
+    assert text.count("cond[") == 1
+    both = lambda b: jnp.cumsum(jnp.sin(b), axis=1)  # noqa: E731
+    one = jax.make_jaxpr(
+        lambda x: map_segment_groups(
+            jnp.sin, lambda c: jnp.cumsum(c, axis=1), (x,), (), plan, 1
+        )
+    )(x)
+    assert str(one) == str(
+        jax.make_jaxpr(lambda x: map_segments(both, (x,), plan, 0))(x)
+    )
 
 
 def test_chunked_take_odd_slot_count_segments():

@@ -583,8 +583,10 @@ def test_build_pads_instances_to_the_segment(row_fetch):
     idx, val, r, windows = _layout(15)
     w_inst, length = windows.rows.shape
     assert w8 % 8 == 0 and w8 % 40 != 0
-    assert length == 32 and w_inst == -(-w8 // 40) * 40
-    assert instance_multiple(w8, length, 4) == 40
+    assert length == 32 and w_inst == 160
+    # 144 instances are past a lane's worth (128): the multiple is the
+    # consumer's block, the least whole segments that hold 128 instances
+    assert instance_multiple(w8, length, 4) == 160
     assert np.asarray(windows.inst2win).size == w_inst
     inert = slice(w8, w_inst)
     assert not np.asarray(windows.vals)[inert].any()
@@ -600,6 +602,138 @@ def test_build_pads_instances_to_the_segment(row_fetch):
     assert instance_multiple(w_inst, length, 4) == 8
 
 
+# --- two levels: the fetch on segments, the consumer on blocks of >= 128 ------
+
+#: (rows, instances per segment the layout is BUILT for, ... it is RUN at):
+#: what the pass makes of the layout's instance count
+_TWO_LEVEL = {
+    # 24 instances: one segment, no loop
+    "below_one_segment": (150, None, 32),
+    # under a lane's worth of instances: every segment its own block
+    "under_128_instances": (600, 32, 32),
+    "one_outer_block": (800, 32, 32),
+    "several_outer_blocks": (2600, 32, 32),
+    # built for another segment: segments and a ragged end after the blocks
+    "left_over_after_the_blocks": (2000, None, 40),
+    # segments of 128 instances need no second level
+    "segment_holds_128": (2600, 128, 128),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_TWO_LEVEL))
+def test_two_level_loop_matches_whole_layout_and_reference(row_fetch, case):
+    """The backward pass with its consumer on blocks of whole segments
+    against the consumer on the whole layout (instances are independent:
+    the same numbers to the rounding of a cumsum whose order the compiler
+    picks by block shape) and against the segment_sum reference."""
+    from photon_tpu.ops.sparse_windows import _backward_cut
+
+    n, built_for, run_at = _TWO_LEVEL[case]
+    row_fetch((built_for or 1 << 20) * 32)
+    idx, val, r, windows = _layout(21, n=n)
+    w_inst, length = windows.rows.shape
+    assert length == 32
+    row_fetch(w_inst * length)
+    whole = np.asarray(rmatvec_windows_prefix(windows, jnp.asarray(r), 300))
+    row_fetch(run_at * length)
+    plan, group = _backward_cut(w_inst, length, 4)
+    blocks, left = divmod(w_inst, plan.per * group)
+    assert (plan.steps, group, blocks if group > 1 else 0, left) == {
+        "below_one_segment": (1, 1, 0, 0),
+        "under_128_instances": (3, 1, 0, 0),
+        "one_outer_block": (4, 4, 1, 0),
+        "several_outer_blocks": (12, 4, 3, 0),
+        "left_over_after_the_blocks": (7, 4, 1, 104),
+        "segment_holds_128": (3, 1, 0, 0),
+    }[case], (w_inst, plan, group)
+    cut = np.asarray(rmatvec_windows_prefix(windows, jnp.asarray(r), 300))
+    np.testing.assert_allclose(whole, cut, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(
+        cut, _reference_rmatvec(idx, val, r, 300), rtol=2e-4, atol=1e-4
+    )
+
+
+@pytest.mark.parametrize(
+    "w_inst,expect",
+    [
+        (58384, 58496),  # sparse_poisson's layout: 457 blocks of 4 segments
+        (58400, 58496),  # ... from the count PR 30 padded it to
+        (25120, 25216),  # glmix_ctr's fixed effect: 197 blocks
+        (2056, 2176),  # chip_smoke's fixed effect
+        (96, 96),  # three segments under a lane's worth: no second level
+        (24, 24),  # one segment: the multiple of 8
+    ],
+)
+def test_instance_multiple_at_quoted_shapes(w_inst, expect):
+    """At ``_SEG_BYTES`` = 2^26 and 4096 slots an instance a segment is 32
+    instances and a consumer block 4 of them."""
+    from photon_tpu.ops.sparse_windows import instance_multiple
+
+    assert w_inst + (-w_inst) % instance_multiple(w_inst, 4096, 4) == expect
+
+
+@pytest.mark.parametrize("case", ["one_segment", "under_128", "holds_128"])
+def test_one_level_programs_are_the_parents(row_fetch, case):
+    """Where the second level does not engage, the pass traces to the
+    program it was before it had one, operation for operation: the whole
+    layout through the consumer (one segment), or ``map_segments`` with the
+    consumer in the segment's body."""
+    from photon_tpu.ops import gather
+    from photon_tpu.ops.sparse_windows import _over_instances, _prefix_partials
+
+    n, per = {
+        "one_segment": (150, 32), "under_128": (600, 32), "holds_128": (2600, 128)
+    }[case]
+    row_fetch(1 << 20)
+    idx, val, r, windows = _layout(22, n=n)
+    w_inst, length = windows.rows.shape
+    gather_mod = row_fetch(per * length)
+    plan = gather_mod.segment_plan(w_inst, length, 4, 8)
+    assert (plan.steps == 1) == (case == "one_segment")
+
+    def parent(w, r_):
+        if plan.steps == 1:
+            contrib = w.vals * gather.take_1d(r_, w.rows)
+            return _prefix_partials(contrib, w.bounds)
+        t2 = gather.lane_rows(r_)
+
+        def instances_block(rows, vals, bounds):
+            return _prefix_partials(vals * gather.fetch_select(t2, rows), bounds)
+
+        return gather.map_segments(
+            instances_block, (w.rows, w.vals, w.bounds), plan, axis=0
+        )
+
+    now = jax.make_jaxpr(
+        lambda w, r_: _over_instances(w, r_, _prefix_partials, w.bounds)
+    )(windows, jnp.asarray(r))
+    assert str(now) == str(jax.make_jaxpr(parent)(windows, jnp.asarray(r)))
+
+
+def test_build_span_records_the_consumer_block(row_fetch):
+    """``windows.build`` carries what the backward pass makes of the
+    layout's shapes: its loop's steps and the instances a consumer block
+    holds (the whole layout where the pass is one segment)."""
+    from photon_tpu import obs
+
+    obs.reset()
+    obs.enable()
+    try:
+        row_fetch(32 * 32)
+        w_inst = _layout(23, n=2600)[3].rows.shape[0]
+        row_fetch(1 << 20)
+        small = _layout(23, n=150)[3].rows.shape[0]
+        spans = [
+            sp for sp in obs.get_tracer().spans() if sp.name == "windows.build"
+        ]
+    finally:
+        obs.disable()
+        obs.reset()
+    assert [(sp.args["segments"], sp.args["consumer_block"]) for sp in spans] == [
+        (w_inst // 32, 128), (1, small)
+    ]
+
+
 def test_pad_windows_for_mesh_pads_each_shard_to_the_segment(row_fetch):
     from photon_tpu.parallel.sparse import pad_windows_for_mesh
 
@@ -609,6 +743,7 @@ def test_pad_windows_for_mesh_pads_each_shard_to_the_segment(row_fetch):
     gather_mod = row_fetch(16 * length)
     padded = pad_windows_for_mesh(windows, 4, 300)
     per_shard = padded.rows.shape[0] // 4
+    # 36 instances a shard: under a lane's worth, so whole segments of 16
     assert padded.rows.shape[0] % 4 == 0 and per_shard % 16 == 0
     assert gather_mod.segment_plan(per_shard, length, 4, 8).tail == 0
     assert padded.bounds.shape[0] == padded.rows.shape[0]
@@ -617,4 +752,38 @@ def test_pad_windows_for_mesh_pads_each_shard_to_the_segment(row_fetch):
     ))
     np.testing.assert_allclose(
         got, _reference_rmatvec(idx, val, r, 300), rtol=2e-4, atol=1e-4
+    )
+
+
+def test_pad_windows_for_mesh_pads_each_shard_to_the_consumer_block(row_fetch):
+    """A shard of 128 instances or more runs the two-level loop: its count
+    is padded to whole consumer blocks, and the shards' passes (each a
+    [dim] partial, as ``sharded_windowed_rmatvec`` sums them) add up to the
+    reference."""
+    from photon_tpu.ops.sparse_windows import _backward_cut
+    from photon_tpu.parallel.sparse import pad_windows_for_mesh
+
+    row_fetch(1 << 20)
+    idx, val, r, windows = _layout(24, n=4000)
+    w_inst, length = windows.rows.shape
+    row_fetch(32 * length)
+    padded = pad_windows_for_mesh(windows, 4, 300)
+    per_shard = padded.rows.shape[0] // 4
+    assert -(-w_inst // 4) >= 128 and per_shard % 128 == 0
+    plan, group = _backward_cut(per_shard, length, 4)
+    assert plan.tail == 0 and group == 4 and plan.segments % group == 0
+    total = np.zeros(300)
+    for k in range(4):
+        own = slice(k * per_shard, (k + 1) * per_shard)
+        shard = ColumnWindows(
+            *(
+                jnp.asarray(x if name == "iota" else x[own])
+                for name, x in zip(ColumnWindows._fields, padded)
+            )
+        )
+        total += np.asarray(
+            rmatvec_windows_prefix(shard, jnp.asarray(r), 300), np.float64
+        )
+    np.testing.assert_allclose(
+        total, _reference_rmatvec(idx, val, r, 300), rtol=2e-4, atol=1e-4
     )

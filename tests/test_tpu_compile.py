@@ -325,7 +325,8 @@ def test_prefix_rmatvec_compiles_for_v5e(fe_coordinate, one_chip):
 
 #: ``benchmarks/configs/sparse_poisson.json`` and the layout its structure
 #: builds: 58 384 instances of 4096 slots over windows of 128 columns,
-#: before the build pads the count to the backward pass's segment
+#: before the build pads the count to the backward pass's consumer block
+#: (4 segments of 32 instances: 58 496)
 CELL_N, CELL_K, CELL_D = 1 << 22, 56, 1 << 20
 CELL_INSTANCES, CELL_LENGTH, CELL_WINDOW = 58384, 4096, 128
 
@@ -439,6 +440,54 @@ def test_cell_pass_temporaries_and_relayouts(cell_passes, which):
     assert under and all(
         p[-1] in ("photon.gather.fetch", "photon.gather.select") for p in under
     ), sorted(under)
+
+
+def test_cell_backward_pass_scans_with_the_instances_on_the_lanes(cell_passes):
+    """What PR 35 is for: the prefix sums under ``photon.rmatvec.prefix``
+    scan a block of at least 128 instances laid out with the instance axis
+    minor (``{0,1,2}``: the instances on the lanes, the scanned 128 down
+    the major axis, plain vector adds). On a segment's 32 instances the
+    same line read ``f32[32,32,128]{2,1,0}``: a scan across the lanes."""
+    import re
+
+    from photon_tpu.analysis import hlo
+
+    compiled, slots = cell_passes["backward"]
+    assert slots == 58496 * CELL_LENGTH
+    text = compiled.as_text()
+    paths = hlo.instruction_scope_paths(text)
+    scans = [
+        ins.shape
+        for ins in hlo.parse_instructions(text).values()
+        if ins.opcode == "reduce-window"
+        and "photon.rmatvec.prefix" in paths.get(ins.name, ())
+    ]
+    # the scan inside chunks of 128 slots, and the one over the chunks' sums
+    assert sorted(s.count(",", 0, s.index("]")) for s in scans) == [1, 2], scans
+    for shape in scans:
+        m = re.match(r"f32\[(\d+),([\d,]+)\]\{([\d,]+):", shape)
+        assert m, shape
+        assert int(m.group(1)) >= 128, f"a block under 128 instances: {shape}"
+        assert m.group(3).startswith("0,"), f"the instances are not minor: {shape}"
+        assert "S(1)" in shape, f"the scanned block lies in HBM: {shape}"
+    (main,) = (s for s in scans if s.count(",", 0, s.index("]")) == 2)
+    assert main.startswith(f"f32[128,{CELL_LENGTH // 128},128]{{0,1,2:"), main
+
+
+def test_cell_forward_pass_has_no_second_level(cell_passes):
+    """The forward pass is the one-level loop it was (its text is the
+    parent's byte for byte but for metadata: PERF.md §6, PR 35): one loop
+    that stacks the plan's 1820 segments of margins, nothing conditional in
+    it."""
+    from photon_tpu.ops.gather import segment_plan
+
+    text = cell_passes["forward"][0].as_text()
+    assert "conditional(" not in text
+    plan = segment_plan(CELL_N, CELL_K, 4, 128)
+    assert text.count(" while(") == 1
+    assert f"f32[{plan.segments},{plan.per}]" in text  # the stacked margins
+    block = plan.per * CELL_K
+    assert f"f32[{block},128]" in text
 
 
 def test_cell_single_row_bucket_sweep_fits_its_budget(one_chip):
