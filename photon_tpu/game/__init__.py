@@ -3,7 +3,7 @@ from photon_tpu.game.config import (  # noqa: F401
     MatrixFactorizationCoordinateConfig,
     RandomEffectCoordinateConfig,
 )
-from photon_tpu.game.data import CSRMatrix, GameData  # noqa: F401
+from photon_tpu.game.data import CSRMatrix, DenseMatrix, GameData  # noqa: F401
 from photon_tpu.game.estimator import BuiltFit, GameEstimator  # noqa: F401
 from photon_tpu.game.model import (  # noqa: F401
     FixedEffectModel,

@@ -75,7 +75,8 @@ TRACE_COUNTERS: collections.Counter = collections.Counter()
 #: bucket that would need more is solved as a loop over entity chunks
 #: inside the same program (``RandomEffectCoordinate._solve_bucket_body``).
 #: 2**29: the per-user coordinate of the ``glmix_ctr`` cell (1.9 M
-#: single-row entities in one bucket) then runs in 9 chunks with 0.41 GB
+#: single-row entities in one bucket) then runs in 8 chunks (9 until
+#: PR 36 priced an entity's rows as vectors) with ~0.4 GB
 #: of temporaries where the whole bucket at once asks for 3.5 GB (compiled
 #: for a described v5e, PERF.md PR 31), and every bucket the tests and
 #: ``chip_smoke.py`` build stays one chunk.
@@ -85,23 +86,54 @@ RE_SOLVE_BYTES = 1 << 29
 #: of these arrays on the 128 lanes, 8 sublanes deep
 _CHUNK_MULTIPLE = 1024
 
+#: Device bytes one bucket's rescoring may hold in temporaries: the
+#: ``coefs[score_slot]`` gather lays each kept row's d coefficients on the
+#: 128 lanes (``rescore_row_bytes``). A bucket with more kept rows than
+#: fit is rescored as a loop over row chunks inside the same program
+#: (``RandomEffectCoordinate._rescore_rows``). 2**32, 4 194 304 rows of a
+#: 16-wide table: the largest bucket ``glmix_ctr.sweeps`` rescores at once
+#: holds 3 343 970 rows (3.4 GB of temporaries), and that cell's programs
+#: keep their shape; what is bounded is what lies beyond it, the
+#: row-heavy buckets of ``glmix_movielens.sweeps`` (PERF.md, PR 36).
+RE_RESCORE_BYTES = 1 << 32
+
 
 def solve_entity_bytes(
     rows: int, d: int, optimizer_config, itemsize: int = 4
 ) -> int:
     """Device bytes one entity of a ``[E, rows, d]`` bucket holds in
-    temporaries while its L-BFGS solve runs, reckoned from what the solver
-    carries through its loops: the block's rows with their label, offset,
-    weight, margin and direction margin, twice (a loop's carry is held
-    going in and coming out); the curvature history, 2 x m vectors of d,
-    twice; and a dozen vectors of d (point, gradient, direction, trial
-    point, the line search's brackets). For [E, 1, 16] and 5 iterations
-    that is 2.3 KB against the 1.9 KB the compiler reports."""
+    temporaries while its L-BFGS solve runs: six vectors of ``rows`` (the
+    margins, the direction's, the trial point's, the loss derivative and
+    the loops' second copies of what they carry), the curvature history,
+    2 x m vectors of d going into a loop and coming out, and a dozen
+    vectors of d (point, gradient, direction, trial point, the line
+    search's brackets). The feature block itself is an argument and is
+    never copied. Against the compiler's own report (compiled for a
+    described v5e: the growth of the sweep program's temporaries per
+    entity between the two largest entity counts compiled, past what fast
+    memory absorbs; PERF.md PR 36), at d = 16:
+
+    ====================== ============== ===========
+    bucket, iterations     compiler       this
+    ====================== ============== ===========
+    [E, 1, 16], 5          1.9 - 2.6 KB   2.1 KB
+    [E, 1, 16], 10         4.1 - 4.9 KB   3.4 KB
+    [E, 256, 16], 10       9.2 KB         9.5 KB
+    [E, 1024, 16], 10      23.5 KB        27.9 KB
+    [E, 4096, 16], 10      93.7 KB        101.6 KB
+    ====================== ============== ===========
+
+    Until PR 36 the rows were priced ``2 x rows x (d + 5)``, as if the
+    block were carried through the loops: 175 KB and 691 KB an entity at
+    1024 and 4096 rows, seven times the compiler's. The history is still
+    under-read at ten pairs (the two-loop recursion's loops then carry
+    copies of their own); pricing it higher moves the chunk count of
+    ``glmix_ctr.sweeps``' one-row bucket and wants a chip reading."""
     m = max(
         1,
         min(optimizer_config.num_corrections, optimizer_config.max_iterations),
     )
-    return itemsize * (2 * rows * (d + 5) + (4 * m + 12) * d)
+    return itemsize * (6 * rows + (4 * m + 12) * d)
 
 
 def solve_chunk_entities(
@@ -109,13 +141,39 @@ def solve_chunk_entities(
 ) -> int:
     """How many of a ``[entities, rows, d]`` bucket's entities one vmapped
     solve takes at a time: all of them where their temporaries fit
-    ``RE_SOLVE_BYTES``, else the largest whole number of tiles that do."""
+    ``RE_SOLVE_BYTES``, else the largest whole number of tiles that do,
+    and where less than one tile fits, just the entities that do: a chunk
+    is never rounded UP past the budget."""
     fit = RE_SOLVE_BYTES // solve_entity_bytes(
         rows, d, optimizer_config, itemsize
     )
     if fit >= entities:
         return entities
-    return max(_CHUNK_MULTIPLE, fit // _CHUNK_MULTIPLE * _CHUNK_MULTIPLE)
+    if fit < _CHUNK_MULTIPLE:
+        return max(1, fit)
+    return fit // _CHUNK_MULTIPLE * _CHUNK_MULTIPLE
+
+
+def rescore_row_bytes(d: int, itemsize: int = 4) -> int:
+    """Device bytes one kept row holds in temporaries while its bucket is
+    rescored: its entity's d coefficients gathered onto the 128 lanes,
+    and the product with the row's features before its sum, as wide. The
+    compiler reports 1025 B a row at d = 16 (a described v5e: 1.076 GB at
+    2**20 rows, 2.150 GB at 2**21)."""
+    return 2 * itemsize * 128 * -(-d // 128)
+
+
+def rescore_chunk_rows(rows: int, d: int, itemsize: int = 4) -> int:
+    """How many of a bucket's ``rows`` kept rows one step of the rescoring
+    takes: all of them where their temporaries fit ``RE_RESCORE_BYTES``,
+    else the rows split evenly over the fewest chunks that fit, each a
+    whole number of tiles."""
+    fit = RE_RESCORE_BYTES // rescore_row_bytes(d, itemsize)
+    if fit >= rows:
+        return rows
+    chunks = -(-rows // fit)
+    per = -(-rows // chunks)
+    return min(fit, -(-per // _CHUNK_MULTIPLE) * _CHUNK_MULTIPLE)
 
 
 def _make_sweep_jits(body, static_argnums, donate_argnums, name):
@@ -1121,11 +1179,45 @@ class RandomEffectCoordinate(Coordinate):
         exactly the pad rows (static per bucket) and is sliced off.
         """
         with scope("photon.re.rescore"):
-            c = coefs[score_slot].astype(score_feats.dtype)
-            s = jnp.einsum("md,md->m", score_feats, c)
+            s = self._rescore_rows(score_feats, score_slot, coefs)
             out = jnp.zeros((self.num_samples + pad_slots,), dtype=s.dtype)
             out = out.at[score_pos].add(s, unique_indices=True)
             return out[: self.num_samples]
+
+    def _rescore_rows(self, score_feats, score_slot, coefs) -> Array:
+        """[M]: every kept row's features dotted with its entity's
+        coefficients, ``chunk`` rows at a time where the whole bucket's
+        gather would pass ``RE_RESCORE_BYTES``: one loop inside the
+        program, its buffers reused from chunk to chunk. The last chunk
+        is moved back to end on the last row, as the solves' last chunk
+        is: the rows it shares with the chunk before are scored twice, to
+        the same numbers (a row's sum does not depend on the rows beside
+        it). On a mesh the kept rows are already split over the devices
+        and every device scores its share at once."""
+
+        def rows_of(feats, slot):
+            c = coefs[slot].astype(feats.dtype)
+            return jnp.einsum("md,md->m", feats, c)
+
+        m = score_feats.shape[0]
+        chunk = rescore_chunk_rows(
+            m, coefs.shape[1], jnp.dtype(score_feats.dtype).itemsize
+        )
+        if chunk >= m or self.mesh is not None:
+            return rows_of(score_feats, score_slot)
+
+        def one_chunk(i, out):
+            start = jnp.minimum(i * chunk, m - chunk)
+            s = rows_of(
+                jax.lax.dynamic_slice_in_dim(score_feats, start, chunk, 0),
+                jax.lax.dynamic_slice_in_dim(score_slot, start, chunk, 0),
+            )
+            return jax.lax.dynamic_update_slice_in_dim(out, s, start, 0)
+
+        return jax.lax.fori_loop(
+            0, -(-m // chunk), one_chunk,
+            jnp.zeros((m,), score_feats.dtype),
+        )
 
     @partial(jax.jit, static_argnums=(0, 5))
     def _score_flat(

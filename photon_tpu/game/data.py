@@ -4,8 +4,10 @@ TPU-native redesign of the reference's GAME data layer:
 
 - ``GameData`` replaces ``RDD[GameDatum]`` (data/GameDatum.scala:56-58,
   GameConverters.scala:49-131) with a columnar host container: label /
-  offset / weight columns, one CSR matrix per feature shard, and one
-  string id column per entity tag. Sample identity is array position.
+  offset / weight columns, one matrix per feature shard (``CSRMatrix``, or
+  ``DenseMatrix`` where the shard is handed in as its ``[n, d]`` array),
+  and one string id column per entity tag. Sample identity is array
+  position.
 
 - ``RandomEffectDataset`` replaces the reference's
   ``activeData: RDD[(REId, LocalDataSet)]`` + projectors
@@ -55,10 +57,61 @@ class CSRMatrix:
         return self.indices[lo:hi], self.values[lo:hi]
 
     def to_dense(self, dtype=np.float32) -> np.ndarray:
-        out = np.zeros((self.num_rows, self.num_cols), dtype=dtype)
-        rows = np.repeat(np.arange(self.num_rows), np.diff(self.indptr))
+        n, d = self.num_rows, self.num_cols
+        out = np.zeros((n, d), dtype=dtype)
+        if d and len(self.indices) == n * d and bool(
+            np.all(np.diff(self.indptr) == d)
+        ):
+            # every row stores d slots: they go to their columns row by
+            # row, a block of rows at a time, and no [n x d] int64 index is
+            # built (8 bytes a cell: the row index, or the column indices
+            # widened for the scatter)
+            idx, val = self.indices.reshape(n, d), self.values.reshape(n, d)
+            step = max(1, _CANONICAL_CHECK_CHUNK_ELEMS // d)
+            for lo in range(0, n, step):
+                np.put_along_axis(
+                    out[lo : lo + step], idx[lo : lo + step],
+                    val[lo : lo + step].astype(dtype, copy=False), axis=1,
+                )
+            return out
+        rows = np.repeat(np.arange(n), np.diff(self.indptr))
         out[rows, self.indices] = self.values
         return out
+
+    def slice_rows(self, lo: int, hi: int) -> "CSRMatrix":
+        """Rows ``[lo, hi)``, re-based so the slice stands alone."""
+        nz_lo, nz_hi = int(self.indptr[lo]), int(self.indptr[hi])
+        return CSRMatrix(
+            indptr=(self.indptr[lo : hi + 1] - nz_lo).astype(self.indptr.dtype),
+            indices=self.indices[nz_lo:nz_hi],
+            values=self.values[nz_lo:nz_hi],
+            num_cols=self.num_cols,
+        )
+
+    def pad_rows(self, pad: int) -> "CSRMatrix":
+        """``pad`` empty rows appended."""
+        return CSRMatrix(
+            indptr=np.concatenate(
+                [self.indptr, np.full(pad, self.indptr[-1], self.indptr.dtype)]
+            ),
+            indices=self.indices,
+            values=self.values,
+            num_cols=self.num_cols,
+        )
+
+    @staticmethod
+    def concat(mats: Sequence["CSRMatrix"]) -> "CSRMatrix":
+        indptrs = [mats[0].indptr]
+        base = int(mats[0].indptr[-1])
+        for m in mats[1:]:
+            indptrs.append(m.indptr[1:] + base)
+            base += int(m.indptr[-1])
+        return CSRMatrix(
+            indptr=np.concatenate(indptrs),
+            indices=np.concatenate([m.indices for m in mats]),
+            values=np.concatenate([m.values for m in mats]),
+            num_cols=mats[0].num_cols,
+        )
 
     def to_ell(
         self, dtype=np.float32, nnz_pad_multiple: int = 8
@@ -90,13 +143,81 @@ class CSRMatrix:
 
 
 @dataclasses.dataclass
+class DenseMatrix:
+    """A feature shard handed in as its dense ``[n, d]`` array: every row
+    holds every column. The coordinates take it as it is: the fixed effect
+    places ``to_dense()`` (the array itself where the dtype is the
+    array's), the random-effect build gathers its rows by index. Nothing
+    on that path makes the CSR of full rows, which at ``[2**23, 128]``
+    would cost an int32 index and a row index beside the values (PERF.md,
+    PR 36).
+
+    ``indptr`` / ``indices`` / ``values`` are that CSR all the same, made
+    when read, for the consumers that walk stored slots (the host scorers,
+    the request spool, the feature cache's writer): whatever takes a
+    ``CSRMatrix`` takes this, at the CSR's cost."""
+
+    array: np.ndarray  # [n, d]
+
+    def __post_init__(self):
+        self.array = np.asarray(self.array)
+        if self.array.ndim != 2:
+            raise ValueError(
+                f"a dense shard is an [n, d] array, got shape {self.array.shape}"
+            )
+
+    @property
+    def num_rows(self) -> int:
+        return self.array.shape[0]
+
+    @property
+    def num_cols(self) -> int:
+        return self.array.shape[1]
+
+    def row(self, i: int) -> tuple[np.ndarray, np.ndarray]:
+        return np.arange(self.num_cols, dtype=np.int32), self.array[i]
+
+    def to_dense(self, dtype=np.float32) -> np.ndarray:
+        return np.asarray(self.array, dtype=dtype)
+
+    to_ell = CSRMatrix.to_ell  # over the CSR of full rows below
+
+    @property
+    def indptr(self) -> np.ndarray:
+        return np.arange(self.num_rows + 1, dtype=np.int64) * self.num_cols
+
+    @property
+    def indices(self) -> np.ndarray:
+        return np.tile(np.arange(self.num_cols, dtype=np.int32), self.num_rows)
+
+    @property
+    def values(self) -> np.ndarray:
+        return self.array.reshape(-1)
+
+    def slice_rows(self, lo: int, hi: int) -> "DenseMatrix":
+        return DenseMatrix(self.array[lo:hi])
+
+    def pad_rows(self, pad: int) -> "DenseMatrix":
+        """``pad`` rows of zeros appended (a CSR shard appends empty rows:
+        the same numbers)."""
+        return DenseMatrix(np.pad(self.array, [(0, pad), (0, 0)]))
+
+    @staticmethod
+    def concat(mats: Sequence["DenseMatrix"]) -> "DenseMatrix":
+        return DenseMatrix(np.concatenate([m.array for m in mats]))
+
+
+FeatureShard = CSRMatrix | DenseMatrix
+
+
+@dataclasses.dataclass
 class GameData:
     """Columnar GAME dataset: N samples, S feature shards, T id tags."""
 
     labels: np.ndarray
     offsets: np.ndarray
     weights: np.ndarray
-    feature_shards: Mapping[str, CSRMatrix]
+    feature_shards: Mapping[str, FeatureShard]
     id_tags: Mapping[str, np.ndarray]  # tag → [N] array of entity keys
     uids: Sequence[str | None] | None = None  # per-sample ids (score output)
     #: ingest provenance, set by the reader that produced this data (the
@@ -138,19 +259,24 @@ class GameData:
     @staticmethod
     def build(
         labels: np.ndarray,
-        feature_shards: Mapping[str, CSRMatrix],
+        feature_shards: Mapping[str, FeatureShard | np.ndarray],
         *,
         offsets: np.ndarray | None = None,
         weights: np.ndarray | None = None,
         id_tags: Mapping[str, Sequence] | None = None,
         uids: Sequence[str | None] | None = None,
     ) -> "GameData":
+        """A shard handed in as a bare ``[n, d]`` array is a dense shard
+        (:class:`DenseMatrix`): what is handed in decides."""
         n = len(labels)
         return GameData(
             labels=np.asarray(labels, dtype=np.float64),
             offsets=np.zeros(n) if offsets is None else np.asarray(offsets),
             weights=np.ones(n) if weights is None else np.asarray(weights),
-            feature_shards=dict(feature_shards),
+            feature_shards={
+                name: DenseMatrix(m) if isinstance(m, np.ndarray) else m
+                for name, m in feature_shards.items()
+            },
             id_tags={
                 t: np.asarray(v).astype(str)
                 for t, v in (id_tags or {}).items()
@@ -164,15 +290,9 @@ def slice_game_data(data: GameData, lo: int, hi: int) -> GameData:
     slice is self-contained — the unit the streaming scorer consumes)."""
     lo = max(0, int(lo))
     hi = min(data.num_samples, int(hi))
-    shards = {}
-    for name, m in data.feature_shards.items():
-        nz_lo, nz_hi = int(m.indptr[lo]), int(m.indptr[hi])
-        shards[name] = CSRMatrix(
-            indptr=(m.indptr[lo : hi + 1] - nz_lo).astype(m.indptr.dtype),
-            indices=m.indices[nz_lo:nz_hi],
-            values=m.values[nz_lo:nz_hi],
-            num_cols=m.num_cols,
-        )
+    shards = {
+        name: m.slice_rows(lo, hi) for name, m in data.feature_shards.items()
+    }
     return GameData(
         labels=data.labels[lo:hi],
         offsets=data.offsets[lo:hi],
@@ -205,17 +325,10 @@ def concat_game_data(pieces: Sequence[GameData]) -> GameData:
         num_cols = mats[0].num_cols
         if any(m.num_cols != num_cols for m in mats):
             raise ValueError(f"shard {name} width differs across pieces")
-        indptrs = [mats[0].indptr]
-        base = int(mats[0].indptr[-1])
-        for m in mats[1:]:
-            indptrs.append(m.indptr[1:] + base)
-            base += int(m.indptr[-1])
-        shards[name] = CSRMatrix(
-            indptr=np.concatenate(indptrs),
-            indices=np.concatenate([m.indices for m in mats]),
-            values=np.concatenate([m.values for m in mats]),
-            num_cols=num_cols,
-        )
+        if all(isinstance(m, DenseMatrix) for m in mats):
+            shards[name] = DenseMatrix.concat(mats)
+        else:  # a dense piece among CSR ones joins them as its CSR
+            shards[name] = CSRMatrix.concat(mats)
     uids = None
     if first.uids is not None:
         uids = [u for p in pieces for u in p.uids]
@@ -256,17 +369,7 @@ def pad_game_data(data: GameData, multiple: int) -> GameData:
     if target == n:
         return data
     pad = target - n
-    shards = {}
-    for name, m in data.feature_shards.items():
-        indptr = np.concatenate(
-            [m.indptr, np.full(pad, m.indptr[-1], dtype=m.indptr.dtype)]
-        )
-        shards[name] = CSRMatrix(
-            indptr=indptr,
-            indices=m.indices,
-            values=m.values,
-            num_cols=m.num_cols,
-        )
+    shards = {name: m.pad_rows(pad) for name, m in data.feature_shards.items()}
     id_tags = {
         tag: np.concatenate(
             [np.asarray(col).astype(str), np.full(pad, PAD_ENTITY_KEY)]
@@ -660,6 +763,23 @@ def _rows_are_canonical(
     return True
 
 
+def _rows_are_full(shard) -> bool:
+    """Whether the block fills can gather rows straight from an ``[n, d]``
+    value matrix: a dense shard, or a CSR shard whose every row stores all
+    columns. Full rows alone are not enough there: reshaping the values
+    assumes STORAGE order == column order, and readers may emit full rows
+    with unsorted indices (the intercept appended last), so the per-row
+    index pattern is verified too. ``PHOTON_RE_DENSE_FAST=0`` sends every
+    shard through the per-nonzero machinery (the A/B lever)."""
+    if shard.num_cols <= 0 or os.environ.get("PHOTON_RE_DENSE_FAST", "1") == "0":
+        return False
+    if isinstance(shard, DenseMatrix):
+        return True
+    return bool(
+        np.all((shard.indptr[1:] - shard.indptr[:-1]) == shard.num_cols)
+    ) and _rows_are_canonical(shard.indices, shard.num_rows, shard.num_cols)
+
+
 def _consolidate_shapes(
     keys: np.ndarray,
     counts: np.ndarray,
@@ -850,15 +970,7 @@ def profile_random_effect_shapes(
     shard = data.feature_shards[config.feature_shard]
     if config.projector_type == ProjectorType.RANDOM:
         d_proj = config.random_projection_dim or 64
-    elif (
-        config.features_to_samples_ratio is None
-        and shard.num_cols > 0
-        and os.environ.get("PHOTON_RE_DENSE_FAST", "1") != "0"
-        and bool(
-            np.all((shard.indptr[1:] - shard.indptr[:-1]) == shard.num_cols)
-        )
-        and _rows_are_canonical(shard.indices, shard.num_rows, shard.num_cols)
-    ):
+    elif config.features_to_samples_ratio is None and _rows_are_full(shard):
         d_proj = shard.num_cols
     else:
         return None
@@ -986,22 +1098,10 @@ def build_random_effect_dataset(
     fast_dense = (
         rnd_proj is None
         and config.features_to_samples_ratio is None
-        and shard.num_cols > 0
-        and os.environ.get("PHOTON_RE_DENSE_FAST", "1") != "0"
-        and bool(
-            np.all(
-                (shard.indptr[1:] - shard.indptr[:-1]) == shard.num_cols
-            )
-        )
-        # full rows alone are not enough: values.reshape assumes STORAGE
-        # order == column order, and readers may emit full rows with
-        # unsorted indices (e.g. intercept appended last) — verify the
-        # per-row index pattern is exactly 0..d-1, in bounded row chunks
-        and _rows_are_canonical(
-            shard.indices, shard.num_rows, shard.num_cols
-        )
+        and _rows_are_full(shard)
     )
     if fast_dense:
+        # a dense shard's own array, or a full-row CSR's values seen as one
         x2d = np.ascontiguousarray(
             shard.values.reshape(shard.num_rows, shard.num_cols),
             dtype=np.float32,

@@ -421,6 +421,16 @@ class GameEstimator:
                         seed=self.seed,
                         mesh=self.mesh,
                     )
+                    batch = coords[cid].batch
+                    block = (
+                        batch.features
+                        if hasattr(batch, "features")
+                        else batch.values
+                    )
+                    place.set(
+                        layout=type(batch).__name__,
+                        block_bytes=int(block.nbytes),
+                    )
                 seconds[cid] = {"place": place.duration_s}
             elif isinstance(cfg, RandomEffectCoordinateConfig):
                 entity_shards = 1

@@ -490,6 +490,52 @@ def test_cell_forward_pass_has_no_second_level(cell_passes):
     assert f"f32[{block},128]" in text
 
 
+def _shapes_only_re_sweep(one_chip, entities, rows, d, kept_rows, n, optimizer_config):
+    """The fused RE sweep over ONE bucket ``[entities, rows, d]`` with
+    ``kept_rows`` flat score rows, compiled from shapes alone: no data is
+    built. -> (the problem configuration, the compiled program)."""
+    opt = GLMProblemConfig(
+        task=TaskType.LOGISTIC_REGRESSION,
+        regularization=RegularizationContext(RegularizationType.L2),
+        optimizer_config=optimizer_config,
+    )
+    coord = RandomEffectCoordinate(
+        config=RandomEffectCoordinateConfig(
+            random_effect_type="e", feature_shard="e", optimization=opt,
+            regularization_weights=(1.0,), active_data_upper_bound=rows,
+        ),
+        dataset=None, device_buckets=[],
+        problem_config=opt.with_regularization_weight(1.0),
+        num_samples=n, dtype=jnp.float32,
+    )
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    block = (
+        sds((entities, rows, d)), sds((entities, rows)), sds((entities, rows)),
+        sds((entities, rows)), sds((entities, rows), jnp.int32),
+    )
+    flat = (sds((kept_rows, d)), sds((kept_rows,), jnp.int32),
+            sds((kept_rows,), jnp.int32))
+    compiled = (
+        type(coord)
+        ._active_sweep_jit(True)
+        .lower(
+            coord, (block,), (flat,), sds((n,)), sds((n,)),
+            [sds((entities, d))], (0,), sds(()),
+        )
+        .compile()
+    )
+    return opt, compiled
+
+
+#: the row-heavy cell's random-effect solver (glmix_movielens.json)
+ROW_HEAVY = OptimizerConfig(
+    max_iterations=10, ls_max_iterations=8, tolerance=1e-7, num_corrections=10
+)
+
+
 def test_cell_single_row_bucket_sweep_fits_its_budget(one_chip):
     """The per-user coordinate's sweep over the ``glmix_ctr.sweeps`` cell's
     one-row bucket, [1 997 496, 1, 16] (the bucket the cell's structure seed
@@ -506,50 +552,69 @@ def test_cell_single_row_bucket_sweep_fits_its_budget(one_chip):
     with open(os.path.join(root, "benchmarks", "configs", "glmix_ctr.json")) as f:
         cell = json.load(f)
     entities, rows, d = 1_997_496, 1, cell["random_effects"]["per_user"]["d"]
-    n = cell["features"]["n"]
-    opt = GLMProblemConfig(
-        task=TaskType.LOGISTIC_REGRESSION,
-        regularization=RegularizationContext(RegularizationType.L2),
-        optimizer_config=OptimizerConfig(
+    opt, compiled = _shapes_only_re_sweep(
+        one_chip, entities, rows, d, entities, cell["features"]["n"],
+        OptimizerConfig(
             max_iterations=cell["solver"]["re_max_iterations"],
             ls_max_iterations=cell["solver"]["re_ls_max_iterations"],
         ),
-    )
-    coord = RandomEffectCoordinate(
-        config=RandomEffectCoordinateConfig(
-            random_effect_type="per_user", feature_shard="per_user",
-            optimization=opt, regularization_weights=(1.0,),
-            active_data_upper_bound=cell["random_effects"]["per_user"]["cap"],
-        ),
-        dataset=None, device_buckets=[],
-        problem_config=opt.with_regularization_weight(1.0),
-        num_samples=n, dtype=jnp.float32,
     )
     chunk = coordinate_mod.solve_chunk_entities(
         entities, rows, d, opt.optimizer_config
     )
     assert chunk % 1024 == 0 and 4 <= -(-entities // chunk) <= 32
-
-    def sds(shape, dtype=jnp.float32):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-
-    block = (
-        sds((entities, rows, d)), sds((entities, rows)), sds((entities, rows)),
-        sds((entities, rows)), sds((entities, rows), jnp.int32),
-    )
-    flat = (sds((entities, d)), sds((entities,), jnp.int32), sds((entities,), jnp.int32))
-    compiled = (
-        type(coord)
-        ._active_sweep_jit(True)
-        .lower(
-            coord, (block,), (flat,), sds((n,)), sds((n,)),
-            [sds((entities, d))], (0,), sds(()),
-        )
-        .compile()
-    )
     m = _fits(compiled)
     assert m.temp_size_in_bytes < 2 * (1 << 30), m.temp_size_in_bytes
     text = compiled.as_text()
     # the chunk loop sits under the bucket's scope, the solver under it
     assert "photon.re.solve/while/body/" in text
     assert "photon.re.chunk/vmap()/while/body/photon.lbfgs.linesearch" in text
+
+
+@pytest.mark.parametrize("rows,fewer,more", [(1024, 8192, 16384), (4096, 2048, 4096)])
+def test_row_heavy_solve_temporaries_against_solve_entity_bytes(
+    one_chip, monkeypatch, rows, fewer, more
+):
+    """``solve_entity_bytes`` read against the compiler's own report for
+    [E, 1024, 16] and [E, 4096, 16] (PR 36): what an entity adds to the
+    sweep program's temporaries between two entity counts past what fast
+    memory absorbs (23.5 KB and 93.7 KB when written) is under the price
+    and over half of it. Until PR 36 the price was seven times the
+    report: the block was priced as if carried through the loops."""
+    from photon_tpu.game import coordinate as coordinate_mod
+
+    # no chunk loop: the whole bucket's temporaries are what is read
+    monkeypatch.setattr(coordinate_mod, "RE_SOLVE_BYTES", 1 << 40)
+    n = 1 << 23
+    temps = []
+    for entities in (fewer, more):
+        opt, compiled = _shapes_only_re_sweep(
+            one_chip, entities, rows, 16, 1024, n, ROW_HEAVY
+        )
+        temps.append(_fits(compiled).temp_size_in_bytes)
+    grown = (temps[1] - temps[0]) / (more - fewer)
+    priced = coordinate_mod.solve_entity_bytes(rows, 16, opt.optimizer_config)
+    assert 0.5 * priced < grown <= priced, (grown, priced)
+
+
+def test_cell_row_heavy_bucket_rescoring_runs_in_row_chunks(one_chip):
+    """The per-movie coordinate's capped bucket of ``glmix_movielens.sweeps``,
+    [751, 4096, 16] with 5 255 525 kept rows (the bucket the cell's
+    structure seed gives): its rescoring passes ``RE_RESCORE_BYTES`` whole
+    (5.4 GB of lane-padded gather) and runs as a loop over two row chunks
+    under ``photon.re.rescore``; the solve needs no chunk loop. Shapes
+    only: no data is built."""
+    from photon_tpu.game import coordinate as coordinate_mod
+
+    kept, d = 5_255_525, 16
+    chunk = coordinate_mod.rescore_chunk_rows(kept, d)
+    assert chunk % 1024 == 0 and -(-kept // chunk) == 2
+    opt, compiled = _shapes_only_re_sweep(
+        one_chip, 751, 4096, d, kept, 1 << 23, ROW_HEAVY
+    )
+    assert coordinate_mod.solve_chunk_entities(751, 4096, d, opt.optimizer_config) == 751
+    m = _fits(compiled)
+    assert m.temp_size_in_bytes < chunk * coordinate_mod.rescore_row_bytes(d) * 1.1
+    text = compiled.as_text()
+    assert "photon.re.rescore/while/body/" in text
+    assert "photon.re.chunk" not in text
