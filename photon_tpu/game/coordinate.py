@@ -13,8 +13,9 @@ TPU design:
   per-iteration treeAggregate + broadcast loop disappears).
 - A RandomEffectCoordinate keeps size-bucketed padded entity blocks; training
   is one vmapped solve per bucket (thousands of independent L-BFGS in one
-  SPMD program — the reference's per-entity JVM loops); scoring is an einsum
-  + scatter-add on sample positions (the reference's RDD join).
+  SPMD program — the reference's per-entity JVM loops); scoring is one
+  einsum over the kept rows laid out in sample order at placement (the
+  reference's RDD join, done once by the build).
 """
 from __future__ import annotations
 
@@ -783,13 +784,68 @@ class _DeviceBucket:
     train_weights: Array  # data weights of active rows (0 on padding)
     sample_pos: Array  # [E, n_act] int32, ≥ num_samples ⇒ padding (gather
     #   clamps to the residual's zero sentinel — never scattered)
-    score_feats: Array  # [M, d] ALL kept rows, padding-free (flat)
-    score_slot: Array  # [M] entity slot into this bucket's coefficients
-    score_pos: Array  # [M] global sample position (≥ num_samples ⇒ pad,
-    #   renumbered unique so the scatter can promise unique_indices)
-    score_pad_slots: int  # appended flat pad rows (static, build time)
+    score_feats: Array  # the kept rows its scores are made from: the
+    #   ``_ScoreBlock.feats`` of its width, shared by every bucket of it
     entity_ids: np.ndarray
     col_index: np.ndarray
+
+
+@dataclasses.dataclass(eq=False)
+class _ScoreBlock:
+    """The kept rows (active AND passive) of every bucket of one width,
+    merged at placement into ascending sample position: what a sweep
+    dots with the entities' coefficients is already where its sums go.
+
+    ``pos`` is ``None`` where the block IS sample order: row i is sample
+    i, and a sample no bucket keeps is a zero row. Otherwise the block
+    holds only the samples its buckets keep and ``pos`` their ascending,
+    distinct positions (those past ``num_samples`` are the zero rows that
+    pad the block to the mesh: the scatter drops them)."""
+
+    feats: Array  # [M, d]
+    slot: Array  # [M] row of the concatenated tables of ``buckets``; the
+    #   row past their end is the zero row the program appends
+    pos: Array | None  # [M] ascending sample positions
+    buckets: tuple  # whose coefficient tables the slots count through
+
+
+def _merge_score_rows(host_buckets, table_rows, n: int, row_multiple: int):
+    """The host buckets' flat score rows as one block per bucket WIDTH in
+    ascending sample position: yields (feats, slot, pos | None, members).
+
+    ``table_rows[i]`` is the height of bucket i's placed coefficient
+    table; a row's slot counts through the tables of its width's buckets
+    laid end to end, and the row after the last table is a zero row: a
+    sample that no bucket keeps points there with zero features, so a
+    diverged entity's Inf never meets its 0. A kept sample lies in one
+    bucket, so the rows are written to their positions and nothing is
+    sorted. Where every bucket has the same width the block is the whole
+    of sample order (``pos`` None, M = n up to the mesh's padding); with
+    several widths each block keeps the samples it covers alone."""
+    widths: dict[int, list[int]] = {}
+    for i, b in enumerate(host_buckets):
+        widths.setdefault(b.score_feats.shape[1], []).append(i)
+    for d, members in widths.items():
+        zero_row = sum(table_rows[i] for i in members)
+        feats = np.zeros((n, d), host_buckets[members[0]].score_feats.dtype)
+        slot = np.full(n, zero_row, np.int32)
+        base = 0
+        for i in members:
+            b = host_buckets[i]
+            feats[b.score_pos] = b.score_feats
+            slot[b.score_pos] = b.score_slot + base
+            base += table_rows[i]
+        pos = None
+        if len(widths) > 1:
+            pos = np.flatnonzero(slot != zero_row).astype(np.int32)
+            feats, slot = feats[pos], slot[pos]
+        pad = -len(slot) % row_multiple
+        if pad:
+            feats = np.pad(feats, [(0, pad), (0, 0)])
+            slot = np.pad(slot, (0, pad), constant_values=zero_row)
+            if pos is not None:
+                pos = np.concatenate([pos, n + np.arange(pad, dtype=np.int32)])
+        yield feats, slot, pos, tuple(members)
 
 
 @dataclasses.dataclass(eq=False)
@@ -804,6 +860,9 @@ class RandomEffectCoordinate(Coordinate):
     #: training then runs as shard_map with per-shard independent
     #: while-loops (zero collectives; see _train_bucket)
     mesh: object = None
+    #: the kept rows merged per bucket width into sample order at
+    #: placement (``_merge_score_rows``): what the rescoring reads
+    score_blocks: list = dataclasses.field(default_factory=list)
 
     @staticmethod
     def build(
@@ -836,60 +895,62 @@ class RandomEffectCoordinate(Coordinate):
                 return jax.device_put(x, NamedSharding(mesh, p))
 
         n_total = dataset.num_samples
+        # placement wrapped against a transient UNAVAILABLE: one flaky
+        # put must not kill a multi-minute coordinate build. The fault
+        # point sits INSIDE the retried thunk, so an injected UNAVAILABLE
+        # exercises the real retry path (util/faults.py; each retry
+        # re-counts the occurrence)
+        from photon_tpu.util import faults
+        from photon_tpu.util.device_retry import put_with_retry
+
+        # The flat score rows first: every bucket holds its width's block.
+        # They are padded to divide the WHOLE mesh (they shard over every
+        # device like the fixed-effect batch); the entity axis of a table
+        # is padded below, so a slot counts through the PLACED heights.
+        e_pads = [
+            0
+            if entity_shards == 1
+            else pad_rows_to_multiple(b.num_entities, entity_shards)
+            - b.num_entities
+            for b in dataset.buckets
+        ]
+        score_blocks = [
+            put_with_retry(
+                lambda feats=feats, slot=slot, pos=pos, members=members: (
+                    _ScoreBlock(
+                        feats=put_rows(jnp.asarray(feats, dtype=dtype)),
+                        slot=put_rows(jnp.asarray(slot)),
+                        pos=None
+                        if pos is None
+                        else put_rows(jnp.asarray(pos)),
+                        buckets=members,
+                    )
+                )
+            )
+            for feats, slot, pos, members in _merge_score_rows(
+                dataset.buckets,
+                [b.num_entities + p for b, p in zip(dataset.buckets, e_pads)],
+                n_total,
+                mesh_devices,
+            )
+        ]
+        block_of = {
+            i: blk for blk in score_blocks for i in blk.buckets
+        }
         device_buckets = []
-        for b in dataset.buckets:
+        for i, (b, e_pad) in enumerate(zip(dataset.buckets, e_pads)):
             # Pad the entity axis so it divides the mesh's entity dimension;
             # padded lanes carry zero weights and the OOB sample slot, so
-            # they train to zero instantly. The flat score rows are padded
-            # to divide the WHOLE mesh (they shard over every device like
-            # the fixed-effect batch): pad rows carry zero features and
-            # DISTINCT positions past num_samples, keeping the scatter's
-            # unique_indices promise (colliding scatters serialize on TPU).
-            e = b.num_entities
-            e_pad = (
-                0
-                if entity_shards == 1
-                else pad_rows_to_multiple(e, entity_shards) - e
-            )
-
-            def pad_e(x, fill=0):
+            # they train to zero instantly.
+            def pad_e(x, fill=0, e_pad=e_pad):
                 if e_pad == 0:
                     return x
                 widths = [(0, e_pad)] + [(0, 0)] * (x.ndim - 1)
                 return np.pad(x, widths, constant_values=fill)
 
-            m = len(b.score_pos)
-            m_pad = (
-                0
-                if mesh_devices == 1
-                else pad_rows_to_multiple(max(m, 1), mesh_devices) - m
-            )
-            score_pos = np.concatenate(
-                [
-                    np.asarray(b.score_pos, np.int32),
-                    n_total + np.arange(m_pad, dtype=np.int32),
-                ]
-            )
-            score_slot = np.concatenate(
-                [np.asarray(b.score_slot, np.int32), np.zeros(m_pad, np.int32)]
-            )
-            score_feats = (
-                b.score_feats
-                if m_pad == 0
-                else np.pad(b.score_feats, [(0, m_pad), (0, 0)])
-            )
-            # placement wrapped against a transient UNAVAILABLE: one
-            # flaky put must not kill a multi-minute coordinate build.
-            # The fault point sits INSIDE the retried thunk, so an
-            # injected UNAVAILABLE exercises the real retry path
-            # (util/faults.py; each retry re-counts the occurrence)
-            from photon_tpu.util import faults
-            from photon_tpu.util.device_retry import put_with_retry
-
             device_buckets.append(
                 put_with_retry(
-                    lambda b=b, pad_e=pad_e, score_feats=score_feats,
-                    score_slot=score_slot, score_pos=score_pos, m_pad=m_pad: (
+                    lambda b=b, pad_e=pad_e, i=i: (
                         faults.fault_point("coordinate.placement"),
                         _DeviceBucket(
                             features=put_entities(
@@ -912,12 +973,7 @@ class RandomEffectCoordinate(Coordinate):
                                     pad_e(b.sample_pos, fill=n_total)
                                 )
                             ),
-                            score_feats=put_rows(
-                                jnp.asarray(score_feats, dtype=dtype)
-                            ),
-                            score_slot=put_rows(jnp.asarray(score_slot)),
-                            score_pos=put_rows(jnp.asarray(score_pos)),
-                            score_pad_slots=int(m_pad),
+                            score_feats=block_of[i].feats,
                             entity_ids=b.entity_ids,
                             col_index=b.col_index,
                         ),
@@ -925,16 +981,19 @@ class RandomEffectCoordinate(Coordinate):
                 )
             )
         # placement choke point: every bucket's device-resident blocks
+        # and the coordinate's score blocks
         obs_memory.count_h2d(
-            sum(
-                obs_memory.tree_device_bytes(
-                    (
-                        db.features, db.labels, db.offsets,
-                        db.train_weights, db.sample_pos, db.score_feats,
-                        db.score_slot, db.score_pos,
-                    )
+            obs_memory.tree_device_bytes(
+                (
+                    [
+                        (
+                            db.features, db.labels, db.offsets,
+                            db.train_weights, db.sample_pos,
+                        )
+                        for db in device_buckets
+                    ],
+                    [(blk.feats, blk.slot, blk.pos) for blk in score_blocks],
                 )
-                for db in device_buckets
             )
         )
         return RandomEffectCoordinate(
@@ -947,7 +1006,18 @@ class RandomEffectCoordinate(Coordinate):
             num_samples=dataset.num_samples,
             dtype=dtype,
             mesh=mesh,
+            score_blocks=score_blocks,
         )
+
+    @property
+    def score_layout(self) -> str:
+        """How the new scores reach sample order, decided by the build
+        from the buckets' widths: ``sample_order`` (one width: the block
+        is sample order and its sums are the score) or ``sorted_scatter``
+        (a block a width, each added at its ascending positions)."""
+        if all(blk.pos is None for blk in self.score_blocks):
+            return "sample_order"
+        return "sorted_scatter"
 
     def with_regularization_weight(self, w: float) -> "RandomEffectCoordinate":
         """In-place λ reweight — see FixedEffectCoordinate: keeps the per-
@@ -1028,7 +1098,8 @@ class RandomEffectCoordinate(Coordinate):
         # a gather of the replicated residual by shard-varying sample
         # positions plus an elementwise add partitions fine under plain
         # GSPMD, so it stays where the compiler's own checks apply.
-        extra = res_pad[jnp.minimum(sample_pos, n_res)]
+        with scope("photon.re.fetch"):
+            extra = res_pad[jnp.minimum(sample_pos, n_res)]
         offsets_eff = offsets + extra
 
         def solve_all(features, labels, offsets_eff, train_weights, w0,
@@ -1162,31 +1233,54 @@ class RandomEffectCoordinate(Coordinate):
             self._train_args(), residual_scores, state, reg_w
         )
 
-    def _score_bucket_body(
-        self, score_feats, score_slot, score_pos, coefs, pad_slots
-    ) -> Array:
-        """Flat padding-free scoring: one compacted feature row per kept
-        sample (active AND passive), dotted with its entity's coefficient
-        row, scattered to its position. Replaces the padded-block einsum —
-        at CTR skew the blocks carried up to 2× the data in padding
-        (VERDICT r4 weak #2); the flat layout scores exactly the samples
-        that exist. Weight-0 rows were zeroed at build, so no mask here.
+    def _score_blocks_body(self, score_args, state, plan) -> Array:
+        """[n]: the coordinate's score at ``state``, one einsum a score
+        block: each kept row (active AND passive) dotted with its entity's
+        row of its width's tables laid end to end, a zero row after them
+        for the samples no bucket keeps. The build put the rows in sample
+        order, so where the buckets share one width the sums ARE the
+        score: nothing is zero-filled, sorted, scattered or added. With
+        several widths each block's sums are added at its own ascending,
+        distinct positions (a scatter the compiler need not sort; it
+        drops the positions past ``num_samples``, the block's padding to
+        the mesh). A kept sample lies in one block and ``x + 0`` is exact:
+        every score is the per-bucket scatter's, bit for bit. Weight-0
+        rows were zeroed at build, so no mask here."""
+        total = None
+        for (feats, slot, *pos), members in zip(score_args, plan):
+            with scope("photon.re.rescore"):
+                tables = [state[i] for i in members]
+                if self.mesh is not None:
+                    # every device's rows may name any entity: the tables
+                    # are gathered whole, each by its own all-gather, and
+                    # laid end to end on the device (left to GSPMD, the
+                    # concatenation of entity-sharded tables is a chain
+                    # of all-to-alls)
+                    from jax.sharding import NamedSharding, PartitionSpec as P
 
-        Every kept sample appears exactly once per coordinate and flat pad
-        rows were renumbered past num_samples at placement, so the scatter
-        promises unique_indices — XLA:TPU's colliding-scatter lowering
-        serializes, the unique path does not. The overflow tail holds
-        exactly the pad rows (static per bucket) and is sliced off.
-        """
-        with scope("photon.re.rescore"):
-            s = self._rescore_rows(score_feats, score_slot, coefs)
-            out = jnp.zeros((self.num_samples + pad_slots,), dtype=s.dtype)
-            out = out.at[score_pos].add(s, unique_indices=True)
-            return out[: self.num_samples]
+                    whole = NamedSharding(self.mesh, P())
+                    tables = [
+                        jax.lax.with_sharding_constraint(t, whole)
+                        for t in tables
+                    ]
+                table = jnp.concatenate(
+                    tables + [jnp.zeros((1, feats.shape[1]), tables[0].dtype)]
+                )
+                s = self._rescore_rows(feats, slot, table)
+                if pos:
+                    part = jnp.zeros((self.num_samples,), s.dtype).at[
+                        pos[0]
+                    ].add(s, indices_are_sorted=True, unique_indices=True)
+                else:
+                    part = s[: self.num_samples]
+            total = part if total is None else total + part
+        if total is None:  # a coordinate without a bucket
+            return jnp.zeros((self.num_samples,), dtype=self.dtype)
+        return total
 
     def _rescore_rows(self, score_feats, score_slot, coefs) -> Array:
-        """[M]: every kept row's features dotted with its entity's
-        coefficients, ``chunk`` rows at a time where the whole bucket's
+        """[M]: every row of a score block dotted with its entity's
+        coefficients, ``chunk`` rows at a time where the whole block's
         gather would pass ``RE_RESCORE_BYTES``: one loop inside the
         program, its buffers reused from chunk to chunk. The last chunk
         is moved back to end on the last row, as the solves' last chunk
@@ -1219,37 +1313,30 @@ class RandomEffectCoordinate(Coordinate):
             jnp.zeros((m,), score_feats.dtype),
         )
 
-    @partial(jax.jit, static_argnums=(0, 5))
-    def _score_flat(
-        self, score_feats, score_slot, score_pos, coefs, pad_slots
-    ) -> Array:
-        return self._score_bucket_body(
-            score_feats, score_slot, score_pos, coefs, pad_slots
-        )
-
     def _score_args(self) -> tuple:
         return tuple(
-            (db.score_feats, db.score_slot, db.score_pos)
-            for db in self.device_buckets
+            (blk.feats, blk.slot) + (() if blk.pos is None else (blk.pos,))
+            for blk in self.score_blocks
         )
 
-    def _pad_slots(self) -> tuple:
-        return tuple(db.score_pad_slots for db in self.device_buckets)
+    def _score_plan(self) -> tuple:
+        """What of the score blocks a program is specialised on: per block
+        the buckets whose tables its slots count through."""
+        return tuple(blk.buckets for blk in self.score_blocks)
 
     @partial(jax.jit, static_argnums=(0, 3))
-    def _score_all_jit(self, score_args, state, pad_slots) -> Array:
+    def _score_all_jit(self, score_args, state, plan) -> Array:
         TRACE_COUNTERS["re_score_all"] += 1
         from photon_tpu.parallel.mesh import constrain_rows
 
-        total = jnp.zeros((self.num_samples,), dtype=self.dtype)
-        for (sf, ss, sp), coefs, pad in zip(score_args, state, pad_slots):
-            total = total + self._score_bucket_body(sf, ss, sp, coefs, pad)
-        # pin the [N] result to the row sharding: left to GSPMD the
+        # pin the [N] result to the row sharding: left to GSPMD a
         # scatter-built total compiles REPLICATED (every device holds the
         # full [N] — the SPMD auditor's partitioned-results check caught
         # exactly this), which at north-star N is an O(N) per-device
         # footprint for a vector the mesh should split
-        return constrain_rows(total, self.mesh)
+        return constrain_rows(
+            self._score_blocks_body(score_args, state, plan), self.mesh
+        )
 
     def score(self, state: list[Array]) -> Array:
         dispatch_count.record(1)
@@ -1257,15 +1344,15 @@ class RandomEffectCoordinate(Coordinate):
         if out is not None:
             return out
         return self._score_all_jit(
-            self._score_args(), state, self._pad_slots()
+            self._score_args(), state, self._score_plan()
         )
 
     def _sweep_body(
-        self, bucket_args, score_args, total, score, state, pad_slots,
+        self, bucket_args, score_args, total, score, state, plan,
         reg_weight,
     ):
         """Whole CD step for ALL buckets as ONE program: residual, every
-        bucket's vmapped solve, every bucket's scatter-score, total update.
+        bucket's vmapped solve, the rescoring in sample order, total update.
         Compiled as ``_sweep_jit`` (total/score/state DONATED — the [N]
         temporaries and each bucket's coefficient block reuse their input
         buffers) and ``_sweep_jit_nodonate`` (XLA:CPU — see
@@ -1285,16 +1372,12 @@ class RandomEffectCoordinate(Coordinate):
         ]
         new_state = [r.x for r in infos]
         with scope("photon.descent.rescore"):
-            new_score = jnp.zeros((self.num_samples,), dtype=self.dtype)
-            for (sf, ss, sp), coefs, pad in zip(
-                score_args, new_state, pad_slots
-            ):
-                new_score = new_score + self._score_bucket_body(
-                    sf, ss, sp, coefs, pad
-                )
             # same row-sharding pin as _score_all_jit: GSPMD otherwise
-            # replicates the scatter-built [N] outputs across the mesh
-            new_score = constrain_rows(new_score, self.mesh)
+            # replicates scatter-built [N] outputs across the mesh
+            new_score = constrain_rows(
+                self._score_blocks_body(score_args, new_state, plan),
+                self.mesh,
+            )
             new_total = constrain_rows(residual + new_score, self.mesh)
         # health fold only off-mesh: reducing entity-SHARDED per-bucket
         # values/gradients to replicated scalars would put an all-reduce
@@ -1346,14 +1429,14 @@ class RandomEffectCoordinate(Coordinate):
             row,
             row,
             self._state_sds_list(),
-            self._pad_slots(),
+            self._score_plan(),
             self._scalar_sds(),
         )
 
     def _score_lowered(self):
         return type(self)._score_all_jit.lower(
             self, self._score_args(), self._state_sds_list(),
-            self._pad_slots(),
+            self._score_plan(),
         )
 
     def spmd_contract(self):
@@ -1378,13 +1461,19 @@ class RandomEffectCoordinate(Coordinate):
             return spmd.SpmdContract()
         itemsize = max(int(jnp.dtype(self.dtype).itemsize), 4)
         rows = self.num_samples + self.mesh.size + 64
-        per_bucket = max(
+        tables = [
+            int(db.features.shape[0]) * int(db.features.shape[2])
+            for db in self.device_buckets
+        ]
+        per_block = max(
             (
                 max(
-                    int(db.features.shape[0]) * int(db.features.shape[2]),
-                    int(db.score_pos.shape[0]),
+                    # a width's tables laid end to end, and their zero row
+                    sum(tables[i] for i in blk.buckets)
+                    + int(blk.feats.shape[1]),
+                    int(blk.slot.shape[0]),
                 )
-                for db in self.device_buckets
+                for blk in self.score_blocks
             ),
             default=1,
         )
@@ -1393,11 +1482,12 @@ class RandomEffectCoordinate(Coordinate):
                 "all-reduce", "all-gather", "reduce-scatter",
                 "collective-permute",
             ),
-            max_bytes_per_site=max(rows, per_bucket + 64) * itemsize,
+            max_bytes_per_site=max(rows, per_block + 64) * itemsize,
             reason=(
-                "RE score fold: per-bucket table/position gathers and "
-                "one [n]-row reduce per site (solves themselves are "
-                "collective-free, pinned on the train program)"
+                "RE score fold: one width's tables and its block's "
+                "positions gathered, one [n]-row reduce per site (solves "
+                "themselves are collective-free, pinned on the train "
+                "program)"
             ),
         )
         return spmd.SpmdContract(
@@ -1431,7 +1521,7 @@ class RandomEffectCoordinate(Coordinate):
             total,
             score,
             state,
-            self._pad_slots(),
+            self._score_plan(),
             reg_w,
         )
 

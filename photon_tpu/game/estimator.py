@@ -471,6 +471,10 @@ class GameEstimator:
                         coords[cid] = RandomEffectCoordinate.build(
                             data, ds, cfg, self.dtype, mesh=self.mesh
                         )
+                        place.set(
+                            score_layout=coords[cid].score_layout,
+                            width_groups=len(coords[cid].score_blocks),
+                        )
                     seconds[cid]["place"] = place.duration_s
                 waste = ds.padding_waste()
                 logger.info(

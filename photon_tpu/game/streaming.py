@@ -37,7 +37,8 @@ tolerance:
   are IEEE-identical to the device's versions of the same ops;
 - the host score scatter writes each kept sample exactly once per
   bucket (the build renumbers flat pad rows past ``num_samples``), so
-  ``out[pos] += s`` equals the device's ``unique_indices`` scatter-add.
+  ``out[pos] += s`` equals the device's score (each sample's one sum,
+  laid in sample order by the materialized coordinate's build).
 
 What streaming mode does NOT cover (validated loudly at fit entry, not
 discovered mid-sweep): trainable fixed-effect coordinates (the global
@@ -836,9 +837,10 @@ class StreamingRandomEffectCoordinate(RandomEffectCoordinate):
     def _score_chunk_body(self, score_feats, coef_rows):
         """One flat score-row chunk: feature rows dotted with their
         HOST-gathered coefficient rows — the ``einsum`` of
-        ``_score_bucket_body`` with the slot gather and position scatter
-        moved to host (gather: same values; scatter: unique positions,
-        so the host fancy ``+=`` equals the device scatter-add)."""
+        ``RandomEffectCoordinate._rescore_rows`` with the slot gather and
+        the way to sample order moved to host (gather: same values;
+        positions: unique, so the host fancy ``+=`` adds each sample's one
+        sum to zero, as the materialized coordinate's merged block does)."""
         TRACE_COUNTERS["stream_re_score"] += 1
         c = coef_rows.astype(score_feats.dtype)
         return jnp.einsum("md,md->m", score_feats, c)

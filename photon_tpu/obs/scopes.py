@@ -43,7 +43,8 @@ SCOPES: dict[str, tuple[str, str]] = {
     # --- GAME programs (game/) --------------------------------------------
     "photon.re.solve": ("random-effect programs", "one size bucket's vmapped per-entity solves"),
     "photon.re.chunk": ("random-effect programs", "one chunk of a bucket too large to solve at once: the solver's loops over that chunk's entities"),
-    "photon.re.rescore": ("random-effect programs", "one bucket's flat scoring scattered back to rows"),
+    "photon.re.fetch": ("random-effect programs", "a bucket's residual offsets fetched from sample order by its rows' positions"),
+    "photon.re.rescore": ("random-effect programs", "one width's kept rows, merged into sample order at placement, dotted with their entities' coefficients"),
     "photon.descent.residual": ("descent loop", "a coordinate's residual: the total less its own old score, the offsets it trains on"),
     "photon.descent.rescore": ("descent loop", "a coordinate's new score and the total rebuilt from it"),
     "photon.score.batch": ("scorer", "GameScorer's fused batch program: every coordinate's margin"),

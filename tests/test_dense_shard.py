@@ -194,9 +194,12 @@ def test_dense_and_csr_shards_build_the_same_fit(built_pair):
     assert len(re_d.device_buckets) == len(re_c.device_buckets)
     for bd, bc in zip(re_d.device_buckets, re_c.device_buckets):
         for field in ("features", "labels", "offsets", "train_weights", "sample_pos",
-                      "score_feats", "score_slot", "score_pos"):
+                      "score_feats"):
             np.testing.assert_array_equal(np.asarray(getattr(bd, field)),
                                           np.asarray(getattr(bc, field)))
+    assert re_d._score_plan() == re_c._score_plan() and re_d.score_layout == "sample_order"
+    for x, y in zip(_leaves(re_d._score_args()), _leaves(re_c._score_args())):
+        np.testing.assert_array_equal(x, y)
     assert dense.update_sequence == csr.update_sequence
 
 
@@ -241,7 +244,9 @@ def _re_coordinate(n=3000, cap=16):
 @pytest.mark.parametrize("rows_a_chunk", [1024, 2048])
 def test_chunked_rescoring_is_the_unchunked_one_bit_for_bit(monkeypatch, rows_a_chunk):
     coord = _re_coordinate()
-    largest = max(int(db.score_pos.shape[0]) for db in coord.device_buckets)
+    (block,) = coord.score_blocks  # one width: the whole of sample order
+    largest = int(block.slot.shape[0])
+    assert largest == coord.num_samples
     rng = np.random.default_rng(0)
     state = [jnp.asarray(rng.standard_normal((db.features.shape[0], db.features.shape[2])),
                          jnp.float32) for db in coord.device_buckets]
@@ -257,7 +262,7 @@ def test_chunked_rescoring_is_the_unchunked_one_bit_for_bit(monkeypatch, rows_a_
     chunk = coordinate_mod.rescore_chunk_rows(largest, D_RE)
     assert chunk < largest and -(-largest // chunk) >= 2  # several chunks
     text = jax.jit(lambda s: chunked._score_all_jit(
-        chunked._score_args(), s, chunked._pad_slots())).lower(state).as_text()
+        chunked._score_args(), s, chunked._score_plan())).lower(state).as_text()
     assert "while" in text
     np.testing.assert_array_equal(np.asarray(chunked.score(state)), whole)
     chunked_sweep = chunked.sweep_step(total, score0, state, donate=False)

@@ -99,7 +99,7 @@ def test_fused_sweep_single_program_per_coordinate(monkeypatch):
         (
             RandomEffectCoordinate,
             ("_sweep_jit", "_sweep_jit_nodonate", "_train_all_jit",
-             "_train_bucket", "_score_all_jit", "_score_flat"),
+             "_train_bucket", "_score_all_jit"),
         ),
     ):
         for prog in progs:
@@ -124,7 +124,6 @@ def test_fused_sweep_single_program_per_coordinate(monkeypatch):
     assert calls["FixedEffectCoordinate._train_jit"] == 0
     assert calls["RandomEffectCoordinate._train_all_jit"] == 0
     assert calls["RandomEffectCoordinate._train_bucket"] == 0
-    assert calls["RandomEffectCoordinate._score_flat"] == 0
 
     # trace counters: each fused program traced ONCE across all sweeps —
     # a count > 1 means the steady state is retracing/recompiling

@@ -519,3 +519,34 @@ def test_fe_sweep_program_reads_the_block_once_after_its_loop(
     assert order.index(forward) > order.index(loop)
     before, after = sorted(order.index(p) for p in passes[("top", "photon.rmatvec")])
     assert before < order.index(loop) < after
+
+
+def test_scope_join_charges_operations_to_the_execution_they_start_in():
+    """``scripts/scope_join.py``: two coordinates run one program name;
+    every operation goes to the execution it started in, keeping its self
+    time (a ``while`` encloses its body), and a scope path falls into the
+    row of the summary that names it."""
+    import importlib.util
+    import os
+
+    path = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+                        "scripts", "scope_join.py")
+    spec = importlib.util.spec_from_file_location("scope_join", path)
+    join = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(join)
+    executions = [(0.0, 1.0, "jit_re_sweep(1)"), (1.0, 3.0, "jit_re_sweep(2)"),
+                  (3.0, 3.5, "jit_other(9)"), (4.0, 5.0, "jit_re_sweep(1)")]
+    ops = [("while.1", 0.125, 0.875), ("fusion.2", 0.25, 0.5), ("fusion.2", 1.0, 2.5),
+           ("sort.9", 2.5, 3.0), ("copy.3", 3.125, 3.25), ("fusion.2", 4.25, 4.5)]
+    per, module_s, other_s = join.charge(
+        executions, ops, {"jit_re_sweep(1)": "per_user", "jit_re_sweep(2)": "per_movie"})
+    assert per == {"per_user": {"while.1": 0.5, "fusion.2": 0.5},
+                   "per_movie": {"fusion.2": 1.5, "sort.9": 0.5}}
+    assert module_s == {"per_user": 2.0, "per_movie": 2.0} and other_s == 0.5
+    assert join._group(("photon.descent.rescore", "photon.re.rescore")) == "photon.re.rescore"
+    assert join._group(("photon.re.solve", "photon.re.fetch")) == "photon.re.fetch"
+    assert join._group(("photon.re.solve", "photon.re.chunk", "photon.lbfgs.direction")) \
+        == "photon.re.solve (solver)"
+    assert join._group(("photon.re.solve",)) == "photon.re.solve (own)"
+    assert join._group(("photon.descent.residual",)) == "photon.descent.*"
+    assert join._group(()) == "(no photon scope)"

@@ -214,10 +214,10 @@ def test_re_bucket_sweep_compiles_for_v5e(deployment, one_chip):
     )
     ds = build_random_effect_dataset(deployment, cfg, seed=0)
     assert sum(b.num_entities for b in ds.buckets) == chip_smoke.USERS
+    # the score block is the whole of sample order whatever the buckets:
+    # a coordinate of the one bucket, over every sample
+    ds.buckets = [max(ds.buckets, key=lambda b: b.features.shape[1])]
     coord = RandomEffectCoordinate.build(deployment, ds, cfg)
-    coord.device_buckets = [
-        max(coord.device_buckets, key=lambda b: b.features.shape[1])
-    ]
     assert coord.device_buckets[0].features.shape[1:] == (
         chip_smoke.USER_CAP, RE_DIM,
     )
@@ -234,7 +234,7 @@ def test_re_bucket_sweep_compiles_for_v5e(deployment, one_chip):
             row,
             row,
             _on(coord._state_sds_list(), one_chip),
-            coord._pad_slots(),
+            coord._score_plan(),
             _on(coord._scalar_sds(), one_chip),
         )
         .compile()
@@ -490,10 +490,14 @@ def test_cell_forward_pass_has_no_second_level(cell_passes):
     assert f"f32[{block},128]" in text
 
 
-def _shapes_only_re_sweep(one_chip, entities, rows, d, kept_rows, n, optimizer_config):
-    """The fused RE sweep over ONE bucket ``[entities, rows, d]`` with
-    ``kept_rows`` flat score rows, compiled from shapes alone: no data is
-    built. -> (the problem configuration, the compiled program)."""
+def _shapes_only_re_sweep(one_chip, buckets, n, optimizer_config):
+    """The fused RE sweep over ``buckets`` [(entities, rows, d, kept rows)]
+    of a coordinate of ``n`` samples, compiled from shapes alone: no data
+    is built. The score blocks are what ``RandomEffectCoordinate.build``
+    would place: one ``[n, d]`` block in sample order where the buckets
+    share a width, else a block of its buckets' kept rows, with their
+    positions, for each width. -> (the problem configuration, the compiled
+    program)."""
     opt = GLMProblemConfig(
         task=TaskType.LOGISTIC_REGRESSION,
         regularization=RegularizationContext(RegularizationType.L2),
@@ -502,7 +506,8 @@ def _shapes_only_re_sweep(one_chip, entities, rows, d, kept_rows, n, optimizer_c
     coord = RandomEffectCoordinate(
         config=RandomEffectCoordinateConfig(
             random_effect_type="e", feature_shard="e", optimization=opt,
-            regularization_weights=(1.0,), active_data_upper_bound=rows,
+            regularization_weights=(1.0,),
+            active_data_upper_bound=max(rows for _, rows, _, _ in buckets),
         ),
         dataset=None, device_buckets=[],
         problem_config=opt.with_regularization_weight(1.0),
@@ -512,18 +517,29 @@ def _shapes_only_re_sweep(one_chip, entities, rows, d, kept_rows, n, optimizer_c
     def sds(shape, dtype=jnp.float32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    block = (
-        sds((entities, rows, d)), sds((entities, rows)), sds((entities, rows)),
-        sds((entities, rows)), sds((entities, rows), jnp.int32),
+    blocks = tuple(
+        (
+            sds((entities, rows, d)), sds((entities, rows)), sds((entities, rows)),
+            sds((entities, rows)), sds((entities, rows), jnp.int32),
+        )
+        for entities, rows, d, _ in buckets
     )
-    flat = (sds((kept_rows, d)), sds((kept_rows,), jnp.int32),
-            sds((kept_rows,), jnp.int32))
+    widths = {}
+    for i, (_, _, d, kept) in enumerate(buckets):
+        widths.setdefault(d, []).append((i, kept))
+    score_args, plan = [], []
+    for d, members in widths.items():
+        m = n if len(widths) == 1 else sum(kept for _, kept in members)
+        positions = () if len(widths) == 1 else (sds((m,), jnp.int32),)
+        score_args.append((sds((m, d)), sds((m,), jnp.int32)) + positions)
+        plan.append(tuple(i for i, _ in members))
     compiled = (
         type(coord)
         ._active_sweep_jit(True)
         .lower(
-            coord, (block,), (flat,), sds((n,)), sds((n,)),
-            [sds((entities, d))], (0,), sds(()),
+            coord, blocks, tuple(score_args), sds((n,)), sds((n,)),
+            [sds((entities, d)) for entities, _, d, _ in buckets],
+            tuple(plan), sds(()),
         )
         .compile()
     )
@@ -553,7 +569,7 @@ def test_cell_single_row_bucket_sweep_fits_its_budget(one_chip):
         cell = json.load(f)
     entities, rows, d = 1_997_496, 1, cell["random_effects"]["per_user"]["d"]
     opt, compiled = _shapes_only_re_sweep(
-        one_chip, entities, rows, d, entities, cell["features"]["n"],
+        one_chip, [(entities, rows, d, entities)], cell["features"]["n"],
         OptimizerConfig(
             max_iterations=cell["solver"]["re_max_iterations"],
             ls_max_iterations=cell["solver"]["re_ls_max_iterations"],
@@ -585,11 +601,13 @@ def test_row_heavy_solve_temporaries_against_solve_entity_bytes(
 
     # no chunk loop: the whole bucket's temporaries are what is read
     monkeypatch.setattr(coordinate_mod, "RE_SOLVE_BYTES", 1 << 40)
-    n = 1 << 23
+    # few samples: the score block is [n, 16] (PR 37), and its rescoring's
+    # temporaries are not what is read here
+    n = 1 << 13
     temps = []
     for entities in (fewer, more):
         opt, compiled = _shapes_only_re_sweep(
-            one_chip, entities, rows, 16, 1024, n, ROW_HEAVY
+            one_chip, [(entities, rows, 16, 1024)], n, ROW_HEAVY
         )
         temps.append(_fits(compiled).temp_size_in_bytes)
     grown = (temps[1] - temps[0]) / (more - fewer)
@@ -599,18 +617,19 @@ def test_row_heavy_solve_temporaries_against_solve_entity_bytes(
 
 def test_cell_row_heavy_bucket_rescoring_runs_in_row_chunks(one_chip):
     """The per-movie coordinate's capped bucket of ``glmix_movielens.sweeps``,
-    [751, 4096, 16] with 5 255 525 kept rows (the bucket the cell's
-    structure seed gives): its rescoring passes ``RE_RESCORE_BYTES`` whole
-    (5.4 GB of lane-padded gather) and runs as a loop over two row chunks
-    under ``photon.re.rescore``; the solve needs no chunk loop. Shapes
-    only: no data is built."""
+    [751, 4096, 16] (the bucket the cell's structure seed gives), with the
+    coordinate's score block: since PR 37 the whole of sample order,
+    [2^23, 16], whatever the bucket keeps. Its rescoring passes
+    ``RE_RESCORE_BYTES`` whole (8.6 GB of lane-padded gather) and runs as a
+    loop over two row chunks under ``photon.re.rescore``; the solve needs
+    no chunk loop. Shapes only: no data is built."""
     from photon_tpu.game import coordinate as coordinate_mod
 
-    kept, d = 5_255_525, 16
-    chunk = coordinate_mod.rescore_chunk_rows(kept, d)
-    assert chunk % 1024 == 0 and -(-kept // chunk) == 2
+    n, d = 1 << 23, 16
+    chunk = coordinate_mod.rescore_chunk_rows(n, d)
+    assert chunk % 1024 == 0 and -(-n // chunk) == 2
     opt, compiled = _shapes_only_re_sweep(
-        one_chip, 751, 4096, d, kept, 1 << 23, ROW_HEAVY
+        one_chip, [(751, 4096, d, 5_255_525)], n, ROW_HEAVY
     )
     assert coordinate_mod.solve_chunk_entities(751, 4096, d, opt.optimizer_config) == 751
     m = _fits(compiled)
@@ -618,3 +637,58 @@ def test_cell_row_heavy_bucket_rescoring_runs_in_row_chunks(one_chip):
     text = compiled.as_text()
     assert "photon.re.rescore/while/body/" in text
     assert "photon.re.chunk" not in text
+
+
+def _scatters_and_sorts(compiled):
+    """(the ``scatter`` instructions under ``photon.re.rescore``, every
+    ``sort`` instruction) of a compiled program."""
+    from photon_tpu.analysis import hlo
+
+    text = compiled.as_text()
+    paths = hlo.instruction_scope_paths(text)
+    instrs = hlo.parse_instructions(text).values()
+    return (
+        [i for i in instrs if i.opcode == "scatter"
+         and "photon.re.rescore" in paths.get(i.name, ())],
+        [i for i in instrs if i.opcode == "sort"],
+    )
+
+
+@pytest.mark.parametrize("cell,n,bucket", [
+    # the largest per-user bucket of each GLMix cell, as its structure seed gives it
+    ("glmix_movielens", 1 << 23, (1108, 1026, 16, 1_200_000)),
+    ("glmix_ctr", 1 << 22, (647, 256, 16, 400_000)),
+])
+def test_one_width_rescoring_neither_sorts_nor_scatters(one_chip, cell, n, bucket):
+    """What PR 37 is for. Both GLMix cells' coordinates have one bucket
+    width, so their score block is sample order and ``jit_re_sweep`` holds
+    no ``sort`` anywhere and no ``scatter`` under ``photon.re.rescore``
+    (at the parent: a sort of the (position, score) pairs and a scatter of
+    them one element at a time, a bucket). The residual fetch has its own
+    scope inside the solve's."""
+    _, compiled = _shapes_only_re_sweep(one_chip, [bucket], n, ROW_HEAVY)
+    _fits(compiled)
+    scatters, sorts = _scatters_and_sorts(compiled)
+    assert not sorts, [i.name for i in sorts]
+    assert not scatters, [i.name for i in scatters]
+    text = compiled.as_text()
+    assert " sort(" not in text
+    assert "photon.re.solve/photon.re.fetch" in text
+
+
+def test_two_width_rescoring_scatters_sorted_and_unique_without_a_sort(one_chip):
+    """A coordinate whose buckets have two widths keeps a block a width
+    and adds each at its ascending, distinct positions: one scatter a
+    block that promises both, and still no sort."""
+    _, compiled = _shapes_only_re_sweep(
+        one_chip,
+        [(1108, 1026, 16, 1_200_000), (4096, 64, 8, 300_000)],
+        1 << 23, ROW_HEAVY,
+    )
+    _fits(compiled)
+    scatters, sorts = _scatters_and_sorts(compiled)
+    assert not sorts and " sort(" not in compiled.as_text()
+    assert len(scatters) == 2, [i.name for i in scatters]
+    for ins in scatters:
+        assert "indices_are_sorted=true" in ins.attributes, ins.attributes
+        assert "unique_indices=true" in ins.attributes, ins.attributes
