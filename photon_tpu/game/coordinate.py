@@ -46,6 +46,7 @@ from photon_tpu.models.glm import model_for_task
 from photon_tpu.obs import memory as obs_memory
 from photon_tpu.obs.health import sweep_health
 from photon_tpu.obs.scopes import scope
+from photon_tpu.ops import gather
 from photon_tpu.ops.losses import POSITIVE_RESPONSE_THRESHOLD
 from photon_tpu.ops.normalization import NormalizationContext
 from photon_tpu.data.dataset import choose_sparse
@@ -87,15 +88,18 @@ RE_SOLVE_BYTES = 1 << 29
 #: of these arrays on the 128 lanes, 8 sublanes deep
 _CHUNK_MULTIPLE = 1024
 
-#: Device bytes one bucket's rescoring may hold in temporaries: the
-#: ``coefs[score_slot]`` gather lays each kept row's d coefficients on the
-#: 128 lanes (``rescore_row_bytes``). A bucket with more kept rows than
-#: fit is rescored as a loop over row chunks inside the same program
-#: (``RandomEffectCoordinate._rescore_rows``). 2**32, 4 194 304 rows of a
-#: 16-wide table: the largest bucket ``glmix_ctr.sweeps`` rescores at once
-#: holds 3 343 970 rows (3.4 GB of temporaries), and that cell's programs
-#: keep their shape; what is bounded is what lies beyond it, the
-#: row-heavy buckets of ``glmix_movielens.sweeps`` (PERF.md, PR 36).
+#: Device bytes a score block's rescoring may hold in temporaries on the
+#: PLAIN path: the ``coefs[score_slot]`` gather lays each kept row's d
+#: coefficients on the 128 lanes (``rescore_row_bytes``). A block with
+#: more rows than fit is rescored as a loop over row chunks inside the same
+#: program (``RandomEffectCoordinate._rescore_rows``). 2**32, 4 194 304
+#: rows of a 16-wide table. Since PR 40 it governs only the blocks whose
+#: table is not fetched packed (``_packs_table``: not a program for a TPU,
+#: a mesh, a width that does not divide 128, a packed table past
+#: ``ops/gather._PACKED_TABLE_BYTES``): of the benchmark's coordinates the
+#: per-user one of ``glmix_ctr.sweeps`` (2**21 + 1 entities, one chunk of
+#: 2**22 rows). The packed path cuts its rows by ``gather.segment_plan``
+#: and holds ~0.07 GB whatever the block (PERF.md, PR 40).
 RE_RESCORE_BYTES = 1 << 32
 
 
@@ -156,17 +160,18 @@ def solve_chunk_entities(
 
 
 def rescore_row_bytes(d: int, itemsize: int = 4) -> int:
-    """Device bytes one kept row holds in temporaries while its bucket is
-    rescored: its entity's d coefficients gathered onto the 128 lanes,
-    and the product with the row's features before its sum, as wide. The
-    compiler reports 1025 B a row at d = 16 (a described v5e: 1.076 GB at
-    2**20 rows, 2.150 GB at 2**21)."""
+    """Device bytes one kept row holds in temporaries while its block is
+    rescored by the PLAIN gather (``_rescore_rows``; the packed fetch
+    holds a segment's rows and no more): its entity's d coefficients
+    gathered onto the 128 lanes, and the product with the row's features
+    before its sum, as wide. The compiler reports 1025 B a row at d = 16
+    (a described v5e: 1.076 GB at 2**20 rows, 2.150 GB at 2**21)."""
     return 2 * itemsize * 128 * -(-d // 128)
 
 
 def rescore_chunk_rows(rows: int, d: int, itemsize: int = 4) -> int:
-    """How many of a bucket's ``rows`` kept rows one step of the rescoring
-    takes: all of them where their temporaries fit ``RE_RESCORE_BYTES``,
+    """How many of a block's ``rows`` kept rows one step of the PLAIN
+    rescoring takes: all of them where their temporaries fit ``RE_RESCORE_BYTES``,
     else the rows split evenly over the fewest chunks that fit, each a
     whole number of tiles."""
     fit = RE_RESCORE_BYTES // rescore_row_bytes(d, itemsize)
@@ -1019,6 +1024,40 @@ class RandomEffectCoordinate(Coordinate):
             return "sample_order"
         return "sorted_scatter"
 
+    def _block_tables(self) -> list[tuple[int, int]]:
+        """(rows, width) of every score block's coefficient table: its
+        buckets' placed tables laid end to end, and the zero row."""
+        return [
+            (
+                sum(self.device_buckets[i].features.shape[0] for i in blk.buckets)
+                + 1,
+                blk.feats.shape[1],
+            )
+            for blk in self.score_blocks
+        ]
+
+    @property
+    def table_fetch(self) -> str:
+        """How a rescored row gets its entity's coefficients in a program
+        traced now (``_packs_table``): ``packed_rows`` (the 128-lane row
+        fetch from the packed table, every block), ``plain``
+        (``coefs[slot]``, every block), ``mixed`` across widths."""
+        packs = {self._packs_table(e, d) for e, d in self._block_tables()}
+        if packs == {True}:
+            return "packed_rows"
+        return "mixed" if True in packs else "plain"
+
+    @property
+    def packed_table_bytes(self) -> int:
+        """Bytes of the blocks' coefficient tables packed ``128 // d``
+        entities to a lane row, whichever fetch they get: what
+        ``table_fetch`` was decided on (0 for a width with no such view)."""
+        itemsize = jnp.dtype(self.dtype).itemsize
+        return sum(
+            gather.packed_table_bytes(e, d, itemsize)
+            for e, d in self._block_tables()
+        )
+
     def with_regularization_weight(self, w: float) -> "RandomEffectCoordinate":
         """In-place λ reweight — see FixedEffectCoordinate: keeps the per-
         bucket compiled programs (static self) valid across the λ grid."""
@@ -1278,24 +1317,56 @@ class RandomEffectCoordinate(Coordinate):
             return jnp.zeros((self.num_samples,), dtype=self.dtype)
         return total
 
+    def _packs_table(self, entities: int, d: int) -> bool:
+        """Whether a score block's rows fetch their coefficients from the
+        packed table (``ops/gather.packs_table``: a program for a TPU, a
+        width that divides 128, a packed table that stays in fast memory).
+        Never on a mesh: there every device scores its share at once."""
+        return self.mesh is None and gather.packs_table(
+            entities, d, jnp.dtype(self.dtype).itemsize
+        )
+
     def _rescore_rows(self, score_feats, score_slot, coefs) -> Array:
         """[M]: every row of a score block dotted with its entity's
-        coefficients, ``chunk`` rows at a time where the whole block's
-        gather would pass ``RE_RESCORE_BYTES``: one loop inside the
-        program, its buffers reused from chunk to chunk. The last chunk
-        is moved back to end on the last row, as the solves' last chunk
-        is: the rows it shares with the chunk before are scored twice, to
-        the same numbers (a row's sum does not depend on the rows beside
-        it). On a mesh the kept rows are already split over the devices
-        and every device scores its share at once."""
+        coefficients, by one of two fetches (``_packs_table``).
+
+        Packed: the table is viewed ``128 // d`` entities to a lane row,
+        and the rows go through ``gather.map_segments`` in the segments of
+        ``gather.segment_plan``, as a forward sparse pass cuts its rows: a
+        segment fetches its entities' lane rows, keeps each row's d lanes
+        (``gather.fetch_select_rows``) and dots them with the features.
+        The coefficients are ``coefs[slot]`` bit for bit and the einsum is
+        the plain path's. ``RE_RESCORE_BYTES`` decides nothing here.
+
+        Plain: ``coefs[slot]``, ``rescore_chunk_rows`` rows at a time
+        where the whole block's gather would pass ``RE_RESCORE_BYTES``:
+        one loop inside the program, its buffers reused from chunk to
+        chunk. The last chunk is moved back to end on the last row, as the
+        solves' last chunk is: the rows it shares with the chunk before
+        are scored twice, to the same numbers (a row's sum does not depend
+        on the rows beside it). On a mesh the kept rows are already split
+        over the devices and every device scores its share at once."""
+        m, d = score_feats.shape
+        packed = self._packs_table(coefs.shape[0], d)
+        t2 = gather.pack_table(coefs) if packed else None
 
         def rows_of(feats, slot):
-            c = coefs[slot].astype(feats.dtype)
-            return jnp.einsum("md,md->m", feats, c)
+            c = gather.fetch_select_rows(t2, slot, d) if packed else coefs[slot]
+            return jnp.einsum("md,md->m", feats, c.astype(feats.dtype))
 
-        m = score_feats.shape[0]
+        if packed:
+            # four lane rows of fast memory a row: the fetched one, its copy
+            # with the rows on the lanes (the feature block's layout) and
+            # room beside them. Read on the chip (PERF.md section 6, PR 40):
+            # 32 768 and 65 536 rows a segment cost the same within 3 %,
+            # 131 072 a third more (the copy goes to HBM); at 32 768 the
+            # compiled dot adds a row's products in the plain path's order.
+            # A flat stream is tiled 1024 to a row of 8 x 128.
+            plan = gather.segment_plan(m, 4, jnp.dtype(coefs.dtype).itemsize, 1024)
+            return gather.map_segments(rows_of, (score_feats, score_slot), plan, 0)
+
         chunk = rescore_chunk_rows(
-            m, coefs.shape[1], jnp.dtype(score_feats.dtype).itemsize
+            m, d, jnp.dtype(score_feats.dtype).itemsize
         )
         if chunk >= m or self.mesh is not None:
             return rows_of(score_feats, score_slot)

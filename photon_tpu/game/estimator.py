@@ -474,6 +474,8 @@ class GameEstimator:
                         place.set(
                             score_layout=coords[cid].score_layout,
                             width_groups=len(coords[cid].score_blocks),
+                            table_fetch=coords[cid].table_fetch,
+                            packed_table_bytes=coords[cid].packed_table_bytes,
                         )
                     seconds[cid]["place"] = place.duration_s
                 waste = ds.padding_waste()

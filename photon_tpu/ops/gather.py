@@ -55,6 +55,38 @@ around the loop of segments makes the table an HBM operand of the fetch
 in HBM. Blocks of 256 and 512 instances read 0.6723 and 0.6802 s: the
 least group that fills the lanes is the one to take.
 
+**A table of rows** (:func:`pack_table`, :func:`fetch_select_rows`;
+PERF.md §6, PR 40). The random effects' rescoring gathers ``[rows, d]``
+out of an ``[entities, d]`` coefficient table, d = 16. Left to the
+compiler the d coefficients of an entity lie on the 128 lanes, so the
+table is 8 x its bytes and a gathered row 512 B: a table past ~14 MB of
+that (27 279 entities read 1.8 ns a row, 65 537 and 262 145 read 9.9,
+2 097 153 read 22.1) leaves fast memory and the gather runs at the
+serialized element rate. Viewed ``128 // d`` entities to a lane row the
+table is its own bytes, the fetch is the 128-lane row fetch above (1.5 ns
+a row) and the select keeps an entity's d lanes by a ``where`` over the
+row's entities and a sum over them. Read on the v5e, the whole rescoring
+of a [2^23, 16] block (fetch, select, dot), ms, plain against packed, at
+packed tables of 1.7 / 4.2 / 16.8 / 33.6 / 67.1 / 134 MB: plain 36.3 /
+104.3 / 104.8 / 53.7 / 53.5 / 188.0; packed in segments of 131 072 rows
+26.1 / 26.3 / 27.1 / 27.5 / 36.9 / 85.1. Up to 33.6 MB the table and
+the fetched block stay in fast memory and a row costs the same; past it
+the compiler reads the table from HBM (it does NOT stage it per step, as
+PR 30's loop did). ``_PACKED_TABLE_BYTES`` = 2^26 is the ceiling of the
+flat part. The packed fetch was ahead at every size read, 134 MB
+included (10.2 against 22.5 ns a row): what lies past the constant is
+left plain because packing such a table holds 8 x its bytes in
+temporaries (1.2 GB at 134 MB), not because the loop loses there. The
+rescoring's segments are SMALLER than a sparse pass's: the compiler turns
+the fetched block rows-to-lanes for the select (the feature block lies
+with its rows on the lanes), so a row holds two lane rows, and the caller
+plans four to a row: at 32 768 and at 65 536 rows a segment both copies
+stay in fast memory (20.0 / 20.1 / 20.4 and 19.5 / 19.7 / 20.5 ms at the
+first three tables), at 131 072 the second goes to HBM (26-27 ms). At
+32 768 (and 131 072) rows the compiled dot adds a row's 16 products in
+the plain path's order and the scores are ``coefs[slot]``'s to the last
+bit; at 65 536 the compiler picked another order.
+
 Which gather a program gets is a fact about its platform
 (:func:`fetches_rows`): the row fetch in a program for a TPU, the plain
 ``table[idx]`` elsewhere (CPU's native gather is faster than the 128x
@@ -76,10 +108,14 @@ __all__ = [
     "chunked_take",
     "fetch_select",
     "fetch_select_dot",
+    "fetch_select_rows",
     "fetches_rows",
     "lane_rows",
     "map_segment_groups",
     "map_segments",
+    "pack_table",
+    "packed_table_bytes",
+    "packs_table",
     "segment_plan",
     "take_1d",
 ]
@@ -90,6 +126,13 @@ __all__ = [
 #: ~88 MB, the table is staged per step under ~45 MB), and the loop's steps
 #: are as few as that allows (each costs ~20 µs of control)
 _SEG_BYTES = 1 << 26
+
+#: bytes of a packed coefficient table (:func:`pack_table`) up to which a
+#: rescoring fetches lane rows from it, from the reading on the chip (module
+#: docstring; PERF.md §6, PR 40): the largest table read that the v5e's
+#: compiler keeps in fast memory is 33.6 MB (3.4 ns a row, as at 1.7 MB), the
+#: smallest that it does not 67.1 MB (4.5 ns)
+_PACKED_TABLE_BYTES = 1 << 26
 
 
 class SegmentPlan(NamedTuple):
@@ -148,6 +191,58 @@ def fetch_select(t2: Array, idx: Array) -> Array:
             sel = (flat & 127)[:, None] == lane_iota
             out = jnp.sum(jnp.where(sel, rows, 0), axis=1)
     return out.reshape(idx.shape)
+
+
+def packed_table_bytes(entities: int, d: int, itemsize: int) -> int:
+    """Bytes of an [entities, d] table viewed ``128 // d`` entities to a
+    128-lane row (:func:`pack_table`); 0 where ``d`` does not divide 128
+    and the table has no such view."""
+    if d < 1 or 128 % d:
+        return 0
+    return -(-entities // (128 // d)) * 128 * itemsize
+
+
+def packs_table(entities: int, d: int, itemsize: int) -> bool:
+    """Whether ``table[slot]`` of an [entities, d] table is the packed row
+    fetch (:func:`fetch_select_rows`) in the program being traced: a
+    program for a TPU, a width that divides 128, and a packed table the
+    compiler keeps in fast memory (``_PACKED_TABLE_BYTES``). Shapes and the
+    platform in, nothing else."""
+    return (
+        fetches_rows()
+        and 0 < packed_table_bytes(entities, d, itemsize) <= _PACKED_TABLE_BYTES
+    )
+
+
+def pack_table(table: Array) -> Array:
+    """The [entities, d] table as [rows, 128], ``128 // d`` entities to a
+    lane row, the last row filled up with zero entities: the row-major
+    bytes of the table (the compiler keeps an [entities, 16] array with the
+    entities on the lanes and relays it: 0.3 ms at 65 537 entities)."""
+    e, d = table.shape
+    pad = -e % (128 // d)
+    if pad:
+        table = jnp.concatenate([table, jnp.zeros((pad, d), table.dtype)])
+    return table.reshape(-1, 128)
+
+
+def fetch_select_rows(t2: Array, slot: Array, d: int) -> Array:
+    """``table[slot]``, [slot.size, d], for one block of slots, ``t2 =
+    pack_table(table)``: the 128-lane row that holds the entity is fetched
+    (:func:`fetch_select`'s fetch) and the entity's ``d`` lanes are kept by
+    a ``where`` over the row's ``128 // d`` entities and a sum over them:
+    one nonzero term a sum, so every coefficient is the table's bit for
+    bit, and a non-finite entity does not reach the entities beside it."""
+    per = 128 // d
+    flat = slot.reshape(-1)
+    entity_iota = jax.lax.broadcasted_iota(jnp.int32, (1, per, 1), 1)
+    with scope("photon.gather"):
+        with scope("photon.gather.fetch"):
+            # d divides 128: per is a power of two
+            rows = t2[flat >> (per.bit_length() - 1)]
+        with scope("photon.gather.select"):
+            sel = (flat & (per - 1))[:, None, None] == entity_iota
+            return jnp.sum(jnp.where(sel, rows.reshape(-1, per, d), 0), axis=1)
 
 
 def fetch_select_dot(t2: Array, idx: Array, weights: Array) -> Array:

@@ -179,10 +179,14 @@ def test_merge_pads_a_block_to_the_mesh_past_the_samples():
     assert np.all(slot[1003:] == slot.max())
 
 
-def test_place_span_carries_the_score_layout():
+@pytest.mark.parametrize("platform", ["cpu", "tpu"])
+def test_place_span_carries_the_score_layout(platform):
     """``photon.game.prepare.place`` of a random effect says which way its
-    scores reach sample order, and over how many width groups."""
+    scores reach sample order, over how many width groups, and how a row
+    gets its coefficients in a program for this platform: the packed
+    table's bytes, and whether the rows are fetched from it."""
     from photon_tpu.game.estimator import GameEstimator
+    from photon_tpu.util import target
 
     seen = {}
     for widths in (1, 2):
@@ -193,11 +197,140 @@ def test_place_span_carries_the_score_layout():
         obs.enable()
         try:
             obs.reset()
-            est.build(_data(widths))
+            with target.compiling_for(platform):
+                built = est.build(_data(widths))
             rows = [r for r in obs.get_tracer().spans() if r.name == "game.prepare.place"]
         finally:
             obs.disable()
             obs.reset()
         (row,) = rows
         seen[widths] = (row.args["score_layout"], row.args["width_groups"])
+        # a width's 90 users and the zero row: 8 (16) entities of 16 (8) to a lane row
+        tables = [(91, 16)] if widths == 1 else [
+            (1 + sum(db.features.shape[0] for db in built.coordinates["per_user"].device_buckets
+                     if db.features.shape[2] == d), d) for d in (8, 16)]
+        assert sum(e for e, _ in tables) == 90 + len(tables)
+        assert row.args["packed_table_bytes"] == sum(-(-e // (128 // d)) * 512 for e, d in tables)
+        assert row.args["table_fetch"] == ("packed_rows" if platform == "tpu" else "plain")
     assert seen == {1: ("sample_order", 1), 2: ("sorted_scatter", 2)}
+
+def _hand_made(widths, heights, n, rng, mesh=None):
+    """A coordinate with no dataset, and what ``_score_blocks_body`` takes:
+    a score block a width (in sample order where there is one width), its
+    slots counting through two tables of ``heights`` laid end to end, the
+    row after them the zero row."""
+    cfg = _config()
+    coord = RandomEffectCoordinate(
+        config=cfg, dataset=None, device_buckets=[],
+        problem_config=cfg.optimization.with_regularization_weight(1.0),
+        num_samples=n, dtype=jnp.float32, mesh=mesh)
+    state, score_args, plan = [], [], []
+    for k, d in enumerate(widths):
+        members = (2 * k, 2 * k + 1)
+        state += [jnp.asarray(rng.standard_normal((h, d)), jnp.float32) for h in heights]
+        m = n if len(widths) == 1 else n // 2
+        slot = rng.integers(0, sum(heights) + 1, m).astype(np.int32)
+        slot[:3] = sum(heights)  # the zero row, under features that are not zero
+        block = (jnp.asarray(rng.standard_normal((m, d)), jnp.float32), jnp.asarray(slot))
+        if len(widths) > 1:
+            block += (jnp.asarray(np.sort(rng.choice(n, m, replace=False)).astype(np.int32)),)
+        score_args.append(block)
+        plan.append(members)
+    return coord, tuple(score_args), state, tuple(plan)
+
+
+#: case -> (bucket widths, heights of a width's two tables, samples, bytes a
+#: segment or None, the ceiling on a packed table or None, an Inf entity,
+#: whether the program for a TPU fetches 128-lane rows)
+PACKED_CASES = {
+    "table_rows_a_multiple_of_8": ((16,), (40, 55), 1600, None, None, False, True),
+    "table_rows_padded_to_8": ((16,), (40, 50), 1600, None, None, False, True),
+    "two_widths": ((16, 8), (40, 50), 1600, None, None, False, True),
+    "segment_loop_with_a_tail": ((16,), (40, 50), 3000, 1024 * 512, None, False, True),
+    "inf_beside_a_finite_entity": ((16,), (40, 50), 1600, None, None, True, True),
+    "width_that_does_not_divide_128": ((12,), (40, 50), 1600, None, None, False, False),
+    "table_over_the_constant": ((16,), (40, 50), 1600, None, 91 * 64 - 1, False, False),
+}
+
+
+@pytest.mark.parametrize("case", sorted(PACKED_CASES))
+def test_packed_rescoring_is_the_plain_gather_bit_for_bit(monkeypatch, case):
+    """The program for a TPU fetches a row's coefficients as the 128-lane
+    row of the packed table and keeps its entity's lanes; run on the CPU
+    backend it gives ``coefs[slot]``'s scores to the last bit. A width or a
+    table the rule leaves out compiles to the plain program."""
+    from photon_tpu.ops import gather
+    from photon_tpu.util import target
+
+    widths, heights, n, seg_bytes, ceiling, inf, packs = PACKED_CASES[case]
+    if seg_bytes is not None:
+        monkeypatch.setattr(gather, "_SEG_BYTES", seg_bytes)
+    if ceiling is not None:
+        monkeypatch.setattr(gather, "_PACKED_TABLE_BYTES", ceiling)
+    coord, score_args, state, plan = _hand_made(widths, heights, n, np.random.default_rng(3))
+    if inf:
+        state[0] = state[0].at[5].set(jnp.inf)
+
+    def body():  # a trace is cached by function
+        return jax.jit(lambda args, s: coord._score_blocks_body(args, s, plan))
+
+    plain = body().lower(score_args, state)
+    with target.compiling_for("tpu"):
+        assert coord._packs_table(sum(heights) + 1, widths[0]) == packs
+        packed = body().lower(score_args, state)
+    wide = "slice_sizes = array<i64: 1, 128>"
+    assert wide not in plain.as_text()
+    assert (wide in packed.as_text()) == packs
+    assert ("while" in packed.as_text()) == (seg_bytes is not None)
+    if not packs:
+        assert packed.as_text() == plain.as_text()
+    expect = np.asarray(plain.compile()(score_args, state))
+    got = np.asarray(packed.compile()(score_args, state))
+    np.testing.assert_array_equal(got, expect)
+    if len(widths) == 1:
+        assert not got[:3].any(), "a slot on the zero row scores 0"
+    if inf:
+        own = np.asarray(score_args[0][1]) == 5
+        assert own.any() and np.isfinite(got[~own]).all(), "the Inf stays its entity's"
+        assert not np.isfinite(got[own]).all()
+
+
+def test_a_coordinate_on_a_mesh_keeps_the_plain_gather():
+    from photon_tpu.util import target
+
+    if len(jax.devices()) < 8:
+        pytest.skip("needs the 8-virtual-device platform")
+    rng = np.random.default_rng(0)
+    with target.compiling_for("tpu"):
+        assert _hand_made((16,), (40, 50), 64, rng)[0]._packs_table(91, 16)
+        meshed = _hand_made((16,), (40, 50), 64, rng, make_mesh(num_data=1, num_entity=8))[0]
+        assert not meshed._packs_table(91, 16)
+
+
+def test_rescore_tables_script_rehearses_on_the_cpu(tmp_path):
+    """``scripts/rescore_tables.py`` (what ``_PACKED_TABLE_BYTES`` was read
+    with on the chip) at tiny shapes: a plain and a packed program a table,
+    the packed one again in a segment loop, every packed score the plain
+    one's to the last bit."""
+    import importlib.util
+    import json
+    import os
+
+    path = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+                        "scripts", "rescore_tables.py")
+    spec = importlib.util.spec_from_file_location("rescore_tables", path)
+    script = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(script)
+    out = tmp_path / "rows.json"
+    cache = jax.config.jax_enable_compilation_cache
+    try:
+        rc = script.main(["--rehearse", "--rows", "4096", "--heights", "50",
+                          "--seg-bytes", "524288", "--out", str(out)])
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache)
+    assert rc == 0
+    rows = json.loads(out.read_text())
+    assert [(r["kind"], r["seg_bytes"]) for r in rows] == [
+        ("plain", None), ("packed", None), ("packed", 524288)]
+    assert all(r["entities"] == 51 and r["packed_table_bytes"] == 7 * 512 for r in rows)
+    assert [r["bit_equal_to_plain"] for r in rows] == [None, True, True]

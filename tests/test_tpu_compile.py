@@ -585,6 +585,14 @@ def test_cell_single_row_bucket_sweep_fits_its_budget(one_chip):
     # the chunk loop sits under the bucket's scope, the solver under it
     assert "photon.re.solve/while/body/" in text
     assert "photon.re.chunk/vmap()/while/body/photon.lbfgs.linesearch" in text
+    # PR 40: this bucket alone makes the coordinate's table 128 MB packed
+    # (the cell's 2^21 + 1 entities: 134 MB), far over what stays in fast
+    # memory: its rows keep the parent's plain gather, one block, no loop
+    from photon_tpu.ops import gather
+
+    assert gather.packed_table_bytes(entities + 1, d, 4) > gather._PACKED_TABLE_BYTES
+    assert _rescore_gathers(compiled) == [f"1,{d}"]
+    assert "photon.re.rescore/while" not in text
 
 
 @pytest.mark.parametrize("rows,fewer,more", [(1024, 8192, 16384), (4096, 2048, 4096)])
@@ -615,17 +623,38 @@ def test_row_heavy_solve_temporaries_against_solve_entity_bytes(
     assert 0.5 * priced < grown <= priced, (grown, priced)
 
 
-def test_cell_row_heavy_bucket_rescoring_runs_in_row_chunks(one_chip):
+def _rescore_gathers(compiled):
+    """The ``slice_sizes`` of every gather under ``photon.re.rescore``, the
+    fused ones included."""
+    import re
+
+    text = compiled.as_text()
+    return [
+        m.group(1)
+        for ln in text.splitlines()
+        if " gather(" in ln and "photon.re.rescore" in ln
+        for m in [re.search(r"slice_sizes=\{([\d,]+)\}", ln)]
+        if m
+    ]
+
+
+@pytest.mark.parametrize("case,d", [("packed_segments", 16), ("plain_row_chunks", 24)])
+def test_cell_row_heavy_bucket_rescoring(one_chip, case, d):
     """The per-movie coordinate's capped bucket of ``glmix_movielens.sweeps``,
     [751, 4096, 16] (the bucket the cell's structure seed gives), with the
-    coordinate's score block: since PR 37 the whole of sample order,
-    [2^23, 16], whatever the bucket keeps. Its rescoring passes
-    ``RE_RESCORE_BYTES`` whole (8.6 GB of lane-padded gather) and runs as a
-    loop over two row chunks under ``photon.re.rescore``; the solve needs
-    no chunk loop. Shapes only: no data is built."""
+    coordinate's score block, the whole of sample order, [2^23, 16]. Since
+    PR 40 its 752-row table is packed eight entities to a lane row and the
+    rows go through ``gather.segment_plan``'s 256 segments of 32 768 (four
+    lane rows a row: the fetched one, its rows-on-lanes copy, room) under
+    ``photon.re.rescore``, holding under 0.2 GB of temporaries (PR 37: two
+    chunks of 2^22 rows, 4.3 GB). A width that does not divide 128 keeps
+    the plain gather, and ``rescore_chunk_rows`` still cuts its 2^23 rows
+    in two (``RE_RESCORE_BYTES``: 8.6 GB of lane-padded gather at once).
+    The solve needs no chunk loop. Shapes only: no data is built."""
     from photon_tpu.game import coordinate as coordinate_mod
+    from photon_tpu.ops import gather
 
-    n, d = 1 << 23, 16
+    n = 1 << 23
     chunk = coordinate_mod.rescore_chunk_rows(n, d)
     assert chunk % 1024 == 0 and -(-n // chunk) == 2
     opt, compiled = _shapes_only_re_sweep(
@@ -633,10 +662,46 @@ def test_cell_row_heavy_bucket_rescoring_runs_in_row_chunks(one_chip):
     )
     assert coordinate_mod.solve_chunk_entities(751, 4096, d, opt.optimizer_config) == 751
     m = _fits(compiled)
-    assert m.temp_size_in_bytes < chunk * coordinate_mod.rescore_row_bytes(d) * 1.1
     text = compiled.as_text()
     assert "photon.re.rescore/while/body/" in text
     assert "photon.re.chunk" not in text
+    if case == "packed_segments":
+        plan = gather.segment_plan(n, 4, 4, 1024)
+        assert plan == (256, 32768, 0)
+        assert f"f32[{plan.segments},{plan.per}]" in text  # the stacked scores
+        assert m.temp_size_in_bytes < 0.2e9, m.temp_size_in_bytes
+        assert _rescore_gathers(compiled) == ["1,128"]
+    else:
+        assert gather.packed_table_bytes(752, d, 4) == 0
+        assert m.temp_size_in_bytes < chunk * coordinate_mod.rescore_row_bytes(d) * 1.1
+        assert _rescore_gathers(compiled) == [f"1,{d}"]
+
+
+def test_cell_per_user_rescoring_fetches_lane_rows_of_the_packed_table(one_chip):
+    """What PR 40 is for: the per_user coordinate of ``glmix_movielens.sweeps``
+    (two of its buckets, [1108, 1026, 16] and the other 64 428 entities:
+    with the zero row a table of 65 537 rows, 4.2 MB packed where the
+    compiler's lane-padded ``coefs[slot]`` read 33.6 MB, out of fast
+    memory) and its [2^23, 16] block: the one gather under
+    ``photon.re.rescore`` fetches 128-lane rows, table, fetched block and
+    the block's rows-on-lanes copy in memory space 1, inside the segment
+    loop, and the program's temporaries are under 1 GB (4.3 GB at PR 37)."""
+    _, compiled = _shapes_only_re_sweep(
+        one_chip, [(1108, 1026, 16, 1_200_000), (64428, 39, 16, 2_000_000)],
+        1 << 23, ROW_HEAVY,
+    )
+    m = _fits(compiled)
+    assert m.temp_size_in_bytes < 1e9, m.temp_size_in_bytes
+    assert _rescore_gathers(compiled) == ["1,128"]
+    text = compiled.as_text()
+    assert "photon.re.rescore/while/body/" in text
+    assert "photon.gather/photon.gather.fetch" in text
+    assert "photon.gather/photon.gather.select" in text
+    ((table, block),) = _row_fetches(text)
+    assert table.startswith("f32[8193,128]") and "S(1)" in table, table
+    assert block.startswith("f32[32768,128]") and "S(1)" in block, block
+    relaid = [ln for ln in text.splitlines() if " copy(" in ln and "f32[32768,128]{0,1" in ln]
+    assert relaid and all("S(1)" in ln.split(" copy(")[0] for ln in relaid), relaid
 
 
 def _scatters_and_sorts(compiled):
