@@ -275,9 +275,12 @@ def _read_health(
     barrier when ``barrier`` is given (util/force.queue_scalars). The
     phl annotation below marks the ONE sanctioned steady-state sync —
     the same barrier the sweep always paid, now carrying the health
-    payload. Spans ``descent.barrier.enqueue`` (the read-back's slices
-    and concatenation put on the queue; arg ``pieces``) and
-    ``.wait`` (the blocking read) split it where the host stops
+    payload. The read-back is ONE small program of its own, launched
+    after the sweep's programs, which stay as they are. Spans
+    ``descent.barrier.enqueue`` (the read-back put on the queue; arg
+    ``pieces``: its device launches, the one program plus a slice a
+    multi-device value; arg ``values``: the device values it carries)
+    and ``.wait`` (the blocking read) split it where the host stops
     launching and starts waiting."""
     order = [cid for cid, h in health_dev.items() if h is not None]
     with obs.span("descent.barrier.enqueue") as enqueue_span:
@@ -290,7 +293,7 @@ def _read_health(
             for name in _SOLVE_COUNTERS:
                 flat.extend(h.get(name, ()))
         pending = queue_scalars(flat, barrier=barrier)
-        enqueue_span.set(pieces=pending.pieces)
+        enqueue_span.set(pieces=pending.pieces, values=pending.values)
     with obs.span("descent.barrier.wait"):
         # phl-ok: PHL002 THE per-sweep barrier read-back — health scalars ride the existing sync
         vals = pending.wait().tolist()
@@ -393,8 +396,9 @@ def run_coordinate_descent(
     split where the chip gains or loses queued work: ``descent.start``
     (everything launched before the first sweep; arg ``dispatches``),
     ``descent.sweep`` ⊃ ``descent.coordinate`` (a program launch each)
-    and ``descent.barrier`` ⊃ ``.enqueue`` (the read-back queued; arg
-    ``pieces``) and ``.wait`` (the blocking read), then
+    and ``descent.barrier`` ⊃ ``.enqueue`` (the read-back's one program
+    queued; args ``pieces``, ``values``) and ``.wait`` (the blocking
+    read), then
     ``descent.record`` (the host's bookkeeping with nothing queued),
     ``descent.validation`` and ``descent.checkpoint``. The tracker rows
     are derived FROM those spans (``seconds``/``sweep_seconds`` are span
@@ -407,11 +411,11 @@ def run_coordinate_descent(
     computes a per-coordinate loss / grad-norm / ``isfinite`` triple
     INSIDE its already-dispatched program, and the scalars ride the
     sweep's ONE read-back barrier home (``util/force.queue_scalars`` —
-    zero extra dispatches, zero extra read-backs; the dispatch-count
-    tests pin this). ``on_divergence`` decides what a non-finite
-    coordinate does at the sweep boundary: ``"raise"`` (default; a
-    :class:`photon_tpu.obs.health.DivergenceError` instead of a silently
-    poisoned checkpoint), ``"warn"``, or ``"halt_coordinate"``
+    the barrier's one program carries them, zero extra read-backs; the
+    dispatch-count tests pin this). ``on_divergence`` decides what a
+    non-finite coordinate does at the sweep boundary: ``"raise"``
+    (default; a :class:`photon_tpu.obs.health.DivergenceError` instead
+    of a silently poisoned checkpoint), ``"warn"``, or ``"halt_coordinate"``
     (re-initialize + freeze the offender, keep training the rest —
     recovery dispatches are paid only at the divergence boundary).
     ``None`` resolves via ``PHOTON_ON_DIVERGENCE``. Host health values

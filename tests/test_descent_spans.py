@@ -7,11 +7,13 @@
   ``descent.run`` ⊃ ``descent.start``, per sweep ``descent.sweep`` ⊃
   ``descent.coordinate`` (one a trained coordinate) and ``descent.barrier``
   ⊃ exactly ``.enqueue`` and ``.wait``, then ``descent.record``;
-  ``.enqueue``'s ``pieces`` is the health rows' device scalars plus the
-  barrier's leaves;
+  ``.enqueue``'s ``values`` is the health rows' device scalars plus the
+  barrier's leaves, and its ``pieces`` the one program that reads them;
 - ``util/force.queue_scalars`` and its ``wait()`` (what was
   ``fetch_scalars``, split where the host stops queueing) return what a
-  direct read of every scalar gives, bit for bit.
+  direct read of every scalar gives, bit for bit, through one launch of
+  one jitted program traced once a fit structure, or piece by piece
+  where that launch raises.
 """
 import jax
 import jax.numpy as jnp
@@ -35,6 +37,7 @@ from photon_tpu.optimize.problem import (
 )
 from photon_tpu.types import TaskType
 from photon_tpu.util import dispatch_count
+from photon_tpu.util import force as force_mod
 from photon_tpu.util.force import queue_scalars
 
 SWEEPS = 2
@@ -214,7 +217,8 @@ def test_enqueue_pieces_are_the_health_scalars_and_the_barrier(captured):
             3 + sum(len(h[k]) for k in ("iterations", "evaluations", "feature_passes"))
             for h in row["health"].values()
         )
-        assert enqueue.args["pieces"] == scalars + 1  # the total's one leaf
+        assert enqueue.args["values"] == scalars + 1  # the total's one leaf
+        assert enqueue.args["pieces"] == 1  # one program reads them all
 
 
 def test_record_follows_its_barrier_and_ends_before_the_next_launch(captured):
@@ -261,27 +265,91 @@ def test_queue_then_wait_reads_every_scalar_bit_for_bit(barrier):
     scalars = _scalars()
     pending = queue_scalars(scalars, barrier=total)
     leaves = {None: 0, "array": 1, "tree": 2}[barrier]
-    assert pending.pieces == 5 + leaves  # the two host numbers are no piece
+    assert pending.values == 5 + leaves  # the two host numbers are no value
+    assert pending.pieces == 1  # one launch reads every device value
     got = pending.wait()
     assert got.dtype == np.float32
     np.testing.assert_array_equal(got, _direct(scalars))
 
 
-def test_a_failed_concatenation_reads_piece_by_piece(monkeypatch):
-    def refuse(*a, **kw):
-        raise ValueError("mixed devices")
-
+@pytest.mark.parametrize("why", ["refused", "two_devices"])
+def test_a_failed_concatenation_reads_piece_by_piece(monkeypatch, why):
+    """A launch of the one program that raises (values on different
+    devices or platforms) leaves each value to its own read: same values,
+    a piece each, no dispatch counted."""
     scalars = _scalars()
-    want = queue_scalars(scalars, barrier=jnp.arange(3.0)).wait()
-    monkeypatch.setattr(jnp, "concatenate", refuse)
-    pending = queue_scalars(scalars, barrier=jnp.arange(3.0))
+    barrier = jnp.arange(3.0)
+    want = queue_scalars(scalars, barrier=barrier).wait()
+    if why == "refused":
+        def refuse(*a, **kw):
+            raise ValueError("mixed devices")
+
+        monkeypatch.setattr(force_mod, "_first_elements", refuse)
+    else:
+        if len(jax.devices()) < 2:
+            pytest.skip("needs more than one device")
+        barrier = jax.device_put(barrier, jax.devices()[1])
+        scalars = [jax.device_put(s, jax.devices()[0])
+                   if isinstance(s, jax.Array) else s for s in scalars]
+    d0 = dispatch_count.snapshot()
+    pending = queue_scalars(scalars, barrier=barrier)
+    assert dispatch_count.snapshot() == d0
+    assert pending.pieces == pending.values == 6
     np.testing.assert_array_equal(pending.wait(), want)
+    np.testing.assert_array_equal(want, _direct(scalars))
 
 
 def test_nothing_to_read_is_an_empty_array():
+    d0 = dispatch_count.snapshot()
     assert queue_scalars([]).pieces == 0
+    assert queue_scalars([3]).pieces == queue_scalars([3]).values == 0
+    assert dispatch_count.snapshot() == d0  # nothing on the device: no launch
     assert queue_scalars([], barrier=jnp.ones(2)).wait().tolist() == []
     assert queue_scalars([3]).wait().tolist() == [3.0]
+
+
+def test_one_device_value_is_one_launch_counted_as_a_dispatch():
+    d0 = dispatch_count.snapshot()
+    pending = queue_scalars([jnp.asarray(4.0), 2.0])
+    assert dispatch_count.snapshot() - d0 == 1
+    assert (pending.pieces, pending.values) == (1, 1)
+    assert pending.wait().tolist() == [4.0, 2.0]
+
+
+def test_multi_device_values_are_read_alone_beside_the_one_program():
+    """A sharded barrier leaf (a mesh fit's total) is read on its own,
+    never gathered into the one program: a piece of its own."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from photon_tpu.parallel.mesh import make_mesh
+
+    n = len(jax.devices())
+    if n < 2:
+        pytest.skip("needs more than one device")
+    mesh = make_mesh(num_data=n)
+    total = jax.device_put(
+        np.arange(2.0 * n, dtype=np.float32), NamedSharding(mesh, P("data"))
+    )
+    scalars = _scalars()
+    pending = queue_scalars(scalars, barrier=total)
+    assert (pending.pieces, pending.values) == (2, 6)
+    np.testing.assert_array_equal(pending.wait(), _direct(scalars))
+    assert queue_scalars([], barrier=total).pieces == 1
+
+
+def test_the_read_back_traces_once_a_fit_structure():
+    """A 3-sweep fit traces the one program once; a second fit of the
+    same structure traces it not at all."""
+    coords = _coordinates(seed=5)
+    force_mod._first_elements.clear_cache()
+    before = force_mod.TRACE_COUNTERS["first_elements"]
+    first = run_coordinate_descent(coords, ["fixed", "user"], 3)
+    assert force_mod.TRACE_COUNTERS["first_elements"] - before == 1
+    second = run_coordinate_descent(_coordinates(seed=5), ["fixed", "user"], 3)
+    assert force_mod.TRACE_COUNTERS["first_elements"] - before == 1
+    rows = [[r["health"] for r in res.tracker if "health" in r]
+            for res in (first, second)]
+    assert rows[0] == rows[1] and len(rows[0]) == 3
 
 
 # -- scripts/scope_join.py --gaps --------------------------------------------

@@ -1,9 +1,9 @@
 """util/force.force — the read-back completion barrier (PERF.md r4).
 
 On CPU the barrier is trivially satisfied; these tests pin the CONTRACT:
-every jax.Array leaf is touched (one fetch), non-device leaves and empty
-arrays are skipped, and mixed dtypes survive the single concatenated
-fetch."""
+every jax.Array leaf is touched (one launch of one program, one fetch),
+non-device leaves and empty arrays are skipped, mixed dtypes survive the
+single fetch bit for bit, and a launch that raises reads leaf by leaf."""
 from __future__ import annotations
 
 import jax.numpy as jnp
@@ -67,19 +67,19 @@ def test_multi_device_detection_defaults_to_host_resident():
 
 
 def test_force_single_fetch_for_single_device_leaves(monkeypatch):
-    """≥2 single-device leaves must take the concatenated SINGLE-fetch path
-    (one blocking round trip), even in a tree mixed with
+    """≥2 single-device leaves must take the one-program SINGLE-fetch path
+    (one launch, one blocking round trip), even in a tree mixed with
     numpy leaves."""
-    import jax.numpy as jnp_mod
+    from photon_tpu.util import force as force_mod
 
     calls = []
-    orig = jnp_mod.concatenate
+    orig = force_mod._first_elements
 
-    def counting(*args, **kwargs):
-        calls.append(1)
-        return orig(*args, **kwargs)
+    def counting(leaves):
+        calls.append(len(leaves))
+        return orig(leaves)
 
-    monkeypatch.setattr(jnp_mod, "concatenate", counting)
+    monkeypatch.setattr(force_mod, "_first_elements", counting)
     force(
         {
             "a": jnp.arange(4, dtype=jnp.float32),
@@ -87,4 +87,42 @@ def test_force_single_fetch_for_single_device_leaves(monkeypatch):
             "c": np.zeros(5),  # host leaf must not break the fast path
         }
     )
-    assert len(calls) == 1
+    assert calls == [2]
+
+
+def test_force_holds_when_the_one_program_cannot_launch(monkeypatch):
+    """A launch that raises (leaves on different devices or platforms)
+    falls back to a fetch per leaf: the barrier still holds, and the
+    per-leaf read gives the same first elements."""
+    from photon_tpu.util import force as force_mod
+
+    def refuse(leaves):
+        raise ValueError("mixed devices")
+
+    leaves = (jnp.arange(4.0) + 1, jnp.asarray(True), jnp.asarray(2.5, jnp.bfloat16))
+    monkeypatch.setattr(force_mod, "_first_elements", refuse)
+    assert force_mod._launch(leaves) is None
+    force(leaves)  # must not raise
+    got = force_mod._read(None, leaves)
+    assert got.dtype == np.float32
+    assert got.tolist() == [1.0, 1.0, 2.5]
+
+
+def test_first_elements_is_the_eager_read_bit_for_bit():
+    from photon_tpu.util.force import _first_elements
+
+    leaves = (
+        jnp.asarray(1.0 + 2.0 ** -30, jnp.float64),
+        jnp.asarray([False, True]),
+        jnp.asarray([[2 ** 24 + 1, 3]], jnp.int32),
+        jnp.asarray(-0.0, jnp.float32),
+        jnp.asarray(2.5, jnp.bfloat16),
+    )
+    want = jnp.concatenate(
+        [x.reshape(-1)[0:1].astype(jnp.float32) for x in leaves]
+    )
+    got = _first_elements(leaves)
+    assert got.dtype == jnp.float32 and got.shape == (5,)
+    np.testing.assert_array_equal(
+        np.asarray(got).view(np.uint32), np.asarray(want).view(np.uint32)
+    )

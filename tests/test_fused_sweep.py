@@ -136,7 +136,8 @@ def test_fused_sweep_single_program_per_coordinate(monkeypatch):
     # the tracker's per-sweep rows record the launch profile
     sweep_rows = [r for r in result.tracker if "sweep_seconds" in r]
     assert len(sweep_rows) == n_sweeps
-    assert all(r["dispatches"] == len(coords) for r in sweep_rows)
+    # one program a coordinate, plus the barrier's one read-back program
+    assert all(r["dispatches"] == len(coords) + 1 for r in sweep_rows)
     assert all(r["granularity"] == "sweep" for r in sweep_rows)
 
 

@@ -316,7 +316,8 @@ def test_ledger_and_health_add_zero_dispatches_and_readbacks(monkeypatch):
     assert rb_on == rb_off
     # one combined barrier+health fetch per sweep, nothing else
     assert rb_off == 3
-    assert all(d == 2 for d in rows_off)  # one program per coordinate
+    # one program per coordinate, plus the barrier's one read-back program
+    assert all(d == 3 for d in rows_off)
     # and the enabled run actually took its censuses (it measured, for
     # free, what the disabled run didn't)
     snap = obs.get_registry().snapshot()
